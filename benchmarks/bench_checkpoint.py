@@ -12,9 +12,10 @@ O(n/S) resident memory per shard file. Rows:
 * ``ckpt_bytes`` — total entry size on disk;
 * ``ckpt_mb_per_s`` — save throughput (bytes / save seconds).
 
-Run standalone (8 forced host devices happen in run.py's subprocess):
+Run standalone (``JAX_PLATFORMS=cpu`` forces 8 host devices; a chip host
+uses its chips):
 
-    PYTHONPATH=src python -m benchmarks.bench_checkpoint --n 200000 --shards 8
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.bench_checkpoint --n 200000
 
 ``benchmarks/run.py --only checkpoint`` merges every ``ckpt_*`` row into
 BENCH_summary.json.
@@ -24,11 +25,12 @@ from __future__ import annotations
 
 import argparse
 import os
-import sys
 import tempfile
 import time
 
 import numpy as np
+
+from repro.launch.runtime import default_shards, force_host_devices
 
 
 def run(n=200_000, shards=8, slots=2, slot_wakes=2048.0, seed=0, verbose=True):
@@ -89,16 +91,15 @@ def run(n=200_000, shards=8, slots=2, slot_wakes=2048.0, seed=0, verbose=True):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=200_000)
-    ap.add_argument("--shards", type=int, default=8)
+    ap.add_argument("--shards", type=int, default=None,
+                    help="shard count (default: 8 on a JAX_PLATFORMS=cpu run, else every device)")
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--slot-wakes", type=float, default=2048.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    if "jax" not in sys.modules:
-        os.environ.setdefault(
-            "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
-        )
-    run(n=args.n, shards=args.shards, slots=args.slots,
+    shards = args.shards or default_shards(8)
+    force_host_devices(shards)
+    run(n=args.n, shards=shards, slots=args.slots,
         slot_wakes=args.slot_wakes, seed=args.seed)
     return 0
 

@@ -19,11 +19,10 @@ Two questions, answered on the 8-shard engine the roofline rows describe:
 Artifacts: a Chrome/Perfetto ``trace.json`` (host timing spans + the
 synthetic per-phase track) and a :class:`repro.obs.RunReport` JSONL with
 the drained counters and phase rows — render either with
-``python -m repro.obs.report``. Needs 8 host devices, so ``run.py``
-launches it in a subprocess:
+``python -m repro.obs.report``. Runs over every chip of a chip host, or
+with ``JAX_PLATFORMS=cpu`` over 8 forced host devices:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-        PYTHONPATH=src python -m benchmarks.bench_obs --n 50000
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.bench_obs --n 50000
 """
 
 from __future__ import annotations
@@ -34,6 +33,8 @@ import sys
 import time
 
 import numpy as np
+
+from repro.launch.runtime import default_shards, force_host_devices
 
 
 def _steady_s_per_slot(engines, n: int, p: int, slots: int, repeats: int = 5):
@@ -82,9 +83,8 @@ def run(
 
     if len(jax.devices()) < shards:
         raise RuntimeError(
-            f"need {shards} devices (have {len(jax.devices())}); set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={shards} "
-            "before jax is imported"
+            f"need {shards} devices (have {len(jax.devices())}); on the CPU "
+            "run main() under JAX_PLATFORMS=cpu, which forces them"
         )
 
     rng = np.random.default_rng(seed)
@@ -140,7 +140,8 @@ def main(argv=None):
     """CLI entry point; forces host-platform devices when still possible."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=200_000)
-    ap.add_argument("--shards", type=int, default=8)
+    ap.add_argument("--shards", type=int, default=None,
+                    help="shard count (default: 8 on a JAX_PLATFORMS=cpu run, else every device)")
     ap.add_argument("--slots", type=int, default=6)
     ap.add_argument("--slot-wakes", type=float, default=2048.0)
     ap.add_argument("--seed", type=int, default=0)
@@ -149,16 +150,11 @@ def main(argv=None):
     ap.add_argument("--trace-out", default="results/obs_trace.json")
     ap.add_argument("--report-out", default="results/obs_runreport.jsonl")
     args = ap.parse_args(argv)
-    if "jax" not in sys.modules and "host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""
-    ):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.shards}"
-        ).strip()
+    shards = args.shards or default_shards(8)
+    force_host_devices(shards)
     run(
         n=args.n,
-        shards=args.shards,
+        shards=shards,
         slots=args.slots,
         slot_wakes=args.slot_wakes,
         seed=args.seed,

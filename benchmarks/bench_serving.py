@@ -15,10 +15,10 @@ millions-of-users framing implies. Rows:
   in slots (bounded by ``snapshot_every`` while training runs).
 
 The final batch is verified bit-exact against the published snapshot
-rows before any row is printed. Run standalone (8 forced host devices
-happen in run.py's subprocess):
+rows before any row is printed. Run standalone (``JAX_PLATFORMS=cpu``
+forces 8 host devices; a chip host uses its chips):
 
-    PYTHONPATH=src python -m benchmarks.bench_serving --n 100000 --shards 8
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.bench_serving --n 100000
 
 ``benchmarks/run.py --only serving`` merges every ``serving_*`` row into
 BENCH_summary.json.
@@ -32,6 +32,8 @@ import threading
 import time
 
 import numpy as np
+
+from repro.launch.runtime import default_shards, force_host_devices
 
 
 def run(n=100_000, shards=8, slots=4, slot_wakes=2048.0, batch=1024, seed=0,
@@ -124,13 +126,16 @@ def run(n=100_000, shards=8, slots=4, slot_wakes=2048.0, batch=1024, seed=0,
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=100_000)
-    ap.add_argument("--shards", type=int, default=8)
+    ap.add_argument("--shards", type=int, default=None,
+                    help="shard count (default: 8 on a JAX_PLATFORMS=cpu run, else every device)")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--slot-wakes", type=float, default=2048.0)
     ap.add_argument("--batch", type=int, default=1024)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    run(n=args.n, shards=args.shards, slots=args.slots,
+    shards = args.shards or default_shards(8)
+    force_host_devices(shards)
+    run(n=args.n, shards=shards, slots=args.slots,
         slot_wakes=args.slot_wakes, batch=args.batch, seed=args.seed)
     return 0
 

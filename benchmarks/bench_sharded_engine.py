@@ -15,25 +15,23 @@ per super-tick (rows x p x 4 bytes for the f32 engine dtype) — the
 numbers behind the ``exchange="auto"`` selection. The timed run uses
 ``--relabel``/``--exchange`` (default: RCM + auto).
 
-Run it with forced host devices (the flag must be set before jax loads,
-so ``main`` sets it for you when possible):
+Run it on a chip host (over every chip) or, with ``JAX_PLATFORMS=cpu``, over
+8 forced host devices (``main`` forces them before JAX starts):
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
-        PYTHONPATH=src python -m benchmarks.bench_sharded_engine --n 1000000
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.bench_sharded_engine --n 1000000
 
-``benchmarks/run.py --only sharded_engine`` invokes this module in a
-subprocess with 8 forced host devices and merges every ``sharded_*`` CSV
-row it prints into the bench summary.
+``benchmarks/run.py --only sharded_engine`` calls :func:`run` in its own
+process and merges every ``sharded_*`` row into the bench summary.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
-import sys
 import time
 
 import numpy as np
+
+from repro.launch.runtime import default_shards, force_host_devices
 
 
 def exchange_stats(graph, shards: int, p: int, partition_mode: str = "degree"):
@@ -89,7 +87,7 @@ def run(
     exchange: str = "auto",
     fused="auto",
     metrics: bool = False,
-    roofline: bool = True,
+    roofline: bool | None = None,
     verbose: bool = True,
 ):
     """Time the sharded engine at scale and report the comm sweep rows.
@@ -99,8 +97,14 @@ def run(
     ``fused`` is the EngineConfig knob (``"auto"`` engages the fused
     super-tick kernel on TPU only — forcing ``True`` on a CPU host runs
     the kernel in interpret mode, which is not a perf configuration).
+    ``roofline`` places the super-tick against the chip's peaks
+    (:mod:`repro.roofline.peaks`); None does so on a TPU only, since the
+    CPU has no peaks to place it against.
     """
     import jax
+
+    if roofline is None:
+        roofline = jax.default_backend() == "tpu"
 
     from benchmarks.bench_sparse_scale import _make_problem
     from repro.core.mixing import ExchangeSpec
@@ -108,9 +112,8 @@ def run(
 
     if len(jax.devices()) < shards:
         raise RuntimeError(
-            f"need {shards} devices (have {len(jax.devices())}); set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={shards} "
-            "before jax is imported"
+            f"need {shards} devices (have {len(jax.devices())}); on the CPU "
+            "run main() under JAX_PLATFORMS=cpu, which forces them"
         )
 
     rng = np.random.default_rng(seed)
@@ -226,7 +229,8 @@ def main(argv=None):
     """CLI entry point; forces host-platform devices when still possible."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=1_000_000)
-    ap.add_argument("--shards", type=int, default=8)
+    ap.add_argument("--shards", type=int, default=None,
+                    help="shard count (default: 8 on a JAX_PLATFORMS=cpu run, else every device)")
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--slot-wakes", type=float, default=8192.0)
     ap.add_argument("--seed", type=int, default=0)
@@ -242,17 +246,11 @@ def main(argv=None):
                     help="run with in-jit telemetry on and report its totals")
     ap.add_argument("--no-roofline", action="store_true")
     args = ap.parse_args(argv)
-    if "jax" not in sys.modules and "host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""
-    ):
-        # jax not loaded yet: we can still force the host devices ourselves.
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.shards}"
-        ).strip()
+    shards = args.shards or default_shards(8)
+    force_host_devices(shards)
     run(
         n=args.n,
-        shards=args.shards,
+        shards=shards,
         slots=args.slots,
         slot_wakes=args.slot_wakes,
         seed=args.seed,
@@ -262,7 +260,7 @@ def main(argv=None):
         exchange=args.exchange,
         fused={"auto": "auto", "on": True, "off": False}[args.fused],
         metrics=args.metrics,
-        roofline=not args.no_roofline,
+        roofline=False if args.no_roofline else None,
     )
 
 

@@ -5,6 +5,10 @@
     PYTHONPATH=src python -m benchmarks.run --list     # show bench names
     PYTHONPATH=src python -m benchmarks.run --only obs # run one bench
 
+All benches run in this one process, so a chip run holds its chip once:
+on a chip host the multi-device benches shard over the host's chips; with
+``JAX_PLATFORMS=cpu`` they shard over 8 forced host devices.
+
 Prints ``name,us_per_call,derived`` CSV lines per bench plus per-table
 summaries. Every run (fast mode included) writes the machine-readable
 ``results/BENCH_summary.json`` mapping name -> {us_per_call, derived} so
@@ -17,55 +21,49 @@ from __future__ import annotations
 import argparse
 import os
 import shutil
-import subprocess
 import sys
 import time
 
 
-def _merge_summary(path, rows):
-    """Shared with the report CLI so the two summary writers cannot drift."""
+# Host devices a JAX_PLATFORMS=cpu run forces for the multi-device benches.
+HOST_DEVICES = 8
+
+
+def _ensure_src():
+    """Make ``repro`` importable when it is not installed."""
     try:
-        from repro.obs.report import merge_bench_summary
+        import repro  # noqa: F401
     except ImportError:
         sys.path.insert(
             0, os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
         )
-        from repro.obs.report import merge_bench_summary
+
+
+def _merge_summary(path, rows):
+    """Shared with the report CLI so the two summary writers cannot drift."""
+    from repro.obs.report import merge_bench_summary
+
     merge_bench_summary(path, rows)
 
 
-def _subprocess_bench(module: str, cli: list, row_prefix: str) -> list:
-    """Run a bench module in a subprocess with 8 forced host devices.
+def _shard_bench(module: str, row_prefix: str, **kw) -> tuple[int, list]:
+    """Run a multi-device bench module's ``run`` in this process.
 
-    Multi-device benches need host-platform devices, which XLA only
-    grants before its first initialization — too late for a process that
-    already imported jax. The subprocess reports back via its CSV rows;
-    every ``row_prefix*`` line it prints becomes a summary row here.
+    It runs on every device the process has: the chip host's, or on a
+    ``JAX_PLATFORMS=cpu`` run the 8 host devices ``main`` forced before
+    JAX started. One process holds the chip, so no child is started.
+    Returns the shard count and every ``row_prefix*`` row it returned.
     """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-    ).strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        filter(None, ["src", env.get("PYTHONPATH", "")])
-    )
-    proc = subprocess.run(
-        [sys.executable, "-m", module, *cli], env=env, capture_output=True, text=True
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(f"{module} failed:\n{proc.stderr[-3000:]}")
-    rows = []
-    for line in proc.stdout.splitlines():
-        if not line.startswith(row_prefix) or line.count(",") < 2:
-            continue
-        name, val, note = line.split(",", 2)
-        rows.append((name, float(val), note))
+    import importlib
+
+    from repro.launch.runtime import default_shards
+
+    shards = default_shards(HOST_DEVICES)
+    rows = importlib.import_module(module).run(shards=shards, **kw)
+    rows = [r for r in rows if r[0].startswith(row_prefix)]
     if not rows:
-        raise RuntimeError(
-            f"{module} printed no {row_prefix}* rows; stdout was:\n"
-            f"{proc.stdout[-2000:]}"
-        )
-    return rows
+        raise RuntimeError(f"{module} returned no {row_prefix}* rows")
+    return shards, rows
 
 
 def _bench_fig1(full, rows, record):
@@ -153,13 +151,8 @@ def _bench_sharded_engine(full, rows, record):
     )
     # Tick rates, partition stats, the halo-fraction / exchanged-bytes
     # sweep over {no relabel, RCM} x {all_gather, p2p} — every sharded_*
-    # row the subprocess prints joins the summary under its own name.
-    sub = _subprocess_bench(
-        "benchmarks.bench_sharded_engine",
-        ["--n", str(kw["n"]), "--shards", "8",
-         "--slots", str(kw["slots"]), "--slot-wakes", str(kw["slot_wakes"])],
-        "sharded_",
-    )
+    # row the bench returns joins the summary under its own name.
+    shards, sub = _shard_bench("benchmarks.bench_sharded_engine", "sharded_", **kw)
     rows.extend(sub)
     rate = next(
         (v for name, v, _ in sub if name == "sharded_equiv_ticks_per_s"), None
@@ -167,7 +160,7 @@ def _bench_sharded_engine(full, rows, record):
     if rate is None:
         raise RuntimeError("sharded_engine printed no sharded_equiv_ticks_per_s row")
     record("sharded_engine", t0,
-           f"n={kw['n']},shards=8,equiv_ticks_per_s={rate:.4g}")
+           f"n={kw['n']},shards={shards},equiv_ticks_per_s={rate:.4g}")
 
 
 def _bench_obs(full, rows, record):
@@ -184,17 +177,12 @@ def _bench_obs(full, rows, record):
     # obs_phase_* decomposition of the super-tick behind the
     # sharded_roofline_supertick_gap row; also writes the trace.json and
     # RunReport JSONL artifacts under results/.
-    sub = _subprocess_bench(
-        "benchmarks.bench_obs",
-        ["--n", str(kw["n"]), "--shards", "8",
-         "--slots", str(kw["slots"]), "--slot-wakes", str(kw["slot_wakes"])],
-        "obs_",
-    )
+    shards, sub = _shard_bench("benchmarks.bench_obs", "obs_", **kw)
     rows.extend(sub)
     over = next((v for name, v, _ in sub if name == "obs_overhead"), None)
     if over is None:
         raise RuntimeError("obs bench printed no obs_overhead row")
-    record("obs", t0, f"n={kw['n']},shards=8,overhead_pct={over:.3g}")
+    record("obs", t0, f"n={kw['n']},shards={shards},overhead_pct={over:.3g}")
 
 
 def _bench_dynamic_topology(full, rows, record):
@@ -212,21 +200,17 @@ def _bench_dynamic_topology(full, rows, record):
 
 def _bench_checkpoint(full, rows, record):
     t0 = time.time()
-    kw = dict(n=200_000, shards=8) if full else dict(n=20_000, shards=8)
+    kw = dict(n=200_000) if full else dict(n=20_000)
     # Engine save/restore round trip at scale: wall seconds each way plus
     # entry bytes, all per-shard with no (n, p) host materialization.
-    sub = _subprocess_bench(
-        "benchmarks.bench_checkpoint",
-        ["--n", str(kw["n"]), "--shards", str(kw["shards"])],
-        "ckpt_",
-    )
+    shards, sub = _shard_bench("benchmarks.bench_checkpoint", "ckpt_", **kw)
     rows.extend(sub)
     save_s = next((v for name, v, _ in sub if name == "ckpt_save_s"), None)
     nbytes = next((v for name, v, _ in sub if name == "ckpt_bytes"), None)
     if save_s is None or nbytes is None:
         raise RuntimeError("checkpoint bench printed no ckpt_save_s/ckpt_bytes rows")
     record("checkpoint", t0,
-           f"n={kw['n']},shards=8,save_s={save_s:.3g},bytes={int(nbytes)}")
+           f"n={kw['n']},shards={shards},save_s={save_s:.3g},bytes={int(nbytes)}")
 
 
 def _bench_serving(full, rows, record):
@@ -240,13 +224,7 @@ def _bench_serving(full, rows, record):
     # snapshot while the sharded engine trains — predictions/s, p50/p99
     # batch latency, and the per-super-tick publication cost all join
     # the summary (served rows are asserted bit-exact in-bench).
-    sub = _subprocess_bench(
-        "benchmarks.bench_serving",
-        ["--n", str(kw["n"]), "--shards", "8",
-         "--slots", str(kw["slots"]), "--slot-wakes", str(kw["slot_wakes"]),
-         "--batch", str(kw["batch"])],
-        "serving_",
-    )
+    shards, sub = _shard_bench("benchmarks.bench_serving", "serving_", **kw)
     rows.extend(sub)
     rate = next(
         (v for name, v, _ in sub if name == "serving_predictions_per_s"), None
@@ -254,7 +232,8 @@ def _bench_serving(full, rows, record):
     if rate is None:
         raise RuntimeError("serving bench printed no serving_predictions_per_s row")
     record("serving", t0,
-           f"n={kw['n']},shards=8,batch={kw['batch']},predictions_per_s={rate:.4g}")
+           f"n={kw['n']},shards={shards},batch={kw['batch']},"
+           f"predictions_per_s={rate:.4g}")
 
 
 def _bench_roofline(full, rows, record):
@@ -270,6 +249,9 @@ def _bench_roofline(full, rows, record):
         return
     record("roofline", t0, f"{len(rs)} dry-run rows")
 
+
+# The benches whose paper-level results are computed in f64.
+X64_BENCHES = frozenset({"fig1", "fig2", "table1", "ablations"})
 
 # Registration order is execution order; roofline stays last so its
 # dry-run rows print after the measured ones they contextualize.
@@ -309,10 +291,15 @@ def main(argv=None) -> int:
         )
         return 2
 
+    _ensure_src()
+    from repro.launch.runtime import enable_compile_cache, force_host_devices
+
+    # Before JAX starts: a CPU run splits the host into the devices the
+    # multi-device benches shard over; a chip run keeps its chips.
+    force_host_devices(HOST_DEVICES)
     import jax
 
-    jax.config.update("jax_enable_x64", True)  # paper-core benches need f64
-
+    enable_compile_cache()
     os.makedirs("results", exist_ok=True)
     rows = []
 
@@ -323,7 +310,10 @@ def main(argv=None) -> int:
 
     for name, bench in BENCHES.items():
         if args.only in (None, name):
-            bench(args.full, rows, record)
+            # The paper-core benches compute in f64; everything else runs
+            # in the 32-bit mode the engine runs in on the chip.
+            with jax.enable_x64(name in X64_BENCHES):
+                bench(args.full, rows, record)
 
     # Machine-readable per-PR perf trajectory (fast mode and --only runs
     # included): the stable contract is name -> {us_per_call, derived},

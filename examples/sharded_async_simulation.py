@@ -1,8 +1,8 @@
 """Multi-device sharded async engine walkthrough.
 
 Shards a 20,000-agent random geometric collaboration graph across 4
-XLA host-platform devices (the same ``shard_map`` program runs unchanged
-on real TPU/GPU meshes): a reverse Cuthill–McKee relabel pass co-locates
+devices: the chips of a four-chip TPU host, or under ``JAX_PLATFORMS=cpu``
+4 XLA host-platform devices (the same ``shard_map`` program runs on both): a reverse Cuthill–McKee relabel pass co-locates
 graph neighbours so the cut shrinks, agent blocks carry their own slice
 of the dataset (no replicated ``obj.data``), and the halo exchange goes
 point-to-point — each shard ships only the border rows its neighbour
@@ -14,8 +14,8 @@ Cross-checks the result against the single-device batched engine — under
 forced wake sets the two are bit-identical; under sampled clocks both
 land on the same fixed point.
 
-Run:  PYTHONPATH=src python examples/sharded_async_simulation.py
-      PYTHONPATH=src python examples/sharded_async_simulation.py --smoke   # CI-sized
+Run:  JAX_PLATFORMS=cpu PYTHONPATH=src python examples/sharded_async_simulation.py
+      JAX_PLATFORMS=cpu PYTHONPATH=src python examples/sharded_async_simulation.py --smoke   # CI-sized
 
 Crash-safe resume (the CI checkpoint lane drives exactly this pair)::
 
@@ -30,8 +30,11 @@ Crash-safe resume (the CI checkpoint lane drives exactly this pair)::
 import argparse
 import os
 
-# Must happen before jax initializes: split the CPU into 4 host devices.
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+from repro.launch.runtime import force_host_devices
+
+# Before JAX starts: a JAX_PLATFORMS=cpu run splits the host into 4
+# devices; a four-chip host runs the shards on its chips.
+force_host_devices(4)
 
 import numpy as np  # noqa: E402
 

@@ -520,12 +520,10 @@ def _restore_sharded(engine, entry: str, manifest: dict):
                 )
 
     state = blank._replace(
-        Theta=jnp.asarray(theta_t),
-        active=jnp.asarray(active_t),
+        Theta=theta_t,
+        active=active_t,
         keys=keys,
-        ustate=jax.tree_util.tree_unflatten(
-            ustate_def, [jnp.asarray(t) for t in ustate_t]
-        ),
+        ustate=jax.tree_util.tree_unflatten(ustate_def, list(ustate_t)),
         applied=applied,
         dropped=dropped,
         messages=messages,
@@ -533,7 +531,7 @@ def _restore_sharded(engine, entry: str, manifest: dict):
         ef=ef,
         metrics=metrics,
     )
-    return state, int(manifest["step"])
+    return engine.place(state), int(manifest["step"])
 
 
 # ---------------------------------------------------------------------------

@@ -742,28 +742,38 @@ def random_geometric_graph(
     ncells = int(np.ceil(1.0 / radius)) + 1
     cell_id = cell[:, 0] * ncells + cell[:, 1]
     order = np.argsort(cell_id, kind="stable")
-    sorted_ids = cell_id[order]
-    uniq, starts = np.unique(sorted_ids, return_index=True)
-    starts = np.append(starts, n)
-    bucket = {int(u): order[s:e] for u, s, e in zip(uniq, starts[:-1], starts[1:])}
+    uniq, starts, counts = np.unique(cell_id[order], return_index=True, return_counts=True)
+    ucx, ucy = np.divmod(uniq, ncells)
+    # Each point's bucket, as an index into uniq (points in sorted order).
+    point_bucket = np.repeat(np.arange(uniq.size), counts)
 
     rows_acc, cols_acc = [], []
     r2 = radius * radius
-    # Half-neighbourhood offsets so each cell pair is visited once.
+    # Half-neighbourhood offsets so each cell pair is visited once; every
+    # point of a cell is paired with every point of the offset cell.
     half = [(0, 0), (0, 1), (1, -1), (1, 0), (1, 1)]
-    for u, members in bucket.items():
-        cx, cy = divmod(u, ncells)
-        for dx, dy in half:
-            other = bucket.get((cx + dx) * ncells + (cy + dy))
-            if other is None:
-                continue
-            d2 = ((pos[members][:, None, :] - pos[other][None, :, :]) ** 2).sum(-1)
-            a, b = np.nonzero(d2 <= r2)
-            if dx == 0 and dy == 0:
-                keep = a < b  # dedupe within-cell pairs
-                a, b = a[keep], b[keep]
-            rows_acc.append(members[a])
-            cols_acc.append(other[b])
+    for dx, dy in half:
+        ox, oy = ucx + dx, ucy + dy
+        inside = (ox >= 0) & (ox < ncells) & (oy >= 0) & (oy < ncells)
+        other_id = ox * ncells + oy
+        pos_in = np.minimum(np.searchsorted(uniq, other_id), uniq.size - 1)
+        has = inside & (uniq[pos_in] == other_id)
+        other_start = np.where(has, starts[pos_in], 0)
+        other_count = np.where(has, counts[pos_in], 0)
+        # Expand (point a, partner b) pairs: a repeats once per point of
+        # its bucket's partner cell; b walks that cell's sorted slice.
+        per_point = other_count[point_bucket]
+        a_sorted = np.repeat(np.arange(n), per_point)
+        first = np.cumsum(per_point) - per_point
+        within = np.arange(a_sorted.size) - np.repeat(first, per_point)
+        b_sorted = np.repeat(other_start[point_bucket], per_point) + within
+        if dx == 0 and dy == 0:
+            keep = a_sorted < b_sorted  # dedupe within-cell pairs
+            a_sorted, b_sorted = a_sorted[keep], b_sorted[keep]
+        a, b = order[a_sorted], order[b_sorted]
+        close = ((pos[a] - pos[b]) ** 2).sum(-1) <= r2
+        rows_acc.append(a[close])
+        cols_acc.append(b[close])
     rows = np.concatenate(rows_acc) if rows_acc else np.zeros(0, dtype=np.int64)
     cols = np.concatenate(cols_acc) if cols_acc else np.zeros(0, dtype=np.int64)
 

@@ -41,6 +41,10 @@ from repro.core.graph import (
 # they only serve the on-chip regime; past this the jnp paths take over.
 _KERNEL_MAX_N = 4096
 
+# f32 products at full f32 precision: a TPU's default would round the
+# operands of these contractions to bf16.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def kernel_max_n() -> int:
     """Largest agent count the Pallas mixing kernels auto-engage at.
@@ -55,7 +59,12 @@ def kernel_max_n() -> int:
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class MixOp:
-    """Dense or sparse neighbour-sum operator. Arrays are jit-closure constants."""
+    """Dense or sparse neighbour-sum operator.
+
+    Its methods close over the numpy arrays, which jit bakes into a
+    program as constants; the batched engines instead pass
+    :meth:`tables` as a jit argument to :meth:`gather_rows`.
+    """
 
     kind: str  # "dense" | "sparse"
     n: int
@@ -95,7 +104,7 @@ class MixOp:
                 jnp.asarray(self.idx), jnp.asarray(self.w, jnp.float32), Theta
             )
         if self.kind == "dense":
-            return jnp.asarray(self.W, Theta.dtype) @ Theta
+            return jnp.matmul(jnp.asarray(self.W, Theta.dtype), Theta, precision=_HIGHEST)
         contrib = jnp.asarray(self.vals, Theta.dtype)[:, None] * Theta[jnp.asarray(self.cols)]
         return jax.ops.segment_sum(
             contrib, jnp.asarray(self.rows), num_segments=self.n, indices_are_sorted=True
@@ -104,12 +113,19 @@ class MixOp:
     def row(self, Theta, i):
         """sum_j W_ij Theta_j for one (possibly traced) agent i: -> (p,)."""
         if self.kind == "dense":
-            return jnp.asarray(self.W, Theta.dtype)[i] @ Theta
+            return jnp.matmul(jnp.asarray(self.W, Theta.dtype)[i], Theta, precision=_HIGHEST)
         cols_i = jnp.asarray(self.idx)[i]  # (K,)
         w_i = jnp.asarray(self.w, Theta.dtype)[i]  # (K,)
         return jnp.sum(w_i[:, None] * Theta[cols_i], axis=0)
 
-    def gather_rows(self, Theta, idx, use_kernel: bool | None = None):
+    def tables(self, dtype) -> dict:
+        """The operator's arrays as device arrays (weights in ``dtype``), for
+        :meth:`gather_rows` callers that pass them through jit."""
+        if self.kind == "dense":
+            return {"W": jnp.asarray(self.W, dtype)}
+        return {"idx": jnp.asarray(self.idx), "w": jnp.asarray(self.w, dtype)}
+
+    def gather_rows(self, Theta, idx, use_kernel: bool | None = None, tables=None):
         """Batched neighbour sums for a row subset: (B,) indices -> (B, p).
 
         The super-tick path of ``repro.sim``: gather only the woken agents'
@@ -117,29 +133,48 @@ class MixOp:
         traced and may contain the out-of-range padding sentinel n (jit
         gathers clamp it to row n-1; callers mask those entries out when
         scattering). Sparse graphs route through the ``sparse_mix`` Pallas
-        machinery on TPU under the same gate as :meth:`all`.
+        machinery on TPU under the same gate as :meth:`all`. ``tables``:
+        this operator's :meth:`tables`, passed in by a jitted caller (None
+        reads the operator's own arrays).
         """
         if use_kernel is None:
             use_kernel = self._kernel_auto(Theta)
+        if tables is None:
+            tables = self.tables(Theta.dtype)
         if self.kind == "dense":
-            return jnp.asarray(self.W, Theta.dtype)[idx] @ Theta
-        cols = jnp.asarray(self.idx)[idx]  # (B, K)
-        w = jnp.asarray(self.w, Theta.dtype)[idx]  # (B, K)
+            W = jnp.asarray(tables["W"], Theta.dtype)
+            return jnp.matmul(W[idx], Theta, precision=_HIGHEST)
+        cols = tables["idx"][idx]  # (B, K)
+        w = jnp.asarray(tables["w"], Theta.dtype)[idx]  # (B, K)
         if use_kernel:
             from repro.kernels import ops
 
             return ops.sparse_rows_mix(cols, w.astype(jnp.float32), Theta)
-        return jnp.einsum("bk,bkp->bp", w, Theta[cols])
+        return jnp.einsum("bk,bkp->bp", w, Theta[cols], precision=_HIGHEST)
 
-    def pairwise_smoothness(self, Theta):
-        """1/2 sum_{i<j} W_ij ||Theta_i - Theta_j||^2 (Eq. 2 first term)."""
+    def edge_tables(self) -> dict:
+        """The graph's weights for :meth:`pairwise_smoothness`, as device
+        arrays: the (n, n) matrix, or the sorted COO triples."""
         if self.kind == "dense":
-            W = jnp.asarray(self.W, Theta.dtype)
+            return {"W": jnp.asarray(self.W)}
+        return {
+            "rows": jnp.asarray(self.rows),
+            "cols": jnp.asarray(self.cols),
+            "vals": jnp.asarray(self.vals),
+        }
+
+    def pairwise_smoothness(self, Theta, tables=None):
+        """1/2 sum_{i<j} W_ij ||Theta_i - Theta_j||^2 (Eq. 2 first term).
+
+        ``tables``: :meth:`edge_tables`, passed in by a jitted caller (None
+        reads the operator's own arrays)."""
+        t = self.edge_tables() if tables is None else tables
+        if self.kind == "dense":
+            W = jnp.asarray(t["W"], Theta.dtype)
             diffs = Theta[:, None, :] - Theta[None, :, :]
             return 0.25 * jnp.sum(W * jnp.sum(diffs**2, axis=-1))
-        rows, cols = jnp.asarray(self.rows), jnp.asarray(self.cols)
-        d2 = jnp.sum((Theta[rows] - Theta[cols]) ** 2, axis=-1)
-        return 0.25 * jnp.sum(jnp.asarray(self.vals, Theta.dtype) * d2)
+        d2 = jnp.sum((Theta[t["rows"]] - Theta[t["cols"]]) ** 2, axis=-1)
+        return 0.25 * jnp.sum(jnp.asarray(t["vals"], Theta.dtype) * d2)
 
 
 _EXCHANGE_METHODS = ("all_gather", "p2p", "auto")
@@ -482,7 +517,7 @@ class ShardedMixOp:
         safe = jnp.minimum(rows, idx_s.shape[0] - 1)
         cols = idx_s[safe]  # (B, K)
         ww = jnp.asarray(w_s, Theta_ext.dtype)[safe]  # (B, K)
-        return jnp.einsum("bk,bkp->bp", ww, Theta_ext[cols])
+        return jnp.einsum("bk,bkp->bp", ww, Theta_ext[cols], precision=_HIGHEST)
 
 
 def sharded_mix_op(

@@ -50,14 +50,19 @@ class Loss:
     smoothness: callable  # (X, mask) -> float
 
 
+# Full f32 precision for the x . theta products (a TPU's default rounds
+# f32 matmul operands to bf16).
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _logistic_point_loss(theta, x, y):
-    margin = y * jnp.dot(x, theta)
+    margin = y * jnp.dot(x, theta, precision=_HIGHEST)
     # log(1 + exp(-m)) computed stably.
     return jnp.logaddexp(0.0, -margin)
 
 
 def _logistic_point_grad(theta, x, y):
-    margin = y * jnp.dot(x, theta)
+    margin = y * jnp.dot(x, theta, precision=_HIGHEST)
     return -y * jax.nn.sigmoid(-margin) * x
 
 
@@ -75,11 +80,11 @@ def _logistic_smoothness(X, mask):
 
 
 def _quadratic_point_loss(theta, x, y):
-    return jnp.square(jnp.dot(x, theta) - y)
+    return jnp.square(jnp.dot(x, theta, precision=_HIGHEST) - y)
 
 
 def _quadratic_point_grad(theta, x, y):
-    return 2.0 * (jnp.dot(x, theta) - y) * x
+    return 2.0 * (jnp.dot(x, theta, precision=_HIGHEST) - y) * x
 
 
 def _quadratic_lip_l1(X, mask):
@@ -164,8 +169,11 @@ class AgentData:
 class Objective:
     """Q(Theta) of Eq. 2, fully specified.
 
-    Construct via :func:`make_objective`. All jnp methods are jit-able; the
-    arrays stored here are treated as constants (closed over by jit).
+    Construct via :func:`make_objective`. All jnp methods are jit-able.
+    ``local_loss``/``local_grad``/``value`` pass the per-agent arrays and
+    the graph's weights to their jitted programs as arguments (see
+    :attr:`device_arrays`), so the program stays small at any n; the other
+    methods close over them as constants.
     """
 
     graph: AgentGraph | CSRGraph
@@ -239,46 +247,35 @@ class Objective:
             g = g * jnp.minimum(1.0, self.clip / jnp.maximum(norms, 1e-12))
         return g
 
-    @partial(jax.jit, static_argnums=0)
+    @cached_property
+    def device_arrays(self) -> dict:
+        """The per-agent arrays and the graph's weights as device arrays
+        (built eagerly even when first asked for inside a trace)."""
+        with jax.ensure_compile_time_eval():
+            return self._build_device_arrays()
+
+    def _build_device_arrays(self) -> dict:
+        return {
+            "X": jnp.asarray(self.data.X),
+            "y": jnp.asarray(self.data.y),
+            "mask": jnp.asarray(self.data.mask),
+            "lambdas": jnp.asarray(self.lambdas),
+            "degrees": jnp.asarray(self.degrees),
+            "confidences": jnp.asarray(self.confidences),
+            "edges": self.mix.edge_tables(),
+        }
+
     def local_loss(self, Theta):
         """L_i(Theta_i; S_i) for all agents: (n,) vector."""
+        return _local_loss(self, self.device_arrays, Theta)
 
-        def one(theta_i, X_i, y_i, mask_i, lam):
-            m = jnp.maximum(mask_i.sum(), 1.0)
-            vals = jax.vmap(lambda x, y: self.loss.point_loss(theta_i, x, y))(X_i, y_i)
-            return jnp.sum(vals * mask_i) / m + lam * jnp.sum(theta_i**2)
-
-        return jax.vmap(one)(
-            Theta,
-            jnp.asarray(self.data.X),
-            jnp.asarray(self.data.y),
-            jnp.asarray(self.data.mask),
-            jnp.asarray(self.lambdas),
-        )
-
-    @partial(jax.jit, static_argnums=0)
     def local_grad(self, Theta):
         """grad L_i(Theta_i; S_i) for all agents: (n, p)."""
+        return _local_grad(self, self.device_arrays, Theta)
 
-        def one(theta_i, X_i, y_i, mask_i, lam):
-            m = jnp.maximum(mask_i.sum(), 1.0)
-            g = self._point_grads(theta_i, X_i, y_i)
-            return jnp.sum(g * mask_i[:, None], axis=0) / m + 2.0 * lam * theta_i
-
-        return jax.vmap(one)(
-            Theta,
-            jnp.asarray(self.data.X),
-            jnp.asarray(self.data.y),
-            jnp.asarray(self.data.mask),
-            jnp.asarray(self.lambdas),
-        )
-
-    @partial(jax.jit, static_argnums=0)
     def value(self, Theta):
-        smooth = self.mix.pairwise_smoothness(Theta)
-        d = jnp.asarray(self.degrees)
-        c = jnp.asarray(self.confidences)
-        return smooth + self.mu * jnp.sum(d * c * self.local_loss(Theta))
+        """Q(Theta) of Eq. 2."""
+        return _value(self, self.device_arrays, Theta)
 
     @partial(jax.jit, static_argnums=0)
     def block_grad(self, Theta):
@@ -333,6 +330,37 @@ class Objective:
                 A[sl, j * p : (j + 1) * p] += -wij * np.eye(p)
         sol = np.linalg.solve(A, b)
         return sol.reshape(n, p)
+
+
+def _local_loss_from(obj: Objective, a: dict, Theta):
+    def one(theta_i, X_i, y_i, mask_i, lam):
+        m = jnp.maximum(mask_i.sum(), 1.0)
+        vals = jax.vmap(lambda x, y: obj.loss.point_loss(theta_i, x, y))(X_i, y_i)
+        return jnp.sum(vals * mask_i) / m + lam * jnp.sum(theta_i**2)
+
+    return jax.vmap(one)(Theta, a["X"], a["y"], a["mask"], a["lambdas"])
+
+
+@partial(jax.jit, static_argnums=0)
+def _local_loss(obj: Objective, a: dict, Theta):
+    return _local_loss_from(obj, a, Theta)
+
+
+@partial(jax.jit, static_argnums=0)
+def _local_grad(obj: Objective, a: dict, Theta):
+    def one(theta_i, X_i, y_i, mask_i, lam):
+        m = jnp.maximum(mask_i.sum(), 1.0)
+        g = obj._point_grads(theta_i, X_i, y_i)
+        return jnp.sum(g * mask_i[:, None], axis=0) / m + 2.0 * lam * theta_i
+
+    return jax.vmap(one)(Theta, a["X"], a["y"], a["mask"], a["lambdas"])
+
+
+@partial(jax.jit, static_argnums=0)
+def _value(obj: Objective, a: dict, Theta):
+    smooth = obj.mix.pairwise_smoothness(Theta, a["edges"])
+    local = _local_loss_from(obj, a, Theta)
+    return smooth + obj.mu * jnp.sum(a["degrees"] * a["confidences"] * local)
 
 
 def make_objective(

@@ -1,31 +1,17 @@
-"""Version-compat ``shard_map`` shim shared by the SPMD layers.
+"""The ``shard_map`` wrapper shared by the SPMD layers.
 
-jax moved ``shard_map`` from ``jax.experimental.shard_map`` (0.4.x) to the
-top-level ``jax`` namespace (>= 0.6) and renamed the replication-check
-keyword from ``check_rep`` to ``check_vma`` along the way. Both the
-synchronous scale layer (:mod:`repro.core.spmd`) and the sharded async
-engine (:mod:`repro.sim.engine`) need the same wrapper, so it lives here
-once instead of being copy-pasted per caller.
+Both the synchronous scale layer (:mod:`repro.core.spmd`) and the sharded
+async engine (:mod:`repro.sim.engine`) call ``jax.shard_map`` with the
+replication check off, so the call lives here once.
 """
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: public API, replication check renamed check_vma.
-    from jax import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
+import jax
 
 
 def shard_map(body, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with the replication check disabled, any jax version."""
-    return _shard_map_impl(
-        body,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_SHARD_MAP_CHECK_KW: False},
+    """``jax.shard_map`` with the replication check disabled."""
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

@@ -25,9 +25,10 @@ kernel fuses everything on-chip). The quadratic loss only: the logistic
 path keeps the unfused vmap (its exp/log1p inner loop gains nothing from
 fusion and the engines gate on ``loss.name``).
 
-Layout: grid over row tiles (bb rows per step). The wake-index and
-neighbour-index tables ride in SMEM via scalar prefetch so the kernel
-can issue data-dependent row gathers; the slab streams in once and stays
+Layout: grid over row tiles (bb rows per step). Each step's tile of the
+wake-index and neighbour-index tables is copied into SMEM (one tile at
+a time, so SMEM never holds the whole (B, K) table) and the kernel
+issues data-dependent row gathers from it; the slab streams in once and stays
 VMEM-resident; the output slab is initialized from it at step 0 and
 updated in place across grid steps (constant out-block index =>
 revisited VMEM buffer, one writeback at the end). Feature dim is a
@@ -45,13 +46,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.sparse_mix import _lane
+
 
 DEF_BB = 8  # woken rows per grid step (sublane multiple)
 
 
 def _fused_row_update_kernel(
-    B,
-    K,
     limit,
     clip,
     rows_ref,
@@ -66,9 +67,9 @@ def _fused_row_update_kernel(
     out_ref,
 ):
     step = pl.program_id(0)
-    bb = w_ref.shape[0]
-    b0 = step * bb
+    bb, K = w_ref.shape
     nt, pp = out_ref.shape
+    hi = jax.lax.Precision.HIGHEST
 
     @pl.when(step == 0)
     def _init_slab():
@@ -78,15 +79,15 @@ def _fused_row_update_kernel(
         out_ref[:, :] = theta_ref[:, :].astype(out_ref.dtype)
 
     def one_row(r, _):
-        b = b0 + r  # caller pads B to a tile multiple with sentinel rows
-        row = rows_ref[b]
+        row = rows_ref[r, 0]  # caller pads B to a tile multiple with sentinel rows
         grow = jnp.minimum(row, nt - 1)  # sentinel clamps for the gather
         tr = theta_ref[pl.ds(grow, 1), :].astype(jnp.float32)  # (1, pp)
+        w_row = w_ref[pl.ds(r, 1), :].astype(jnp.float32)  # (1, K)
 
         def neighbor(k, acc):
-            j = idx_ref[b, k]
+            j = idx_ref[r, k]
             contrib = theta_ref[pl.ds(j, 1), :].astype(jnp.float32)
-            return acc + w_ref[pl.ds(r, 1), pl.ds(k, 1)].astype(jnp.float32) * contrib
+            return acc + _lane(w_row, k) * contrib
 
         neigh = jax.lax.fori_loop(0, K, neighbor, jnp.zeros((1, pp), jnp.float32))
 
@@ -96,7 +97,8 @@ def _fused_row_update_kernel(
         # Per-point residuals 2 (x.th - y) — the quadratic point grad is
         # resid * x, so the clip/mask/mean pipeline stays rank-2 (1, m).
         dots = jax.lax.dot_general(
-            tr, Xr, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            tr, Xr, (((1,), (1,)), ((), ())), precision=hi,
+            preferred_element_type=jnp.float32,
         )  # (1, m)
         resid = 2.0 * (dots - yr)
         if clip is not None:
@@ -105,13 +107,15 @@ def _fused_row_update_kernel(
                 jnp.ones((1, pp), jnp.float32),
                 jnp.abs(Xr),
                 (((1,), (1,)), ((), ())),
+                precision=hi,
                 preferred_element_type=jnp.float32,
             )  # (1, m)
             norms = jnp.abs(resid) * abs_x
             resid = resid * jnp.minimum(1.0, clip / jnp.maximum(norms, 1e-12))
         m_hat = jnp.maximum(jnp.sum(mr), 1.0)
         g_sum = jax.lax.dot_general(
-            resid * mr, Xr, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            resid * mr, Xr, (((1,), (0,)), ((), ())), precision=hi,
+            preferred_element_type=jnp.float32,
         )  # (1, pp)
 
         alpha = coef_ref[pl.ds(r, 1), pl.ds(0, 1)]  # (1, 1) broadcasts below
@@ -165,26 +169,25 @@ def fused_row_update(
     bb = min(block_b, B)
     nb = pl.cdiv(B, bb)
     m = X.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # rows + neighbour indices ride in SMEM
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((bb, K), lambda b, *_: (b, 0)),
-            pl.BlockSpec((bb, coef.shape[1]), lambda b, *_: (b, 0)),
-            pl.BlockSpec((bb, m, p), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec((bb, m), lambda b, *_: (b, 0)),
-            pl.BlockSpec((bb, m), lambda b, *_: (b, 0)),
-            pl.BlockSpec((bb, p), lambda b, *_: (b, 0)),
-            pl.BlockSpec((nt, p), lambda b, *_: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((nt, p), lambda b, *_: (0, 0)),
-    )
+    smem = pltpu.SMEM
     kernel = functools.partial(
-        _fused_row_update_kernel, B, K, limit, None if clip is None else float(clip)
+        _fused_row_update_kernel, limit, None if clip is None else float(clip)
     )
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid=(nb,),
+        in_specs=[
+            pl.BlockSpec((bb, 1), lambda b: (b, 0), memory_space=smem),
+            pl.BlockSpec((bb, K), lambda b: (b, 0), memory_space=smem),
+            pl.BlockSpec((bb, K), lambda b: (b, 0)),
+            pl.BlockSpec((bb, coef.shape[1]), lambda b: (b, 0)),
+            pl.BlockSpec((bb, m, p), lambda b: (b, 0, 0)),
+            pl.BlockSpec((bb, m), lambda b: (b, 0)),
+            pl.BlockSpec((bb, m), lambda b: (b, 0)),
+            pl.BlockSpec((bb, p), lambda b: (b, 0)),
+            pl.BlockSpec((nt, p), lambda b: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((nt, p), lambda b: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((nt, p), jnp.float32),
         interpret=interpret,
-    )(rows.astype(jnp.int32), idx.astype(jnp.int32), w, coef, X, y, mask, noise, theta)
+    )(rows.astype(jnp.int32)[:, None], idx.astype(jnp.int32), w, coef, X, y, mask, noise, theta)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-DEF_BP = 256  # feature-tile width
+DEF_BP = 128  # feature-tile width
 DEF_BK = 128  # contraction tile
 
 
@@ -36,11 +36,15 @@ def _mix_kernel(a_ref, t_ref, out_ref):
 
 
 def graph_mix(mix, theta, block_p=DEF_BP, block_k=DEF_BK, interpret=False):
-    """mix: (n, n) float; theta: (n, p). Returns (n, p) float32."""
-    n, p = theta.shape
-    bk = min(block_k, n)
+    """mix: (n, nk) float; theta: (nk, p). Returns (n, p) float32.
+
+    nk (the contraction) is n, or n zero-padded to a multiple of block_k.
+    """
+    n, nk = mix.shape
+    p = theta.shape[1]
+    bk = min(block_k, nk)
     bp = min(block_p, p)
-    nb_k = pl.cdiv(n, bk)
+    nb_k = pl.cdiv(nk, bk)
     nb_p = pl.cdiv(p, bp)
     return pl.pallas_call(
         _mix_kernel,

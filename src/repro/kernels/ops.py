@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the TPU
-lowering is the target); ``INTERPRET`` flips automatically based on the
-backend so the same call sites run compiled on real TPUs.
+``interpret=None`` compiles the kernels for the TPU when JAX's backend is
+a TPU and interprets them anywhere else (the CPU test runs). The engine
+and mixing gates engage the kernels only on a TPU, so interpretation
+never stands in for the device on a program path.
 """
 
 from __future__ import annotations
@@ -21,6 +22,27 @@ from repro.kernels import ssm_scan as _ssk
 
 def _default_interpret():
     return jax.default_backend() != "tpu"
+
+
+# Scoped VMEM that Mosaic lets one kernel use on a TPU v5e by default.
+VMEM_LIMIT_BYTES = 16 * 2**20
+
+
+def _round_up(x: int, mult: int) -> int:
+    return -(-int(x) // mult) * mult
+
+
+def fused_row_update_fits(nt: int, p: int, m: int, block_b: int = 8) -> bool:
+    """Whether the fused kernel's blocks fit a v5e kernel's scoped VMEM.
+
+    Counts every block double-buffered, in f32 at its lane- and
+    sublane-padded size: the (nt, p) slab in and out, and each grid
+    step's (block_b, ...) tiles of data, weights (K < nt) and coefficients.
+    """
+    pp, m8 = _round_up(p, 128), _round_up(m, 8)
+    slab = _round_up(nt, 8) * pp
+    tiles = block_b * (m8 * pp + 2 * _round_up(m, 128) + _round_up(nt, 128) + 128 + pp)
+    return 4 * 2 * (2 * slab + tiles) <= VMEM_LIMIT_BYTES
 
 
 def _pad_to(x, mult, axis):
@@ -53,12 +75,21 @@ def dp_clip_noise(grads, noise, clip, noise_scale, block_n=128, block_d=512, int
 
 
 @functools.partial(jax.jit, static_argnames=("block_p", "block_k", "interpret"))
-def graph_mix(mix, theta, block_p=256, block_k=128, interpret=None):
-    """Y = mix @ theta. mix (n,n), theta (n,p) -> (n,p) float32."""
+def graph_mix(mix, theta, block_p=128, block_k=128, interpret=None):
+    """Y = mix @ theta. mix (n,n), theta (n,p) -> (n,p) float32.
+
+    The (n, block_p) output tile stays VMEM-resident across the
+    contraction, so its size, not p, bounds the VMEM the kernel needs.
+    """
     interpret = _default_interpret() if interpret is None else interpret
     n, p = theta.shape
     bp = min(block_p, max(128, p))
     t = _pad_to(theta, bp, 1)
+    if n > block_k:
+        # Zero-pad the contraction to whole tiles: a partial tile would
+        # read past the arrays' ends and add what it finds there.
+        mix = _pad_to(mix, block_k, 1)
+        t = _pad_to(t, block_k, 0)
     out = _gmk.graph_mix(mix, t, block_p=bp, block_k=block_k, interpret=interpret)
     return out[:, :p]
 

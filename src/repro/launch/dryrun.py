@@ -31,11 +31,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import AGENT_MODES, ARCH_IDS, SHAPES, get_config
 from repro.configs.base import P2PConfig
 from repro.core import spmd
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh, use_mesh
 from repro.models import build_model
 from repro.models.encdec import enc_len
 from repro.models.sharding import batch_specs, cache_specs, param_specs
 from repro.roofline.analysis import analyze_compiled
+from repro.roofline.peaks import peaks_for
 
 SLIDING_WINDOW_500K = 8192
 
@@ -206,10 +207,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, gossip="ppermute",
         set_activation_axes(None)
         set_seq_axis(None)
     mem = compiled.memory_analysis()
-    roof = analyze_compiled(
-        compiled, chips, meta["model_flops"],
-        peak_flops=PEAK_FLOPS_BF16, hbm_bw=HBM_BW, link_bw=ICI_BW,
-    )
+    # The dry run places the program on its launch target, a v5e pod.
+    roof = analyze_compiled(compiled, chips, meta["model_flops"], peaks_for("TPU v5 lite"))
     mem_row = {}
     for k in ("argument_size_in_bytes", "output_size_in_bytes",
               "temp_size_in_bytes", "generated_code_size_in_bytes",

@@ -10,37 +10,22 @@ this with meshes that fit.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6
-    from jax.sharding import AxisType
-
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}  # noqa: E731
-except ImportError:  # jax 0.4.x: meshes are Auto-typed implicitly
-    _AXIS_KW = lambda n: {}  # noqa: E731
+from jax.sharding import AxisType
 
 
 def use_mesh(mesh):
-    """Ambient-mesh context across jax versions.
-
-    jax >= 0.6 spells it ``jax.set_mesh``; on 0.4.x the Mesh object itself
-    is the (legacy global-mesh) context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """The ambient-mesh context (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    """A mesh with Auto axes: the SPMD layer leaves propagation to XLA
+    (``jax.make_mesh`` alone would make the axes Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
-
-# TPU v5e hardware constants used by the roofline analysis (launch target).
-PEAK_FLOPS_BF16 = 197e12  # per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link

@@ -25,6 +25,7 @@ from repro.configs.base import P2PConfig
 from repro.core import spmd
 from repro.data.synthetic import token_stream
 from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.runtime import enable_compile_cache
 from repro.models import build_model
 from repro.models.encdec import enc_len
 
@@ -68,6 +69,7 @@ def build(args):
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     dshape = tuple(int(x) for x in args.mesh.split("x"))
     n_dev = len(jax.devices())
     assert np.prod(dshape) <= n_dev, f"mesh {dshape} needs more than {n_dev} devices"

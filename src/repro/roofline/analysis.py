@@ -4,6 +4,9 @@
     memory term     = HLO_bytes_per_device / HBM_bw
     collective term = collective_bytes_per_device / link_bw
 
+The peaks come from :mod:`repro.roofline.peaks` for the chip the program
+is placed on; there is no default chip.
+
 ``cost_analysis()`` of an SPMD-partitioned executable reports per-device
 FLOPs/bytes, so the formulas above are the per-chip version of the spec's
 (global / (chips * bw)) — identical numbers.
@@ -116,8 +119,10 @@ class Roofline:
         return out
 
 
-def roofline_terms(cost, hlo_text, chips, model_flops_global,
-                   peak_flops=197e12, hbm_bw=819e9, link_bw=50e9) -> Roofline:
+def roofline_terms(cost, hlo_text, chips, model_flops_global, peaks) -> Roofline:
+    """Roofline from ``cost_analysis()`` and HLO text against ``peaks``
+    (a :class:`repro.roofline.peaks.Peaks`)."""
+    peak_flops, hbm_bw, link_bw = peaks.flops, peaks.hbm_bw, peaks.link_bw
     flops = float(cost.get("flops", 0.0))
     # cost_analysis reports "bytes accessed" (HBM traffic proxy).
     bytes_acc = float(cost.get("bytes accessed", 0.0))
@@ -144,18 +149,17 @@ def roofline_terms(cost, hlo_text, chips, model_flops_global,
     )
 
 
-def analyze_compiled(compiled, chips, model_flops_global, **kw) -> Roofline:
+def analyze_compiled(compiled, chips, model_flops_global, peaks) -> Roofline:
     """Primary path: loop-aware HLO parse (see hlo_parse.py) — XLA's
     cost_analysis() counts while bodies once, which under-reports any
     scan-over-layers program by ~num_layers x. The raw cost_analysis values
-    are attached for reference as ``xla_raw``."""
+    are attached for reference as ``xla_raw``. ``peaks``: the
+    :class:`repro.roofline.peaks.Peaks` of the chip it is placed on."""
     from repro.roofline.hlo_parse import analyze_hlo
 
     text = compiled.as_text()
     totals = analyze_hlo(text)
-    peak_flops = kw.get("peak_flops", 197e12)
-    hbm_bw = kw.get("hbm_bw", 819e9)
-    link_bw = kw.get("link_bw", 50e9)
+    peak_flops, hbm_bw, link_bw = peaks.flops, peaks.hbm_bw, peaks.link_bw
     cbytes = float(sum(totals.coll.values()))
     compute_s = totals.flops / peak_flops
     memory_s = totals.bytes / hbm_bw

@@ -15,10 +15,9 @@ MODEL_FLOPs numerator is the *useful* Eq. 4 arithmetic for the expected
 wakes per slot (residual + gradient + neighbour mix + axpy), so
 ``useful_ratio`` exposes padding waste from the static woken-row batch.
 
-Peak numbers default to one TPU v5e-class chip (197 TF/s, 819 GB/s HBM,
-50 GB/s link); pass ``peak_flops``/``hbm_bw``/``link_bw`` to re-place the
-same program on other hardware. On a CPU host the placement is still the
-TPU roofline — the HLO is the same program, only the peaks are nominal.
+The peaks are those of the device the engine runs on, from
+:mod:`repro.roofline.peaks`; on a device the table lacks (the CPU among
+them) the placement raises instead of borrowing another chip's peaks.
 """
 
 from __future__ import annotations
@@ -26,6 +25,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.roofline.analysis import Roofline, analyze_compiled
+from repro.roofline.peaks import device_peaks
 
 
 def model_flops_per_supertick(engine) -> float:
@@ -54,7 +54,7 @@ def model_flops_per_supertick(engine) -> float:
     return float(np.sum(probs * per_wake))
 
 
-def supertick_roofline(engine, state=None, steps: int = 8, **roofline_kw) -> Roofline:
+def supertick_roofline(engine, state=None, steps: int = 8) -> Roofline:
     """Compile ``steps`` super-ticks of ``engine`` and analyse the HLO.
 
     ``state`` defaults to a fresh zero-model ``init_state``; pass a real
@@ -66,14 +66,10 @@ def supertick_roofline(engine, state=None, steps: int = 8, **roofline_kw) -> Roo
     if state is None:
         state = engine.init_state(np.zeros((engine.n, engine.p)))
     steps = int(steps)
-    if hasattr(engine, "_static"):  # ShardedAsyncEngine
-        compiled = engine._chunk.lower(state, engine._static, steps).compile()
-        chips = int(engine.num_shards)
-    else:
-        compiled = engine._chunk.lower(state, steps).compile()
-        chips = 1
+    compiled = engine._chunk.lower(state, engine._static, steps).compile()
+    chips = int(getattr(engine, "num_shards", 1))
     model_flops = model_flops_per_supertick(engine) * steps
-    roof = analyze_compiled(compiled, chips, model_flops, **roofline_kw)
+    roof = analyze_compiled(compiled, chips, model_flops, device_peaks())
     roof.steps = steps
     return roof
 
@@ -84,7 +80,6 @@ def supertick_report(
     steps: int = 8,
     measured_s_per_tick: float | None = None,
     prefix: str = "roofline_supertick",
-    **roofline_kw,
 ) -> list:
     """CSV-style ``(name, value, note)`` rows for the bench summary.
 
@@ -93,7 +88,7 @@ def supertick_report(
     also emits the ``gap`` row (measured / bound — the "remaining gap"
     between the simulator and the bandwidth roofline).
     """
-    roof = supertick_roofline(engine, state=state, steps=steps, **roofline_kw)
+    roof = supertick_roofline(engine, state=state, steps=steps)
     bound_s = max(roof.compute_s, roof.memory_s, roof.collective_s) / max(steps, 1)
     note = (
         f"dominant={roof.dominant} compute={roof.compute_s / steps * 1e6:.3g}us "

@@ -6,9 +6,11 @@ Two modes, one JSON summary line on stdout (bench-style):
     # run(..., checkpoint_every=, checkpoint_dir=) or the examples
     python -m repro.serve --checkpoint-dir ckpts --batch 256 --requests 32
 
-    # live: train a synthetic swarm and serve it concurrently
-    python -m repro.serve --live --n 20000 --shards 8 --slots 6 \
-        --snapshot-every 2 --batch 256
+    # live: train a synthetic swarm and serve it concurrently (on the CPU,
+    # JAX_PLATFORMS=cpu splits the host into --shards devices; a chip
+    # host shards over its chips)
+    JAX_PLATFORMS=cpu python -m repro.serve --live --n 20000 --shards 8 \
+        --slots 6 --snapshot-every 2 --batch 256
 
 The live mode runs the engine in a background thread and keeps issuing
 batched ``predict`` calls against whatever version is newest — the
@@ -20,9 +22,10 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import threading
 import time
+
+from repro.launch.runtime import enable_compile_cache, force_host_devices
 
 
 def _parse(argv):
@@ -160,13 +163,11 @@ def _serve_live(args) -> int:
 def main(argv=None) -> int:
     """CLI entry point."""
     args = _parse(argv)
-    if args.live and args.shards > 1:
-        # Must land before jax initializes its backends; respects an
-        # externally-pinned XLA_FLAGS (the CI lanes set their own).
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            f"--xla_force_host_platform_device_count={args.shards}",
-        )
+    if args.live:
+        # Before JAX starts: a JAX_PLATFORMS=cpu run splits the host into
+        # --shards devices; a chip host keeps the chips it has.
+        force_host_devices(args.shards)
+    enable_compile_cache()
     if args.live:
         return _serve_live(args)
     return _serve_checkpoint(args)

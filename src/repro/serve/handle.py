@@ -153,7 +153,9 @@ def _gather_rows(tiles, sids, lids, w):
     whole read path, so no ``(n, p)`` intermediate can exist here.
     """
     rows = tiles[sids, lids].astype(w.dtype)  # (B, K, p)
-    return jnp.einsum("bk,bkp->bp", w, rows)
+    # Full f32 precision: a TPU's default would round the rows to bf16,
+    # and a warm row (weight 1) must come back exactly as published.
+    return jnp.einsum("bk,bkp->bp", w, rows, precision=jax.lax.Precision.HIGHEST)
 
 
 @partial(jax.jit, static_argnames=())

@@ -114,12 +114,21 @@ def test_sharded_super_tick_closes_over_no_per_agent_array():
         np.shape(c) for c in jaxpr.consts if hasattr(c, "shape") and np.size(c) >= n
     ]
     assert not leaked, f"replicated per-agent constants leaked into the super-tick: {leaked}"
-    # Sanity-check the check: the single-device engine's slot *does* close
-    # over the replicated data, so the probe can tell the difference.
+    # The single-device engine passes its O(n) tables as arguments too.
     eng1 = AsyncEngine(CDUpdate(obj), seed=0)
     s1 = eng1.init_state(np.zeros((n, obj.p)))
-    jaxpr1 = jax.make_jaxpr(eng1._slot_forced)(s1, jnp.ones(n, bool))
-    assert any(hasattr(c, "shape") and np.size(c) >= n for c in jaxpr1.consts)
+    jaxpr1 = jax.make_jaxpr(eng1._slot_forced)(s1, eng1._static, jnp.ones(n, bool))
+    leaked1 = [
+        np.shape(c) for c in jaxpr1.consts if hasattr(c, "shape") and np.size(c) >= n
+    ]
+    assert not leaked1, f"per-agent constants leaked into the single-device slot: {leaked1}"
+    # Sanity-check the check: a row step that gathers from the replicated
+    # arrays does close over them, so the probe can tell the difference.
+    from repro.core.coordinate_descent import eq4_rows
+
+    rows = jnp.arange(4)
+    jaxpr_rep = jax.make_jaxpr(lambda th: eq4_rows(obj, th, rows, th[rows]))(s1.Theta)
+    assert any(hasattr(c, "shape") and np.size(c) >= n for c in jaxpr_rep.consts)
 
 
 def test_default_batch_size_follows_owned_agents_under_relabel():

@@ -406,3 +406,39 @@ def test_propagation_update_converges_to_exact_solution():
     star = solve()
     assert np.abs(res.Theta - star).max() < 1e-6
     assert res.objective[-1] <= res.objective[0]
+
+
+# ---------------------------------------------------------------------------
+# TPU compile options of the super-tick scan
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "kind,rows,p,guarded",
+    [
+        ("TPU v5 lite", 1_000_000, 20, True),  # 96 MB slab: the shape that halted
+        ("TPU v5 lite", 700_000, 20, True),  # just past half the 128 MiB VMEM
+        ("TPU v5 lite", 600_000, 20, False),  # ran unguarded on the chip
+        ("TPU v5 lite", 1_000_000, 8, False),
+        ("TPU v7 unknown", 1_000_000, 20, False),  # no guard for this kind
+    ],
+)
+def test_scan_compiler_options_follow_slab_size(monkeypatch, kind, rows, p, guarded):
+    """The VMEM guard engages on a TPU from the model slab's compact size
+    alone: at half the chip's VMEM and up, and only on a kind it knows."""
+    from repro.sim import engine as engine_mod
+
+    class _Dev:
+        device_kind = kind
+
+    monkeypatch.setattr(engine_mod.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(engine_mod.jax, "devices", lambda *a: [_Dev()])
+    opts = engine_mod._scan_compiler_options(rows, p, jnp.float32)
+    want = engine_mod._VMEM_GUARD["TPU v5 lite"][0] if guarded else None
+    assert opts == want
+
+
+def test_scan_compiler_options_off_tpu():
+    from repro.sim.engine import _scan_compiler_options
+
+    assert _scan_compiler_options(10**7, 20, jnp.float32) is None

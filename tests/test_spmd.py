@@ -18,13 +18,13 @@ import pytest
 from repro.configs import get_reduced
 from repro.configs.base import P2PConfig
 from repro.core import spmd
-from repro.launch.mesh import use_mesh
+from repro.launch.mesh import make_mesh, use_mesh
 from repro.models import build_model
 from repro.models.sharding import batch_specs, cache_specs, param_specs
 
 
 def make_mesh_1dev():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.slow
