@@ -1,0 +1,7 @@
+"""Device time per super-tick of the ops in the obs.scatter span."""
+
+from bench import readers
+
+
+def read(ctx):
+    return readers.span_ms(ctx, ("scatter",))
