@@ -1,26 +1,17 @@
-"""Observability bench: telemetry overhead + super-tick phase attribution.
+"""Observability bench: what does telemetry cost?
 
-Two questions, answered on the 8-shard engine the roofline rows describe:
+The same sharded run (the 8-shard engine the roofline rows describe) is
+timed metrics-off and metrics-on (full :class:`repro.obs.MetricsSpec`);
+the ``obs_overhead`` row reports the steady-state super-tick overhead in
+percent. The acceptance target is <= 5% — the counters only re-reduce
+values the slot already computed, so most of the "overhead" is timing
+noise. Where the super-tick's time goes is read from a device trace by
+the benchmark under ``bench/`` (its ``obs.*`` scopes), not here.
 
-* **What does telemetry cost?** The same sharded run is timed metrics-off
-  and metrics-on (full :class:`repro.obs.MetricsSpec`); the ``obs_overhead``
-  row reports the steady-state super-tick overhead in percent. The
-  acceptance target is <= 5% — the counters only re-reduce values the slot
-  already computed, so most of the "overhead" is timing noise.
-* **Where does the super-tick's time go?** ``repro.obs.profile_supertick``
-  times the engine's jitted phase-prefix programs and differences them,
-  attributing the slot wall-clock to wake_sample / halo_publish /
-  halo_collective / halo_scatter / gather_mix / row_update / scatter /
-  finalize. The ``obs_phase_*`` rows decompose the measured super-tick the
-  ``sharded_roofline_supertick_gap`` row compares against its bandwidth
-  bound; ``obs_phase_total`` records the coverage (sum of phases vs the
-  independently measured full slot — within 15% by construction).
-
-Artifacts: a Chrome/Perfetto ``trace.json`` (host timing spans + the
-synthetic per-phase track) and a :class:`repro.obs.RunReport` JSONL with
-the drained counters and phase rows — render either with
-``python -m repro.obs.report``. Runs over every chip of a chip host, or
-with ``JAX_PLATFORMS=cpu`` over 8 forced host devices:
+Artifact: a :class:`repro.obs.RunReport` JSONL with the drained
+counters — render it with ``python -m repro.obs.report``. Runs over
+every chip of a chip host, or with ``JAX_PLATFORMS=cpu`` over 8 forced
+host devices:
 
     JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.bench_obs --n 50000
 """
@@ -69,16 +60,14 @@ def run(
     slot_wakes: float = 2048.0,
     seed: int = 0,
     exchange: str = "auto",
-    trace_out: str = "results/obs_trace.json",
     report_out: str = "results/obs_runreport.jsonl",
     verbose: bool = True,
 ):
-    """Measure telemetry overhead and phase attribution; write the artifacts."""
+    """Measure telemetry overhead; write the drained run's report."""
     import jax
 
     from benchmarks.bench_sparse_scale import _make_problem
     from repro.core.mixing import ExchangeSpec
-    from repro.obs import SpanRecorder, profile_supertick
     from repro.sim import CDUpdate, ShardedAsyncEngine
 
     if len(jax.devices()) < shards:
@@ -113,26 +102,20 @@ def run(
         )
     ]
 
-    # Drained run -> RunReport; phase profile -> trace + obs_phase_* rows
-    # decomposing the super-tick behind sharded_roofline_supertick_gap.
+    # Drained run -> RunReport.
     result = eng_on.run(
         np.zeros((n, p)), slots, metrics_every=max(slots // 2, 1)
     )
-    recorder = SpanRecorder()
-    prof = profile_supertick(eng_on, state=result.state, recorder=recorder)
-    result.report.add_phase_rows(prof.rows(prefix="obs_phase"))
-    for path in (trace_out, report_out):
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-    recorder.export_chrome_trace(trace_out)
+    d = os.path.dirname(report_out)
+    if d:
+        os.makedirs(d, exist_ok=True)
     result.report.to_jsonl(report_out)
     rows += result.report.bench_rows()
 
     if verbose:
         for name, v, note in rows:
             print(f"{name},{v:.4g},{note}")
-        print(f"# trace: {trace_out}  report: {report_out}", file=sys.stderr)
+        print(f"# report: {report_out}", file=sys.stderr)
     return rows
 
 
@@ -147,7 +130,6 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--exchange", default="auto",
                     help="ExchangeSpec string: method[:dtype[:ef]]")
-    ap.add_argument("--trace-out", default="results/obs_trace.json")
     ap.add_argument("--report-out", default="results/obs_runreport.jsonl")
     args = ap.parse_args(argv)
     shards = args.shards or default_shards(8)
@@ -159,7 +141,6 @@ def main(argv=None):
         slot_wakes=args.slot_wakes,
         seed=args.seed,
         exchange=args.exchange,
-        trace_out=args.trace_out,
         report_out=args.report_out,
     )
 

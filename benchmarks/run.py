@@ -173,10 +173,8 @@ def _bench_obs(full, rows, record):
         if full
         else dict(n=50_000, slots=6, slot_wakes=2048.0)
     )
-    # Telemetry overhead (metrics-on vs off, target <=5%) and the
-    # obs_phase_* decomposition of the super-tick behind the
-    # sharded_roofline_supertick_gap row; also writes the trace.json and
-    # RunReport JSONL artifacts under results/.
+    # Telemetry overhead (metrics-on vs off, target <=5%); also writes
+    # the RunReport JSONL artifact under results/.
     shards, sub = _shard_bench("benchmarks.bench_obs", "obs_", **kw)
     rows.extend(sub)
     over = next((v for name, v, _ in sub if name == "obs_overhead"), None)

@@ -398,27 +398,26 @@ class ShardedMixOp:
         q = jnp.clip(jnp.round(v / scale), -127.0, 127.0).astype(jnp.int8)
         return {"q": q, "scale": scale}, q.astype(v.dtype) * scale
 
-    def exchange_halo(self, Theta_local, ex, ef=None, *, upto=None, collect_stats=False):
+    def exchange_halo(self, Theta_local, ex, ef=None, *, collect_stats=False):
         """Extend this shard's (R, p) block with its halo rows.
 
         Runs inside ``shard_map``. ``ex`` is this shard's slice of
         :meth:`exchange_inputs` (leading S axis already consumed); ``ef``
         is this shard's (Bmax, p) error-feedback accumulator slice (None
-        when not threaded). Returns ``(out, ef_new, stats)`` where ``out``
-        for the full exchange (``upto=None``) is the (R + Hmax, p)
-        extended array the tiles index — halo slots past this shard's
-        real halo size are unreferenced by the tiles — and ``ef_new`` is
-        the updated accumulator (unchanged/None without error feedback).
+        when not threaded). Returns ``(Theta_ext, ef_new, stats)`` where
+        ``Theta_ext`` is the (R + Hmax, p) extended array the tiles index
+        — halo slots past this shard's real halo size are unreferenced by
+        the tiles — and ``ef_new`` is the updated accumulator
+        (unchanged/None without error feedback).
 
-        The exchange decomposes into three ``jax.named_scope`` phases the
-        super-tick profiler can cut at (``upto``):
+        The exchange decomposes into three ``jax.named_scope`` phases:
 
-        * ``"halo_publish"`` — gather/quantize/pack this shard's border
-          rows into the send payload (``out`` = the packed payload);
-        * ``"halo_collective"`` — the ``ppermute``s / ``all_gather`` that
-          ship it (``out`` = the received raw buffers);
-        * ``None`` (``"halo_scatter"``) — dequantize and place received
-          rows into the halo slots (``out`` = ``Theta_ext``).
+        * ``obs.halo_publish`` — gather/quantize/pack this shard's border
+          rows into the send payload;
+        * ``obs.halo_collective`` — the ``ppermute``s / ``all_gather`` that
+          ship it;
+        * ``obs.halo_scatter`` — dequantize and place received rows into
+          the halo slots.
 
         ``collect_stats=True`` on a compressed wire reports the
         telemetry dict ``{"quant_err_sq", "ef_residual_sq"}`` computed
@@ -461,8 +460,6 @@ class ShardedMixOp:
                 else:
                     send = payload["q"]
                     scales = payload.get("scale")
-        if upto == "halo_publish":
-            return (send, scales), ef_new, stats
 
         # -- collective: ship the payload.
         with jax.named_scope("obs.halo_collective"):
@@ -480,8 +477,6 @@ class ShardedMixOp:
                     jax.lax.all_gather(scales, self.axis) if scales is not None else None
                 )
                 got = (pool, pool_s)
-        if upto == "halo_collective":
-            return got, ef_new, stats
 
         # -- scatter: dequantize received rows into the halo slots.
         with jax.named_scope("obs.halo_scatter"):

@@ -1,17 +1,19 @@
-"""``repro.obs`` — in-jit engine telemetry, phase tracing, run reports.
+"""``repro.obs`` — in-jit engine telemetry, host spans, run reports.
 
 Three layers, importable in any combination:
 
 * :mod:`repro.obs.metrics` — :class:`MetricsSpec` selects counter
   groups; the engines carry the resulting metrics pytree through their
   jit-scanned super-ticks (``EngineConfig(metrics=...)``), so
-  collection adds no host round-trips and leaves Theta bit-exact;
-* :mod:`repro.obs.trace` — :class:`SpanRecorder` + Chrome-trace export
-  and :func:`profile_supertick`, which attributes a super-tick's
-  wall-clock to its named phases by prefix differencing;
-* :mod:`repro.obs.report` — :class:`RunReport` (periodic metric drains
-  + phase rows, JSONL round-trip) and the ``python -m repro.obs.report``
-  CLI that renders summaries and merges ``obs_*`` rows into
+  collection adds no host round-trips and leaves Theta bit-exact; and
+  the serving tier's host counters (:data:`SERVE_COUNTERS`);
+* :mod:`repro.obs.trace` — :func:`span`, the host spans the run driver
+  and the serving tier open at their boundaries. They land in a
+  ``jax.profiler`` trace on the device's clock; the super-tick's phases
+  are ``obs.*`` named scopes, found in the same trace through the HLO;
+* :mod:`repro.obs.report` — :class:`RunReport` (periodic metric drains,
+  JSONL round-trip) and the ``python -m repro.obs.report`` CLI that
+  renders summaries and merges ``obs_*`` rows into
   ``BENCH_summary.json``.
 """
 
@@ -26,12 +28,7 @@ from repro.obs.metrics import (
     topology_log_init,
 )
 from repro.obs.report import RunReport, merge_bench_summary
-from repro.obs.trace import (
-    PhaseProfile,
-    SpanRecorder,
-    profile_supertick,
-    validate_trace,
-)
+from repro.obs.trace import span
 
 __all__ = [
     "SERVE_COUNTERS",
@@ -39,13 +36,10 @@ __all__ = [
     "ExchangeVolume",
     "MetricsAccumulator",
     "MetricsSpec",
-    "PhaseProfile",
     "RunReport",
-    "SpanRecorder",
     "merge_bench_summary",
-    "profile_supertick",
     "serve_counters_init",
+    "span",
     "summarize_counters",
     "topology_log_init",
-    "validate_trace",
 ]

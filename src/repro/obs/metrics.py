@@ -336,12 +336,16 @@ SERVE_COUNTERS = (
     "serve_version_lag",  # gauge: newest published slot minus the slot just served
     "serve_version_lag_max",  # worst version lag any request observed
     "serve_publish_s_total",  # wall seconds spent publishing snapshots (float)
+    "serve_publish_sync_s_total",  # of which waiting for the slot counter (float)
+    "serve_route_s_total",  # wall seconds checking, routing and copying requests in (float)
+    "serve_fetch_s_total",  # wall seconds waiting for answers on the host (float)
 )
 
 
 def serve_counters_init() -> dict:
-    """A fresh host-side serving counter dict (all zeros)."""
-    return {k: (0.0 if k == "serve_publish_s_total" else 0) for k in SERVE_COUNTERS}
+    """A fresh host-side serving counter dict (all zeros; ``*_s_total``
+    seconds are floats)."""
+    return {k: (0.0 if k.endswith("_s_total") else 0) for k in SERVE_COUNTERS}
 
 
 def summarize_counters(snapshot: dict) -> dict:

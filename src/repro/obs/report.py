@@ -4,8 +4,7 @@ The drain side of the telemetry layer. The engines accumulate counters
 on-device (:mod:`repro.obs.metrics`); ``run(..., metrics_every=N)``
 drains them to the host every N slots and appends each drain to a
 :class:`RunReport` — the one artifact that holds a run's metadata, its
-counter trajectory, and (when a phase profile ran) the per-phase timing
-rows. Reports round-trip through JSONL (one ``kind``-tagged object per
+counter trajectory. Reports round-trip through JSONL (one ``kind``-tagged object per
 line, so files stream and append) and merge their rows into the
 ``BENCH_summary.json`` perf trajectory under ``obs_*`` names.
 
@@ -13,14 +12,12 @@ CLI::
 
     python -m repro.obs.report results/obs_runreport.jsonl
     python -m repro.obs.report report.jsonl --merge-bench BENCH_summary.json
-    python -m repro.obs.report --validate-trace results/obs_trace.json
 
 The first form renders the run summary table (metadata, final counter
-totals, per-phase attribution); ``--merge-bench`` folds the report's
-``obs_*`` rows into a bench summary file (same merge semantics as
-``benchmarks/run.py``, which imports :func:`merge_bench_summary` from
-here so the two writers cannot drift); ``--validate-trace`` asserts a
-Chrome ``trace.json`` loads and carries spans (the CI obs lane check).
+totals); ``--merge-bench`` folds the report's ``obs_*`` rows into a
+bench summary file (same merge semantics as ``benchmarks/run.py``, which
+imports :func:`merge_bench_summary` from here so the two writers cannot
+drift).
 """
 
 from __future__ import annotations
@@ -57,11 +54,10 @@ def merge_bench_summary(path: str, rows) -> None:
 
 @dataclasses.dataclass
 class RunReport:
-    """One run's telemetry: metadata, drained snapshots, phase rows."""
+    """One run's telemetry: metadata and drained snapshots."""
 
     meta: dict = dataclasses.field(default_factory=dict)
     snapshots: list = dataclasses.field(default_factory=list)
-    phase_rows: list = dataclasses.field(default_factory=list)
 
     def add_snapshot(self, slot: int, counters: dict, derived: dict | None = None):
         """Append one drained metrics snapshot (host-side dict of arrays)."""
@@ -73,24 +69,13 @@ class RunReport:
             }
         )
 
-    def add_phase_rows(self, rows) -> None:
-        """Attach per-phase bench rows (``(name, us, note)`` triples)."""
-        self.phase_rows.extend((str(n), float(v), str(note)) for n, v, note in rows)
-
     # -- serialization -----------------------------------------------------
     def to_jsonl(self, path: str) -> None:
-        """Write the report as kind-tagged JSONL (meta, snapshots, rows)."""
+        """Write the report as kind-tagged JSONL (meta, then snapshots)."""
         with open(path, "w") as f:
             f.write(json.dumps({"kind": "meta", **self.meta}) + "\n")
             for snap in self.snapshots:
                 f.write(json.dumps({"kind": "snapshot", **snap}) + "\n")
-            for name, value, note in self.phase_rows:
-                f.write(
-                    json.dumps(
-                        {"kind": "phase_row", "name": name, "value": value, "note": note}
-                    )
-                    + "\n"
-                )
 
     @classmethod
     def from_jsonl(cls, path: str) -> "RunReport":
@@ -107,21 +92,16 @@ class RunReport:
                     report.meta = obj
                 elif kind == "snapshot":
                     report.snapshots.append(obj)
-                elif kind == "phase_row":
-                    report.phase_rows.append((obj["name"], obj["value"], obj["note"]))
                 else:
                     raise ValueError(f"{path}: unknown report line kind {kind!r}")
         return report
 
     # -- rendering ---------------------------------------------------------
     def bench_rows(self) -> list:
-        """The report's contribution to ``BENCH_summary.json``.
-
-        Phase rows pass through as-is (they are already bench-shaped);
-        the final snapshot's scalar counters become ``obs_<counter>``
-        rows with the slot count in the note.
-        """
-        rows = list(self.phase_rows)
+        """The report's contribution to ``BENCH_summary.json``: the final
+        snapshot's scalar counters as ``obs_<counter>`` rows, with the
+        slot count in the note."""
+        rows = []
         if self.snapshots:
             last = self.snapshots[-1]
             for name, value in last["counters"].items():
@@ -143,10 +123,6 @@ class RunReport:
                 lines.append(f"  {k:<24} {v}")
             for k, v in sorted(last.get("derived", {}).items()):
                 lines.append(f"  {k:<24} {v}")
-        if self.phase_rows:
-            lines.append("== phases ==")
-            for name, value, note in self.phase_rows:
-                lines.append(f"  {name:<32} {value:>12.1f}us  {note}")
         return "\n".join(lines)
 
 
@@ -162,10 +138,10 @@ def main(argv=None) -> int:
     """Entry point for ``python -m repro.obs.report``."""
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs.report",
-        description="Render a RunReport JSONL, merge its rows into a bench "
-        "summary, or validate an exported trace.json.",
+        description="Render a RunReport JSONL and merge its rows into a bench "
+        "summary.",
     )
-    ap.add_argument("report", nargs="?", default=None, help="RunReport JSONL path")
+    ap.add_argument("report", help="RunReport JSONL path")
     ap.add_argument(
         "--merge-bench",
         action="append",
@@ -174,27 +150,13 @@ def main(argv=None) -> int:
         help="merge the report's obs_* rows into this BENCH_summary.json "
         "(repeatable; keeps the dual-written copies in sync)",
     )
-    ap.add_argument(
-        "--validate-trace",
-        default=None,
-        metavar="TRACE",
-        help="assert a Chrome trace.json loads and carries spans",
-    )
     args = ap.parse_args(argv)
-    if args.report is None and args.validate_trace is None:
-        ap.error("nothing to do: pass a report JSONL and/or --validate-trace")
-    if args.validate_trace is not None:
-        from repro.obs.trace import validate_trace
-
-        n = validate_trace(args.validate_trace)
-        print(f"{args.validate_trace}: valid Chrome trace, {n} spans")
-    if args.report is not None:
-        report = RunReport.from_jsonl(args.report)
-        print(report.summary_table())
-        rows = report.bench_rows()
-        for path in args.merge_bench:
-            merge_bench_summary(path, rows)
-            print(f"merged {len(rows)} obs rows into {path}")
+    report = RunReport.from_jsonl(args.report)
+    print(report.summary_table())
+    rows = report.bench_rows()
+    for path in args.merge_bench:
+        merge_bench_summary(path, rows)
+        print(f"merged {len(rows)} obs rows into {path}")
     return 0
 
 

@@ -1,187 +1,46 @@
-"""Super-tick phase tracing: host span recorder + prefix-timing profiler.
+"""Host spans on the profiler's clock.
 
-The ROADMAP's open ``sharded_roofline_supertick_gap`` row says how far
-the measured super-tick sits from its bandwidth bound, but not *which
-phase* — halo publish, collective, dequant/scatter, the fused row
-kernel, or the final scatter — eats the difference. Two mechanisms
-close that:
+The run driver and the serving tier mark the boundaries of their host
+work with :func:`span`, a ``jax.profiler.TraceAnnotation``. While a
+profiler trace runs (``jax.profiler.start_trace``), each span lands in
+it on the host thread that opened it, on the same clock as the device's
+ops, so an idle gap on the device can be put down to what the host was
+doing then. With no trace running a span records nothing and keeps
+nothing; entering one costs about a microsecond.
 
-* every engine phase body is wrapped in ``jax.named_scope`` (HLO-level
-  names, visible in XLA profiles) and the host-side driver sections in
-  ``jax.profiler.TraceAnnotation`` (visible in a live ``jax.profiler``
-  trace);
-* :func:`profile_supertick` measures per-phase wall-clock **by prefix
-  differencing**: the engines expose ``phase_program(upto)`` — the
-  jitted slot cut after a named phase, returning that phase's live
-  intermediates — so timing each prefix and differencing consecutive
-  prefixes attributes the pipeline time phase by phase. The phase times
-  sum to the full-slot time by construction (up to clamping of timing
-  noise), which is what lets them decompose the roofline gap row.
+The spans (one per call or per event, never per slot or per agent):
 
-:class:`SpanRecorder` collects named spans (both the real host timing
-sections and the synthetic per-phase attribution) and exports a
-Chrome/Perfetto-loadable ``trace.json``; :func:`validate_trace` is the
-loader the CI obs lane asserts with.
+================================  ==========================================
+``repro.run``                     one ``run()`` call of either engine
+``repro.run.advance``             the dispatch of one scan chunk
+``repro.run.<event>``             a periodic callback: ``publish``,
+                                  ``record``, ``drain``, ``checkpoint``
+``repro.run.topology``            a graph refresh or an admission
+``repro.run.result``              building ``SimResult`` (its host pulls)
+``repro.serve.publish``           ``ServeHandle.publish``
+``repro.serve.publish.sync``      its wait for the slot counter
+``repro.serve.predict``           ``ServeHandle.predict`` / ``rows``
+``repro.serve.route``             id checks, routing, host-to-device copies
+``repro.serve.score``             the dispatch of the serving program
+``repro.serve.fetch``             the wait for its result on the host
+================================  ==========================================
+
+Inside the jitted super-tick the phases are ``jax.named_scope("obs.*")``
+scopes instead; they reach a device trace through each op's HLO
+metadata. JAX's persistent compilation cache leaves that metadata out of
+its key by default, so a program that differs from a cached one only in
+its scopes would run the cached executable and be profiled under the
+other's scopes. Importing this module keys the cache on the metadata
+too.
 """
 
 from __future__ import annotations
 
-import contextlib
-import dataclasses
-import json
-import time
-
 import jax
-import numpy as np
+
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
-class SpanRecorder:
-    """Lightweight host-side span recorder with Chrome-trace export.
-
-    Spans land in the ``traceEvents`` "X" (complete-event) form; wall
-    times are ``time.perf_counter`` microseconds relative to the
-    recorder's creation. ``tid`` separates tracks (0 = live host spans,
-    1 = synthetic per-phase attribution).
-    """
-
-    def __init__(self):
-        self._t0 = time.perf_counter()
-        self.events: list[dict] = []
-
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
-
-    @contextlib.contextmanager
-    def span(self, name: str, tid: int = 0, **args):
-        """Record a live span around a host-side section (also annotated
-        for ``jax.profiler`` so device traces line up with ours)."""
-        start = self._now_us()
-        with jax.profiler.TraceAnnotation(name):
-            yield
-        self.add(name, start, self._now_us() - start, tid=tid, **args)
-
-    def add(self, name: str, start_us: float, dur_us: float, tid: int = 0, **args):
-        """Append one complete event (used for synthetic attribution spans)."""
-        self.events.append(
-            {
-                "name": name,
-                "ph": "X",
-                "ts": float(start_us),
-                "dur": float(max(dur_us, 0.0)),
-                "pid": 0,
-                "tid": int(tid),
-                "args": {k: _jsonable(v) for k, v in args.items()},
-            }
-        )
-
-    def export_chrome_trace(self, path: str) -> None:
-        """Write the collected spans as a Chrome/Perfetto ``trace.json``."""
-        with open(path, "w") as f:
-            json.dump(
-                {"traceEvents": self.events, "displayTimeUnit": "ms"}, f, indent=1
-            )
-
-
-def _jsonable(v):
-    if isinstance(v, (np.generic, np.ndarray)):
-        return np.asarray(v).tolist()
-    return v
-
-
-def validate_trace(path: str) -> int:
-    """Load a ``trace.json`` and return its span count.
-
-    Raises ``ValueError`` when the file is not a Chrome-trace object or
-    carries no spans — the assertion the CI obs lane runs on the
-    exported artifact.
-    """
-    with open(path) as f:
-        data = json.load(f)
-    events = data.get("traceEvents") if isinstance(data, dict) else None
-    if not isinstance(events, list) or not events:
-        raise ValueError(f"{path} is not a Chrome trace with events")
-    for e in events:
-        if not isinstance(e, dict) or "name" not in e or "ph" not in e:
-            raise ValueError(f"{path} carries a malformed trace event: {e!r}")
-    return len(events)
-
-
-@dataclasses.dataclass
-class PhaseProfile:
-    """Per-phase wall-clock attribution of one engine super-tick."""
-
-    phases: dict  # name -> seconds per slot (prefix-differenced)
-    total_s: float  # sum of the phase times (last prefix time + noise clamps)
-    measured_s: float  # independently timed full-slot wall-clock
-    prefix_s: dict  # name -> seconds of the cumulative prefix program
-
-    def rows(self, prefix: str = "obs_phase") -> list:
-        """CSV-style ``(name, us, note)`` rows for the bench summary."""
-        out = [
-            (f"{prefix}_{name}", s * 1e6, f"{100.0 * s / max(self.total_s, 1e-12):.1f}% of slot")
-            for name, s in self.phases.items()
-        ]
-        cov = self.total_s / max(self.measured_s, 1e-12)
-        out.append(
-            (
-                f"{prefix}_total",
-                self.total_s * 1e6,
-                f"sum of phases; measured full slot {self.measured_s * 1e6:.4g}us "
-                f"(coverage {cov:.2f})",
-            )
-        )
-        return out
-
-
-def profile_supertick(
-    engine,
-    state=None,
-    inner: int = 4,
-    repeats: int = 3,
-    recorder: SpanRecorder | None = None,
-) -> PhaseProfile:
-    """Attribute one sampled super-tick's wall-clock to its phases.
-
-    Times the engine's jitted phase-prefix programs (compile excluded:
-    each program is warmed before timing; best-of-``repeats`` over
-    ``inner``-call loops) and differences consecutive prefixes. A
-    ``recorder`` collects both the live timing spans and a synthetic
-    per-phase track laid out as one reconstructed super-tick; pass the
-    same recorder across calls to accumulate one trace file.
-    """
-    if state is None:
-        state = engine.init_state(np.zeros((engine.n, engine.p)))
-    recorder = SpanRecorder() if recorder is None else recorder
-    names = list(engine.phase_names)
-
-    def timed(fn, label):
-        out = fn(state)  # compile + warm
-        jax.block_until_ready(out)
-        best = float("inf")
-        for _ in range(repeats):
-            with recorder.span(f"obs.time.{label}"):
-                t0 = time.perf_counter()
-                for _ in range(inner):
-                    out = fn(state)
-                jax.block_until_ready(out)
-                best = min(best, (time.perf_counter() - t0) / inner)
-        return best
-
-    prefix_s = {}
-    for name in names:
-        prefix_s[name] = timed(engine.phase_program(name), f"prefix.{name}")
-    measured = timed(engine.phase_program(None), "full_slot")
-
-    phases, prev, cursor = {}, 0.0, 0.0
-    for name in names:
-        dt = max(prefix_s[name] - prev, 0.0)
-        phases[name] = dt
-        prev = prefix_s[name]
-        recorder.add(f"obs.phase.{name}", cursor * 1e6, dt * 1e6, tid=1)
-        cursor += dt
-    return PhaseProfile(
-        phases=phases,
-        total_s=sum(phases.values()),
-        measured_s=measured,
-        prefix_s=prefix_s,
-    )
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span named ``name``: use it as a context manager."""
+    return jax.profiler.TraceAnnotation(name)

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs.metrics import serve_counters_init
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,29 +224,34 @@ class ServeHandle:
                 "with ServeHandle.for_engine(engine) (checkpoint-served "
                 "handles are read-only)"
             )
-        t0 = time.perf_counter()
-        part = getattr(eng, "part", None)
-        if part is not None:
-            snap = ThetaSnapshot(
-                version=eng._ptr_of(state),
-                tiles=state.Theta,
-                shard_of=part.shard_of,
-                local_of=part.local_of,
-                pending=frozenset(eng._pending),
-            )
-        else:
-            snap = ThetaSnapshot(
-                version=eng._ptr_of(state),
-                tiles=state.Theta[None],
-                shard_of=None,
-                local_of=None,
-                pending=frozenset(eng._pending),
-            )
-        self._store.publish(snap)
-        dt = time.perf_counter() - t0
-        with self._lock:
-            self._counters["serve_snapshots_published"] += 1
-            self._counters["serve_publish_s_total"] += dt
+        with span("repro.serve.publish"):
+            t0 = time.perf_counter()
+            with span("repro.serve.publish.sync"):
+                version = eng._ptr_of(state)
+            sync = time.perf_counter() - t0
+            part = getattr(eng, "part", None)
+            if part is not None:
+                snap = ThetaSnapshot(
+                    version=version,
+                    tiles=state.Theta,
+                    shard_of=part.shard_of,
+                    local_of=part.local_of,
+                    pending=frozenset(eng._pending),
+                )
+            else:
+                snap = ThetaSnapshot(
+                    version=version,
+                    tiles=state.Theta[None],
+                    shard_of=None,
+                    local_of=None,
+                    pending=frozenset(eng._pending),
+                )
+            self._store.publish(snap)
+            dt = time.perf_counter() - t0
+            with self._lock:
+                self._counters["serve_snapshots_published"] += 1
+                self._counters["serve_publish_s_total"] += dt
+                self._counters["serve_publish_sync_s_total"] += sync
 
     # -- the read path -----------------------------------------------------
     def snapshot(self) -> ThetaSnapshot:
@@ -270,12 +276,13 @@ class ServeHandle:
         Warm ids return their snapshot row bit-exactly (bf16 tiles
         upcast exactly); cold ids return the Eq. 16 neighbour average.
         """
-        ids = self._check_ids(agent_ids)
-        snap = self._store.latest if at is None else at
-        sids, lids, w, cold = self._route(ids, snap, neighbors)
-        out = np.asarray(_gather_rows(snap.tiles, sids, lids, w))
-        self._account(ids.size, int(cold.sum()), snap.version)
-        return ServeResult(values=out, version=snap.version, cold=cold)
+        with span("repro.serve.predict"):
+            t0 = time.perf_counter()
+            with span("repro.serve.route"):
+                ids = self._check_ids(agent_ids)
+                snap = self._store.latest if at is None else at
+                sids, lids, w, cold = self._route(ids, snap, neighbors)
+            return self._answer(_gather_rows, (snap.tiles, sids, lids, w), ids, cold, snap, t0)
 
     def predict(self, agent_ids, X, neighbors=None, at=None) -> ServeResult:
         """Batched personalized predictions ``<theta_i, x_b>`` -> (B,).
@@ -288,18 +295,22 @@ class ServeHandle:
         spec, or the engine's arrival attachment map — and are scored
         on their Eq. 16 confidence-zero average row.
         """
-        ids = self._check_ids(agent_ids)
-        X = np.asarray(X)
-        if X.shape != (ids.size, self.p):
-            raise ValueError(
-                f"X must be (B, p) = ({ids.size}, {self.p}) to match "
-                f"agent_ids; got {X.shape}"
+        with span("repro.serve.predict"):
+            t0 = time.perf_counter()
+            with span("repro.serve.route"):
+                ids = self._check_ids(agent_ids)
+                X = np.asarray(X)
+                if X.shape != (ids.size, self.p):
+                    raise ValueError(
+                        f"X must be (B, p) = ({ids.size}, {self.p}) to match "
+                        f"agent_ids; got {X.shape}"
+                    )
+                snap = self._store.latest if at is None else at
+                sids, lids, w, cold = self._route(ids, snap, neighbors)
+                X = jnp.asarray(X)
+            return self._answer(
+                _score_rows, (snap.tiles, sids, lids, w, X), ids, cold, snap, t0
             )
-        snap = self._store.latest if at is None else at
-        sids, lids, w, cold = self._route(ids, snap, neighbors)
-        y = np.asarray(_score_rows(snap.tiles, sids, lids, w, jnp.asarray(X)))
-        self._account(ids.size, int(cold.sum()), snap.version)
-        return ServeResult(values=y, version=snap.version, cold=cold)
 
     # -- internals ---------------------------------------------------------
     def _check_ids(self, agent_ids) -> np.ndarray:
@@ -361,11 +372,28 @@ class ServeHandle:
             lids = snap.local_of[gids]
         return jnp.asarray(sids), jnp.asarray(lids), jnp.asarray(w), cold
 
-    def _account(self, batch: int, cold: int, served_version: int) -> None:
+    def _answer(self, program, args, ids, cold, snap, t0: float) -> ServeResult:
+        """Dispatch the routed request's serving ``program``, wait for its
+        answer on the host, and account for it (routing began at ``t0``)."""
+        routed = time.perf_counter()
+        with span("repro.serve.score"):
+            out = program(*args)
+        t1 = time.perf_counter()
+        with span("repro.serve.fetch"):
+            out = np.asarray(out)
+        fetch = time.perf_counter() - t1
+        self._account(ids.size, int(cold.sum()), snap.version, routed - t0, fetch)
+        return ServeResult(values=out, version=snap.version, cold=cold)
+
+    def _account(
+        self, batch: int, cold: int, served_version: int, route_s: float, fetch_s: float
+    ) -> None:
         lag = self._store.latest_version - served_version
         with self._lock:
             c = self._counters
             c["serve_requests"] += 1
+            c["serve_route_s_total"] += route_s
+            c["serve_fetch_s_total"] += fetch_s
             c["serve_predictions"] += batch
             c["serve_batch_rows_max"] = max(c["serve_batch_rows_max"], batch)
             c["serve_cold_starts"] += cold

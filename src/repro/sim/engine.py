@@ -57,6 +57,7 @@ from repro.core.mixing import kernel_max_n, sharded_mix_op
 from repro.core.model_propagation import propagation_rows_from
 from repro.core.spmd_compat import shard_map
 from repro.obs.metrics import ExchangeVolume, MetricsAccumulator, topology_log_init
+from repro.obs.trace import span
 from repro.sim import clocks
 from repro.sim.config import EngineConfig, resolve_config
 from repro.sim.partition import partition_graph
@@ -169,36 +170,48 @@ def _check_recordable(update, record_every: int) -> None:
         )
 
 
+def _advance(advance, state, steps: int):
+    """One scan-chunk dispatch, in its ``repro.run.advance`` span."""
+    with span("repro.run.advance"):
+        return advance(state, steps)
+
+
+def _fire(name: str, cb, state) -> None:
+    """One periodic callback, in its ``repro.run.<name>`` span."""
+    with span(f"repro.run.{name}"):
+        cb(state)
+
+
 def _drive_slots(state, slots: int, stride: int, advance, events=()):
     """Shared chunked driver for both engines: run ``slots`` super-ticks
     through ``advance(state, steps)`` in ``stride``-sized chunks, reusing
     a length-1 scan for the tail so only two scan lengths ever compile
-    (not one per remainder). ``events`` is a list of ``(every, callback)``
-    pairs; each callback fires with the state whenever the completed slot
-    count hits a multiple of its period (and once more at the end when
-    ``slots`` is not a multiple — a run always closes with a final
-    record/drain). ``stride`` must divide every period, or fire points
-    fall between chunks (callers pass the gcd)."""
-    events = [(int(every), cb) for every, cb in events if cb is not None and every > 0]
+    (not one per remainder). ``events`` is a list of ``(name, every,
+    callback)`` triples; each callback fires with the state whenever the
+    completed slot count hits a multiple of its period (and once more at
+    the end when ``slots`` is not a multiple — a run always closes with a
+    final record/drain). ``stride`` must divide every period, or fire
+    points fall between chunks (callers pass the gcd)."""
     done = 0
     while done < slots:
         steps = min(stride, slots - done)
         if steps == stride:
-            state = advance(state, stride)
+            state = _advance(advance, state, stride)
         else:
             for _ in range(steps):
-                state = advance(state, 1)
+                state = _advance(advance, state, 1)
         done += steps
-        for every, cb in events:
+        for name, every, cb in events:
             if done % every == 0 or done == slots:
-                cb(state)
+                _fire(name, cb, state)
     return state
 
 
 def _event_stride(events, default: int) -> int:
-    """The chunk stride serving ``(every, cb)`` events: gcd of the periods
-    (so every fire point lands on a chunk boundary), or ``default``."""
-    periods = [int(every) for every, cb in events if cb is not None and every > 0]
+    """The chunk stride serving ``(name, every, cb)`` events: gcd of the
+    periods (so every fire point lands on a chunk boundary), or
+    ``default``."""
+    periods = [every for _, every, _ in events]
     return math.gcd(*periods) if periods else default
 
 
@@ -221,79 +234,88 @@ def _run_driver(
 
     Validates the periodic-side-effect arguments (identical error
     messages from either engine), registers each requested side effect
-    as an ``(every, callback)`` event — objective recording, metric
-    drains into a :class:`repro.obs.RunReport`, crash-safe checkpoints,
-    and serving-snapshot publication into a
-    :class:`repro.serve.ServeHandle` — then drives the slots through
-    the static chunked driver or the dynamic segment driver. Returns
-    ``(state, objective, report)``; each engine assembles its own
-    :class:`SimResult` from them.
+    as a ``(name, every, callback)`` event — objective recording
+    (``record``), metric drains into a :class:`repro.obs.RunReport`
+    (``drain``), crash-safe checkpoints (``checkpoint``), and
+    serving-snapshot publication into a :class:`repro.serve.ServeHandle`
+    (``publish``) — then drives the slots through the static chunked
+    driver or the dynamic segment driver, and returns the engine's
+    :class:`SimResult` (``engine._result``). The whole call is the host
+    span ``repro.run``, with the driver's spans nested in it (see
+    :mod:`repro.obs.trace`).
 
     When serving is on, the handle also publishes once *before* the
     first slot, so readers have a (version = starting slot) snapshot
     during the first ``snapshot_every`` slots of a live run.
     """
-    _check_recordable(engine.update, record_every)
-    if metrics_every > 0 and engine._macc is None:
-        raise ValueError(
-            "metrics_every requires metrics collection on; construct the "
-            "engine with EngineConfig(metrics=True) (or a MetricsSpec)"
-        )
-    if (checkpoint_every > 0) != (checkpoint_dir is not None):
-        raise ValueError(
-            "checkpoint_every and checkpoint_dir come together: pass both "
-            "(periodic checkpoints) or neither"
-        )
-    if (snapshot_every > 0) != (serve is not None):
-        raise ValueError(
-            "snapshot_every and serve come together: pass both (a "
-            "repro.serve.ServeHandle receiving the published snapshots) "
-            "or neither"
-        )
-    state = engine.init_state(Theta0) if state is None else state
-    record = record_every > 0
-    objective = [engine._objective_value(state)] if record else None
-    if metrics_every > 0 and report is None:
-        from repro.obs.report import RunReport
-
-        report = RunReport(meta=engine.report_meta())
-    events = []
-    if record:
-        events.append(
-            (record_every, lambda s: objective.append(engine._objective_value(s)))
-        )
-    if metrics_every > 0:
-
-        def _drain(s):
-            counters, derived = engine.metrics_snapshot(s)
-            report.add_snapshot(engine._ptr_of(s), counters, derived)
-
-        events.append((metrics_every, _drain))
-    if checkpoint_every > 0:
-        from repro.checkpoint.engine_io import save_engine_checkpoint
-
-        events.append(
-            (
-                checkpoint_every,
-                lambda s: save_engine_checkpoint(
-                    engine, s, checkpoint_dir, keep_last=checkpoint_keep_last
-                ),
+    with span("repro.run"):
+        _check_recordable(engine.update, record_every)
+        if metrics_every > 0 and engine._macc is None:
+            raise ValueError(
+                "metrics_every requires metrics collection on; construct the "
+                "engine with EngineConfig(metrics=True) (or a MetricsSpec)"
             )
-        )
-    if snapshot_every > 0:
-        serve.publish(state)
-        events.append((snapshot_every, serve.publish))
-    if engine.dynamic:
-        state = _drive_dynamic(engine, state, slots, events, engine.advance)
-    else:
-        state = _drive_slots(
-            state,
-            slots,
-            _event_stride(events, engine.steps_per_chunk),
-            engine.advance,
-            events,
-        )
-    return state, objective, report
+        if (checkpoint_every > 0) != (checkpoint_dir is not None):
+            raise ValueError(
+                "checkpoint_every and checkpoint_dir come together: pass both "
+                "(periodic checkpoints) or neither"
+            )
+        if (snapshot_every > 0) != (serve is not None):
+            raise ValueError(
+                "snapshot_every and serve come together: pass both (a "
+                "repro.serve.ServeHandle receiving the published snapshots) "
+                "or neither"
+            )
+        state = engine.init_state(Theta0) if state is None else state
+        record = record_every > 0
+        objective = [engine._objective_value(state)] if record else None
+        if metrics_every > 0 and report is None:
+            from repro.obs.report import RunReport
+
+            report = RunReport(meta=engine.report_meta())
+        events = []
+        if record:
+            events.append(
+                (
+                    "record",
+                    record_every,
+                    lambda s: objective.append(engine._objective_value(s)),
+                )
+            )
+        if metrics_every > 0:
+
+            def _drain(s):
+                counters, derived = engine.metrics_snapshot(s)
+                report.add_snapshot(engine._ptr_of(s), counters, derived)
+
+            events.append(("drain", metrics_every, _drain))
+        if checkpoint_every > 0:
+            from repro.checkpoint.engine_io import save_engine_checkpoint
+
+            events.append(
+                (
+                    "checkpoint",
+                    checkpoint_every,
+                    lambda s: save_engine_checkpoint(
+                        engine, s, checkpoint_dir, keep_last=checkpoint_keep_last
+                    ),
+                )
+            )
+        if snapshot_every > 0:
+            _fire("publish", serve.publish, state)
+            events.append(("publish", snapshot_every, serve.publish))
+        if engine.dynamic:
+            state = _drive_dynamic(engine, state, slots, events, engine.advance)
+        else:
+            state = _drive_slots(
+                state,
+                slots,
+                _event_stride(events, engine.steps_per_chunk),
+                engine.advance,
+                events,
+            )
+        with span("repro.run.result"):
+            return engine._result(state, objective, report)
 
 
 # ---------------------------------------------------------------------------
@@ -425,7 +447,7 @@ def _drive_dynamic(engine, state, slots: int, events, advance):
     """Segment driver for dynamic-topology runs (both engines).
 
     Splits the run at every absolute slot where anything fires — the
-    periodic ``(every, cb)`` events, a :class:`GraphUpdate` refresh, or a
+    periodic ``(name, every, cb)`` events, a :class:`GraphUpdate` refresh, or a
     scheduled arrival — advances between the fire points with the shared
     chunked driver, and applies the topology work at the boundaries
     (graph changes land between super-ticks, never inside a scan). Order
@@ -437,7 +459,7 @@ def _drive_dynamic(engine, state, slots: int, events, advance):
     start = engine._ptr_of(state)
     end = start + slots
     points = {end}
-    for every, _cb in events:
+    for _, every, _ in events:
         points.update(range(start + every, end, every))
     if gu is not None:
         # The refresh grid is *absolute* (multiples of gu.every in slot
@@ -467,7 +489,8 @@ def _drive_dynamic(engine, state, slots: int, events, advance):
         # first super-tick. The edge_refreshes count disambiguates a
         # pre-refresh save (end-of-segment) from a post-refresh one
         # (interior event at the same slot).
-        state = engine._refresh_topology(state, start // gu.every)
+        with span("repro.run.topology"):
+            state = engine._refresh_topology(state, start // gu.every)
     prev = start
     for t in sorted(points):
         if t > prev:
@@ -475,12 +498,14 @@ def _drive_dynamic(engine, state, slots: int, events, advance):
         prev = t
         rel = t - start
         if gu is not None and start < t < end and t % gu.every == 0:
-            state = engine._refresh_topology(state, t // gu.every)
+            with span("repro.run.topology"):
+                state = engine._refresh_topology(state, t // gu.every)
         if t in admissions:
-            state = engine.admit(state, admissions[t])
-        for every, cb in events:
+            with span("repro.run.topology"):
+                state = engine.admit(state, admissions[t])
+        for name, every, cb in events:
             if rel % every == 0 or t == end:
-                cb(state)
+                _fire(name, cb, state)
     return state
 
 
@@ -577,12 +602,6 @@ class AsyncEngine:
                 dp_limit=getattr(update, "planned_Ti", None),
             )
         )
-        if self.fused:
-            self._phases = ("wake_sample", "fused_row_update", "finalize")
-        else:
-            self._phases = ("wake_sample", "gather_mix", "row_update", "scatter", "finalize")
-        self._phase_cache: dict = {}
-
         scan_options = _scan_compiler_options(self.n, self.p, self.dtype)
         self._chunk = jax.jit(
             self._chunk_impl, static_argnums=2, compiler_options=scan_options
@@ -709,12 +728,9 @@ class AsyncEngine:
         return engine_state_dict(self, state, step=step)
 
     # -- one super-tick ----------------------------------------------------
-    def _slot(self, state: SimState, static: dict, wake_mask, upto: str | None = None):
+    def _slot(self, state: SimState, static: dict, wake_mask) -> SimState:
         """One super-tick against the ``static`` tables (see
-        :meth:`_static_tables`). ``upto`` cuts the pipeline after a named
-        phase and returns that phase's live intermediates — the prefix
-        programs :func:`repro.obs.profile_supertick` times; None runs the
-        full slot."""
+        :meth:`_static_tables`)."""
         n, B = self.n, self.batch_size
         with jax.named_scope("obs.wake_sample"):
             key, k_leave, k_rejoin, k_wake, k_strag, k_upd = jax.random.split(
@@ -742,13 +758,12 @@ class AsyncEngine:
             woken = jnp.nonzero(wake, size=B, fill_value=n)[0].astype(jnp.int32)
             valid = woken < n
             dropped = total - valid.sum().astype(jnp.int32)
-        if upto == "wake_sample":
-            return wake, woken, valid, dropped, active
 
         Theta = state.Theta
         safe = jnp.minimum(woken, n - 1)
         consts = static.get("consts")
-        consts_rows = None if consts is None else jax.tree.map(lambda t: t[safe], consts)
+        with jax.named_scope("obs.row_gather"):
+            consts_rows = None if consts is None else jax.tree.map(lambda t: t[safe], consts)
         if self.fused and self._delays is None:
             with jax.named_scope("obs.fused_row_update"):
                 # One Pallas launch: gather + mix + Eq. 4/6 + drop-mode scatter.
@@ -760,8 +775,6 @@ class AsyncEngine:
                     srows=woken, ssize=n, consts=consts_rows,
                 )
                 Theta = new_slab.astype(Theta.dtype)
-            if upto == "fused_row_update":
-                return Theta, applied
         else:
             with jax.named_scope("obs.gather_mix"):
                 if self._delays is not None:
@@ -775,8 +788,6 @@ class AsyncEngine:
                 else:
                     hist = state.hist
                     neigh = self.update.mix.gather_rows(Theta, woken, tables=static["mix"])
-            if upto == "gather_mix":
-                return neigh
 
             with jax.named_scope("obs.row_update"):
                 if consts_rows is None:
@@ -788,14 +799,10 @@ class AsyncEngine:
                         Theta[safe], woken, valid, neigh, k_upd, state.ustate,
                         srows=woken, ssize=n, consts=consts_rows,
                     )
-            if upto == "row_update":
-                return new_rows, applied
 
             with jax.named_scope("obs.scatter"):
                 tgt = jnp.where(applied, woken, n)
                 Theta = Theta.at[tgt].set(new_rows.astype(Theta.dtype), mode="drop")
-            if upto == "scatter":
-                return Theta
 
         with jax.named_scope("obs.finalize"):
             deg = static["deg"][safe]
@@ -907,8 +914,9 @@ class AsyncEngine:
             cols = tiles["idx"][safe]  # (B, cap)
             w = jnp.asarray(tiles["w"], Theta.dtype)[safe]  # (B, cap)
             neigh = jnp.einsum("bk,bkp->bp", w, Theta[cols], precision=_HIGHEST)
-        with jax.named_scope("obs.row_update"):
+        with jax.named_scope("obs.row_gather"):
             consts_rows = jax.tree.map(lambda t: t[safe], tiles["consts"])
+        with jax.named_scope("obs.row_update"):
             new_rows, applied, ustate = self.update.apply_rows(
                 Theta[safe], woken, valid, neigh, k_upd, state.ustate,
                 srows=woken, ssize=n, consts=consts_rows,
@@ -1042,30 +1050,6 @@ class AsyncEngine:
         return dict(self.topology_log)
 
     # -- observability -----------------------------------------------------
-    @property
-    def phase_names(self) -> tuple:
-        """The named super-tick phases, in pipeline order."""
-        return self._phases
-
-    def phase_program(self, upto: str | None = None):
-        """The jitted sampled slot cut after phase ``upto`` (None = full).
-
-        The prefix programs :func:`repro.obs.profile_supertick` times and
-        differences to attribute the super-tick's wall-clock phase by
-        phase; each returns the cut phase's live intermediates so XLA
-        cannot dead-code-eliminate the prefix.
-        """
-        if self.dynamic:
-            raise NotImplementedError(
-                "phase profiling serves the static-topology path only"
-            )
-        if upto is not None and upto not in self._phases:
-            raise ValueError(f"unknown phase {upto!r} (have {self._phases})")
-        if upto not in self._phase_cache:
-            fn = jax.jit(lambda state, static: self._slot(state, static, None, upto=upto))
-            self._phase_cache[upto] = lambda state: fn(state, self._static)
-        return self._phase_cache[upto]
-
     def metrics_snapshot(self, state: SimState) -> tuple:
         """Drain the device counters: ``(counters, derived)`` host dicts.
 
@@ -1157,7 +1141,7 @@ class AsyncEngine:
         All three periodic arguments share one event loop — see
         ``_run_driver``.
         """
-        state, objective, report = _run_driver(
+        return _run_driver(
             self,
             Theta0,
             slots,
@@ -1171,10 +1155,12 @@ class AsyncEngine:
             snapshot_every=snapshot_every,
             serve=serve,
         )
-        record = record_every > 0
+
+    def _result(self, state: SimState, objective, report) -> SimResult:
+        """The run's :class:`SimResult`: pulls the counters and models."""
         return SimResult(
             Theta=np.asarray(state.Theta),
-            objective=np.asarray(objective) if record else None,
+            objective=None if objective is None else np.asarray(objective),
             messages=float(state.messages),
             wakes_applied=int(state.applied),
             wakes_dropped=int(state.dropped),
@@ -1417,13 +1403,6 @@ class ShardedAsyncEngine:
         )
         self._use_ef = self.smix.error_feedback
 
-        halo = ("wake_sample", "halo_publish", "halo_collective", "halo_scatter")
-        if self.fused:
-            self._phases = halo + ("fused_row_update", "finalize")
-        else:
-            self._phases = halo + ("gather_mix", "row_update", "scatter", "finalize")
-        self._phase_cache: dict = {}
-
         self._chunk = jax.jit(
             self._chunk_impl,
             static_argnums=2,
@@ -1642,14 +1621,9 @@ class ShardedAsyncEngine:
 
     # -- one shard-local super-tick ----------------------------------------
     def _slot_local(
-        self, state: ShardedSimState, static: _ShardStatic, wake_mask, upto=None
-    ):
-        """One slot on one shard (arrays carry the local leading dim 1).
-
-        ``upto`` cuts the SPMD pipeline after a named phase and returns
-        that phase's live intermediates (without the leading shard dim —
-        :meth:`phase_program` re-wraps them); None runs the full slot.
-        """
+        self, state: ShardedSimState, static: _ShardStatic, wake_mask
+    ) -> ShardedSimState:
+        """One slot on one shard (arrays carry the local leading dim 1)."""
         n, R, Bs = self.n, self.part.rows_per_shard, self.batch_size
         with jax.named_scope("obs.wake_sample"):
             key, k_leave, k_rejoin, k_wake, k_strag, k_upd = jax.random.split(
@@ -1679,32 +1653,24 @@ class ShardedAsyncEngine:
             woken = jnp.nonzero(wake, size=Bs, fill_value=R)[0].astype(jnp.int32)
             valid = woken < R
             dropped = total - valid.sum().astype(jnp.int32)
-        if upto == "wake_sample":
-            return wake, woken, valid, dropped, active
 
         Theta = state.Theta[0]
         ex = jax.tree.map(lambda a: a[0], static.exchange)
         ef = state.ef[0] if self._use_ef else None
         collect_stats = self._macc is not None and self._macc.quantized
-        if upto in ("halo_publish", "halo_collective"):
-            out, _, _ = self.smix.exchange_halo(
-                Theta, ex, ef, upto=upto, collect_stats=collect_stats
-            )
-            return out
         Theta_ext, ef_new, quant_stats = self.smix.exchange_halo(
             Theta, ex, ef, collect_stats=collect_stats
         )
-        if upto == "halo_scatter":
-            return Theta_ext
 
         safe = jnp.minimum(woken, R - 1)
         grows = jnp.where(valid, static.owned[0][safe], n)  # global ids, sentinel n
         ustate = jax.tree.map(lambda x: x[0], state.ustate)
-        consts_rows = (
-            None
-            if static.consts is None
-            else jax.tree.map(lambda t: t[0][safe], static.consts)
-        )
+        with jax.named_scope("obs.row_gather"):
+            consts_rows = (
+                None
+                if static.consts is None
+                else jax.tree.map(lambda t: t[0][safe], static.consts)
+            )
         if self.fused:
             with jax.named_scope("obs.fused_row_update"):
                 # One Pallas launch over the halo-extended slab: gather + mix
@@ -1716,27 +1682,19 @@ class ShardedAsyncEngine:
                     srows=woken, ssize=R, consts=consts_rows,
                 )
                 Theta = new_ext[:R].astype(Theta.dtype)
-            if upto == "fused_row_update":
-                return Theta, applied
         else:
             with jax.named_scope("obs.gather_mix"):
                 neigh = self.smix.gather_rows(
                     Theta_ext, static.idx[0], static.w[0], woken
                 )
-            if upto == "gather_mix":
-                return neigh
             with jax.named_scope("obs.row_update"):
                 new_rows, applied, ustate = self.update.apply_rows(
                     Theta[safe], grows, valid, neigh, k_upd, ustate,
                     srows=woken, ssize=R, consts=consts_rows,
                 )
-            if upto == "row_update":
-                return new_rows, applied
             with jax.named_scope("obs.scatter"):
                 tgt = jnp.where(applied, woken, R)
                 Theta = Theta.at[tgt].set(new_rows.astype(Theta.dtype), mode="drop")
-            if upto == "scatter":
-                return Theta
 
         with jax.named_scope("obs.finalize"):
             messages = state.messages[0] + jnp.sum(
@@ -1959,40 +1917,6 @@ class ShardedAsyncEngine:
         return dict(self.topology_log)
 
     # -- observability -----------------------------------------------------
-    @property
-    def phase_names(self) -> tuple:
-        """The named super-tick phases, in SPMD pipeline order."""
-        return self._phases
-
-    def phase_program(self, upto: str | None = None):
-        """The jitted sampled slot cut after phase ``upto`` (None = full).
-
-        Same contract as :meth:`AsyncEngine.phase_program`; the cut runs
-        as the full ``shard_map`` program (collectives included), with
-        the static tiles passed as inputs — never closed over — so the
-        prefix measures what the real slot pays.
-        """
-        if upto is not None and upto not in self._phases:
-            raise ValueError(f"unknown phase {upto!r} (have {self._phases})")
-        if upto not in self._phase_cache:
-
-            def local(s, st):
-                out = self._slot_local(s, st, None, upto)
-                if upto is not None:
-                    out = jax.tree.map(lambda a: a[None], out)
-                return out
-
-            fn = jax.jit(
-                lambda state, static: shard_map(
-                    local,
-                    mesh=self.mesh,
-                    in_specs=(P("shards"), P("shards")),
-                    out_specs=P("shards"),
-                )(state, static)
-            )
-            self._phase_cache[upto] = lambda state: fn(state, self._static)
-        return self._phase_cache[upto]
-
     def metrics_snapshot(self, state: ShardedSimState) -> tuple:
         """Drain the device counters: ``(counters, derived)`` host dicts.
 
@@ -2067,7 +1991,7 @@ class ShardedAsyncEngine:
         serve=None,
     ) -> SimResult:
         """Drive ``slots`` super-ticks; same contract as :meth:`AsyncEngine.run`."""
-        state, objective, report = _run_driver(
+        return _run_driver(
             self,
             Theta0,
             slots,
@@ -2081,11 +2005,14 @@ class ShardedAsyncEngine:
             snapshot_every=snapshot_every,
             serve=serve,
         )
-        record = record_every > 0
+
+    def _result(self, state: ShardedSimState, objective, report) -> SimResult:
+        """The run's :class:`SimResult`: pulls the counters and the
+        unpadded models from the shards."""
         part = self.part
         return SimResult(
             Theta=self.global_theta(state),
-            objective=np.asarray(objective) if record else None,
+            objective=None if objective is None else np.asarray(objective),
             messages=float(np.asarray(state.messages).sum()),
             wakes_applied=int(np.asarray(state.applied).sum()),
             wakes_dropped=int(np.asarray(state.dropped).sum()),

@@ -1,12 +1,14 @@
-"""Observability layer: in-jit metrics, phase tracing, run reports.
+"""Observability layer: in-jit metrics, host spans, run reports.
 
 The load-bearing invariant is that telemetry is *free of side effects*:
 metrics-on must leave Theta bit-exact versus metrics-off under forced
 wakes, on both engines and both wire formats (the counters only
 re-reduce values the slot already computed — no extra PRNG draws, no
 Theta writes). On top of that: counter semantics against host-side
-ground truth (churn schedule, DP accountant), the phase profiler +
-Chrome-trace export, the report JSONL round-trip and CLI, the
+ground truth (churn schedule, DP accountant), the run driver's and the
+serving tier's spans in a profiler trace and the serving counters at the
+same boundaries, the ``obs.row_gather`` scope in the super-tick's HLO,
+the report JSONL round-trip and CLI, the
 once-per-process ExchangeSpec string deprecation, and the
 BENCH_summary sync guard. Multi-shard (S=4) parity and counters run in
 an 8-host-device subprocess, ``test_spmd.py`` style."""
@@ -27,11 +29,8 @@ from repro.core import AgentData, DPConfig, knn_graph, make_objective
 from repro.obs import (
     MetricsSpec,
     RunReport,
-    SpanRecorder,
     merge_bench_summary,
-    profile_supertick,
     summarize_counters,
-    validate_trace,
 )
 from repro.sim import (
     AsyncEngine,
@@ -232,43 +231,234 @@ def test_exchange_counters_accumulate_per_slot_volume():
     assert float(counters["exchange_bytes"].sum()) == float(steps * xbytes)
 
 
-# -- phase tracing -----------------------------------------------------------
+# -- host spans and serving counters -----------------------------------------
 
 
-def test_profile_supertick_and_trace_export(tmp_path):
-    obj = _quad_problem(n=32, seed=7)
-    eng = AsyncEngine(CDUpdate(obj), slot_wakes=8.0, seed=0, metrics=True)
-    recorder = SpanRecorder()
-    prof = profile_supertick(eng, inner=1, repeats=1, recorder=recorder)
-    assert tuple(prof.phases) == eng.phase_names
-    assert all(dt >= 0.0 for dt in prof.phases.values())
-    np.testing.assert_allclose(sum(prof.phases.values()), prof.total_s)
-    rows = prof.rows(prefix="obs_phase")
-    assert rows[-1][0] == "obs_phase_total"
-    trace = tmp_path / "trace.json"
-    recorder.export_chrome_trace(str(trace))
-    # live timing spans + one synthetic attribution span per phase
-    assert validate_trace(str(trace)) >= len(prof.phases)
-    events = json.loads(trace.read_text())["traceEvents"]
-    names = {e["name"] for e in events if e["tid"] == 1}
-    assert names == {f"obs.phase.{name}" for name in eng.phase_names}
+def _host_spans(tmp_path, fn):
+    """Run ``fn`` under a profiler trace; return its result and the
+    ``repro.*`` host spans as ``(name, thread, start_ns, end_ns)``, read
+    back with ``jax.profiler.ProfileData``, in order of start."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    trace_dir = tmp_path / "trace"
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
+    spans = [
+        (ev.name, line.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith("repro.")
+    ]
+    return out, sorted(spans, key=lambda s: (s[2], -s[3]))
 
 
-def test_validate_trace_rejects_malformed(tmp_path):
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"no_events": 1}))
-    with pytest.raises(ValueError, match="Chrome trace"):
-        validate_trace(str(bad))
-    bad.write_text(json.dumps({"traceEvents": [{"ph": "X"}]}))
-    with pytest.raises(ValueError, match="malformed"):
-        validate_trace(str(bad))
+def _inside(inner, outer) -> bool:
+    return inner[1] == outer[1] and outer[2] <= inner[2] and inner[3] <= outer[3]
 
 
-def test_phase_program_rejects_unknown_phase():
-    obj = _quad_problem(n=24, seed=8)
-    eng = AsyncEngine(CDUpdate(obj), seed=0)
-    with pytest.raises(ValueError, match="phase"):
-        eng.phase_program("not_a_phase")
+def _named(spans, name):
+    return [s for s in spans if s[0] == name]
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["async", "sharded_s1"])
+def test_run_spans_nest_in_repro_run(tmp_path, sharded):
+    """One ``run(..., snapshot_every=, serve=)`` call: one
+    ``repro.run.advance`` per chunk, one ``repro.serve.publish`` per
+    publication (in its ``repro.run.publish``, holding one
+    ``repro.serve.publish.sync``) and one ``repro.run.result``, all
+    nested in the call's one ``repro.run``."""
+    from repro.serve import ServeHandle
+
+    obj = _quad_problem(n=40, seed=11)
+    kw = dict(slot_wakes=8.0, seed=0)
+    eng = (
+        ShardedAsyncEngine(CDUpdate(obj), num_shards=1, **kw)
+        if sharded
+        else AsyncEngine(CDUpdate(obj), **kw)
+    )
+    handle = ServeHandle.for_engine(eng)
+    theta0 = np.zeros((obj.n, obj.p))
+    eng.run(theta0, slots=12, snapshot_every=4, serve=handle)  # compile untraced
+    c0 = handle.counters()
+    res, spans = _host_spans(
+        tmp_path, lambda: eng.run(theta0, slots=12, snapshot_every=4, serve=handle)
+    )
+    assert res.slots == 12
+    (run,) = _named(spans, "repro.run")
+    assert all(_inside(s, run) for s in spans)
+    advance = _named(spans, "repro.run.advance")
+    assert len(advance) == 3  # 12 slots in chunks of the snapshot period
+    publish = _named(spans, "repro.serve.publish")
+    published = handle.counters()["serve_snapshots_published"] - c0["serve_snapshots_published"]
+    assert len(publish) == published == 4  # at the start and after each chunk
+    events = _named(spans, "repro.run.publish")
+    for pub, event in zip(publish, events, strict=True):
+        assert _inside(pub, event)
+        (sync,) = [s for s in _named(spans, "repro.serve.publish.sync") if _inside(s, pub)]
+    assert len(_named(spans, "repro.serve.publish.sync")) == 4
+    (result,) = _named(spans, "repro.run.result")
+    assert result[2] >= max(s[3] for s in advance + events)
+
+
+def test_dynamic_run_spans_topology_and_events(tmp_path):
+    """The segment driver: a graph refresh in ``repro.run.topology``, each
+    periodic callback in its ``repro.run.<event>`` span."""
+    from repro.sim import GraphUpdate
+
+    obj = _quad_problem(n=40, seed=12)
+    eng = AsyncEngine(
+        CDUpdate(obj),
+        slot_wakes=8.0,
+        seed=0,
+        metrics=True,
+        graph_update=GraphUpdate(every=4, k=5, candidates=4, gamma=2.0, seed=1),
+    )
+    theta0 = np.zeros((obj.n, obj.p))
+    kw = dict(slots=8, record_every=4, metrics_every=8)
+    eng.run(theta0, **kw)
+    res, spans = _host_spans(tmp_path, lambda: eng.run(theta0, **kw))
+    (run,) = _named(spans, "repro.run")
+    assert all(_inside(s, run) for s in spans)
+    assert len(_named(spans, "repro.run.topology")) == 1  # the refresh at slot 4
+    assert len(_named(spans, "repro.run.record")) == 2 == len(res.objective) - 1
+    assert len(_named(spans, "repro.run.drain")) == 1 == len(res.report.snapshots)
+    assert len(_named(spans, "repro.run.advance")) >= 2
+    assert len(_named(spans, "repro.run.result")) == 1
+
+
+@pytest.mark.parametrize("call", ["predict", "rows"])
+def test_serving_spans_in_order_and_counters(tmp_path, call):
+    """A request: ``route``, ``score`` and ``fetch`` in that order inside
+    ``repro.serve.predict``; the counters at those boundaries grow, and
+    the publication's sync is part of its time."""
+    from repro.serve import ServeHandle
+
+    obj = _quad_problem(n=40, seed=13)
+    eng = AsyncEngine(CDUpdate(obj), slot_wakes=8.0, seed=0)
+    handle = ServeHandle.for_engine(eng)
+    eng.run(np.zeros((obj.n, obj.p)), slots=8, snapshot_every=4, serve=handle)
+    ids = np.array([3, 7, 7, 21])
+    args = (ids, np.ones((ids.size, obj.p))) if call == "predict" else (ids,)
+    getattr(handle, call)(*args)  # compile untraced
+    c0 = handle.counters()
+    _, spans = _host_spans(tmp_path, lambda: getattr(handle, call)(*args))
+    c1 = handle.counters()
+    (req,) = _named(spans, "repro.serve.predict")
+    parts = [s for s in spans if s is not req]
+    assert [s[0] for s in parts] == [
+        "repro.serve.route", "repro.serve.score", "repro.serve.fetch",
+    ]
+    assert all(_inside(s, req) for s in parts)
+    assert all(a[3] <= b[2] for a, b in zip(parts, parts[1:]))
+    assert c1["serve_requests"] == c0["serve_requests"] + 1
+    for name in ("serve_route_s_total", "serve_fetch_s_total"):
+        grown = c1[name] - c0[name]
+        assert 0.0 < grown <= (req[3] - req[2]) * 1e-9
+    assert 0.0 < c1["serve_publish_sync_s_total"] <= c1["serve_publish_s_total"]
+
+
+@pytest.mark.parametrize("variant", ["unfused", "fused", "sharded", "dynamic"])
+def test_supertick_hlo_carries_row_gather_scope(variant):
+    """The woken rows' constant gather is its own ``obs.row_gather``
+    scope in the compiled super-tick, on every slot path."""
+    from repro.sim import GraphUpdate
+
+    obj = _quad_problem(n=32, seed=14)
+    kw = dict(slot_wakes=8.0, seed=0)
+    if variant == "sharded":
+        eng = ShardedAsyncEngine(CDUpdate(obj), num_shards=1, **kw)
+        chunk, tables = eng._chunk, eng._static
+    elif variant == "dynamic":
+        eng = AsyncEngine(CDUpdate(obj), graph_update=GraphUpdate(every=4), **kw)
+        chunk, tables = eng._chunk_dyn, eng._dyn
+    else:
+        eng = AsyncEngine(CDUpdate(obj), fused=variant == "fused", **kw)
+        chunk, tables = eng._chunk, eng._static
+    assert eng.fused == (variant == "fused")
+    state = eng.init_state(np.zeros((obj.n, obj.p)))
+    text = chunk.lower(state, tables, 2).compile().as_text()
+    assert "obs.row_gather" in text
+    assert "obs.row_update" in text or "obs.fused_row_update" in text
+
+
+def test_spans_keep_nothing_without_a_profiler():
+    """With no trace running, the spans of many runs and requests leave
+    nothing behind: no allocation made under ``repro.obs.trace`` is alive
+    after 40 calls that opened about 1,600 spans."""
+    import gc
+    import tracemalloc
+
+    from repro.obs import trace
+    from repro.serve import ServeHandle
+
+    obj = _quad_problem(n=40, seed=15)
+    eng = AsyncEngine(CDUpdate(obj), slot_wakes=8.0, seed=0)
+    handle = ServeHandle.for_engine(eng)
+    theta0 = np.zeros((obj.n, obj.p))
+    ids, X = np.arange(4), np.ones((4, obj.p))
+
+    def calls(k):
+        # Each call opens 1 + 8 advance + 9 x 3 publication + 1 result
+        # + 4 request spans: 41.
+        for _ in range(k):
+            eng.run(theta0, slots=8, snapshot_every=1, serve=handle)
+            handle.predict(ids, X)
+
+    calls(2)  # compile untraced
+    tracemalloc.start(64)
+    try:
+        calls(40)
+        gc.collect()
+        snap = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    kept = snap.filter_traces([tracemalloc.Filter(True, trace.__file__, all_frames=True)])
+    assert sum(st.size for st in kept.statistics("filename")) == 0
+
+
+def test_compile_cache_keeps_each_programs_scopes(tmp_path):
+    """Two programs that differ only in their ``obs.*`` scope do not share
+    an executable through the persistent compilation cache: the second is
+    compiled with its own scope, not loaded with the first's."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    import repro.obs.trace  # noqa: F401  (keys the cache on metadata)
+
+    def program(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 2.0 + 1.0
+        return jax.jit(f)
+
+    keys = ("jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes", "jax_enable_compilation_cache")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    compilation_cache.reset_cache()
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    try:
+        x = jnp.ones(8)
+        first = program("obs.first_scope").lower(x).compile().as_text()
+        assert "obs.first_scope" in first and any(tmp_path.iterdir())
+        second = program("obs.second_scope").lower(x).compile().as_text()
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert "obs.second_scope" in second
+    assert "obs.first_scope" not in second
 
 
 # -- run reports -------------------------------------------------------------
@@ -298,16 +488,13 @@ def test_report_jsonl_roundtrip_and_bench_rows(tmp_path):
         {"wakes_applied": np.int64(5), "staleness_hist": np.array([3, 2])},
         derived={"dp_eps_spent_max": np.float64(0.5)},
     )
-    report.add_phase_rows([("obs_phase_total", 12.5, "sum of phases")])
     path = tmp_path / "report.jsonl"
     report.to_jsonl(str(path))
     back = RunReport.from_jsonl(str(path))
     assert back.meta == {"engine": "AsyncEngine", "n": 8}
     assert back.snapshots == report.snapshots
-    assert back.phase_rows == [("obs_phase_total", 12.5, "sum of phases")]
     rows = dict((name, v) for name, v, _ in back.bench_rows())
     assert rows["obs_wakes_applied"] == 5.0
-    assert rows["obs_phase_total"] == 12.5
     assert "staleness_hist" not in rows  # vectors render in the table, not rows
 
 
@@ -318,19 +505,13 @@ def test_report_cli_renders_merges_and_validates(tmp_path, capsys):
     report.add_snapshot(1, {"wakes_applied": np.int64(3)})
     rpath = tmp_path / "r.jsonl"
     report.to_jsonl(str(rpath))
-    recorder = SpanRecorder()
-    recorder.add("span", 0.0, 1.0)
-    tpath = tmp_path / "t.json"
-    recorder.export_chrome_trace(str(tpath))
     bench = tmp_path / "BENCH_summary.json"
     merge_bench_summary(str(bench), [("existing_row", 1.0, "kept")])
 
-    rc = report_cli.main(
-        [str(rpath), "--merge-bench", str(bench), "--validate-trace", str(tpath)]
-    )
+    rc = report_cli.main([str(rpath), "--merge-bench", str(bench)])
     assert rc == 0
     out = capsys.readouterr().out
-    assert "wakes_applied" in out and "valid Chrome trace" in out
+    assert "wakes_applied" in out
     merged = json.loads(bench.read_text())
     assert merged["obs_wakes_applied"]["us_per_call"] == 3.0
     assert merged["existing_row"]["us_per_call"] == 1.0  # merge, not clobber
@@ -342,11 +523,13 @@ def test_report_cli_renders_merges_and_validates(tmp_path, capsys):
 # -- satellites: warning dedup, bench sync, run.py CLI -----------------------
 
 
-def test_exchange_string_deprecation_warns_once_per_process():
+def test_exchange_string_deprecation_warns_once_per_process(monkeypatch):
     import repro.core.mixing as mixing
 
     obj = _quad_problem(n=24, seed=10)
-    mixing._warned_bare_exchange_string = False
+    # Restored after the test, so a later test in this process that
+    # expects the first warning still sees it.
+    monkeypatch.setattr(mixing, "_warned_bare_exchange_string", False)
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         for _ in range(3):
@@ -476,20 +659,12 @@ OBS_MULTIDEV_SCRIPT = textwrap.dedent(
                                dp.eps_spent(counts).max())
     print("S4_DP_OK")
 
-    # 3) Drained run + phase profile + trace on the 8-shard engine: the
-    #    CI obs lane's in-test twin.
+    # 3) Drained run + report on the 8-shard engine: the CI obs lane's
+    #    in-test twin.
     eng8 = ShardedAsyncEngine(CDUpdate(obj), num_shards=8, relabel="rcm",
                               slot_wakes=16.0, seed=0, metrics=True)
     res = eng8.run(np.zeros((n, p)), slots=6, metrics_every=3)
     assert len(res.report.snapshots) == 2
-    from repro.obs import SpanRecorder, profile_supertick, validate_trace
-    rec = SpanRecorder()
-    prof = profile_supertick(eng8, state=res.state, inner=1, repeats=1,
-                             recorder=rec)
-    assert tuple(prof.phases) == eng8.phase_names
-    res.report.add_phase_rows(prof.rows())
-    rec.export_chrome_trace("obs_trace_test.json")
-    assert validate_trace("obs_trace_test.json") >= len(prof.phases)
     res.report.to_jsonl("obs_report_test.jsonl")
     from repro.obs import RunReport
     back = RunReport.from_jsonl("obs_report_test.jsonl")
