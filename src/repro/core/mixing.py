@@ -118,12 +118,13 @@ class MixOp:
         w_i = jnp.asarray(self.w, Theta.dtype)[i]  # (K,)
         return jnp.sum(w_i[:, None] * Theta[cols_i], axis=0)
 
-    def tables(self, dtype) -> dict:
+    def tables(self, dtype, xp=jnp) -> dict:
         """The operator's arrays as device arrays (weights in ``dtype``), for
-        :meth:`gather_rows` callers that pass them through jit."""
+        :meth:`gather_rows` callers that pass them through jit; host arrays
+        with ``xp=np``."""
         if self.kind == "dense":
-            return {"W": jnp.asarray(self.W, dtype)}
-        return {"idx": jnp.asarray(self.idx), "w": jnp.asarray(self.w, dtype)}
+            return {"W": xp.asarray(self.W, dtype)}
+        return {"idx": xp.asarray(self.idx), "w": xp.asarray(self.w, dtype)}
 
     def gather_rows(self, Theta, idx, use_kernel: bool | None = None, tables=None):
         """Batched neighbour sums for a row subset: (B,) indices -> (B, p).
@@ -141,11 +142,12 @@ class MixOp:
             use_kernel = self._kernel_auto(Theta)
         if tables is None:
             tables = self.tables(Theta.dtype)
+        # Rows first, then the cast (they commute): a caller's table may be
+        # any array-like that gathers rows (the engine's packed tables).
         if self.kind == "dense":
-            W = jnp.asarray(tables["W"], Theta.dtype)
-            return jnp.matmul(W[idx], Theta, precision=_HIGHEST)
+            return jnp.matmul(tables["W"][idx].astype(Theta.dtype), Theta, precision=_HIGHEST)
         cols = tables["idx"][idx]  # (B, K)
-        w = jnp.asarray(tables["w"], Theta.dtype)[idx]  # (B, K)
+        w = tables["w"][idx].astype(Theta.dtype)  # (B, K)
         if use_kernel:
             from repro.kernels import ops
 

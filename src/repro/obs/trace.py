@@ -8,9 +8,11 @@ ops, so an idle gap on the device can be put down to what the host was
 doing then. With no trace running a span records nothing and keeps
 nothing; entering one costs about a microsecond.
 
-The spans (one per call or per event, never per slot or per agent):
+The spans (one per set-up, call or event, never per slot or per agent):
 
 ================================  ==========================================
+``repro.engine.place_tables``     an ``AsyncEngine``'s set-up: its static
+                                  tables stored in the scan's layouts
 ``repro.run``                     one ``run()`` call of either engine
 ``repro.run.advance``             the dispatch of one scan chunk
 ``repro.run.<event>``             a periodic callback: ``publish``,

@@ -43,6 +43,7 @@ memory.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import NamedTuple
 
@@ -50,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.graph import TopologyState, as_csr, csr_from_coo, neighbor_counts
@@ -126,6 +128,72 @@ def _resolve_fused(update, fused, slab_rows: int, dtype, has_delay: bool) -> boo
             raise ValueError(f"fused=True but the fused path does not serve {reason}")
         return True
     return False
+
+
+def _row_packing(shape, wanted):
+    """How to store a table of ``shape`` so that the runtime's default
+    (row-major) layout for what is stored is, byte for byte, the
+    ``wanted`` layout: ``(perm, padded)``, the table's axes in
+    ``wanted``'s major-to-minor order with the axes its tile covers
+    padded to the tile (the agent axis never). None where that is the
+    table as it is, where the table has one axis, or where ``wanted``
+    does not keep the agent axis major-most."""
+    perm = tuple(wanted.major_to_minor)
+    if len(shape) < 2 or perm[0] != 0:
+        return None
+    padded = [shape[d] for d in perm]
+    tile = wanted.tiling[0] if wanted.tiling else ()
+    for axis, t in zip(range(len(padded) - len(tile), len(padded)), tile):
+        if axis > 0:
+            padded[axis] = -(-padded[axis] // t) * t
+    if perm == tuple(range(len(shape))) and tuple(padded) == tuple(shape):
+        return None
+    return perm, tuple(padded)
+
+
+def _pack_rows(table: np.ndarray, perm, padded) -> np.ndarray:
+    """``table`` with its axes in ``perm`` order, zero-padded to ``padded``
+    (on the host: packing on the device would hold the table twice and
+    a transposed copy besides)."""
+    out = np.zeros(padded, table.dtype)
+    out[tuple(slice(0, table.shape[d]) for d in perm)] = table.transpose(perm)
+    return out
+
+
+@jax.tree_util.register_pytree_node_class
+class _RowTable:
+    """A static table stored as :func:`_row_packing` says, read by rows.
+
+    ``data`` holds the table with its axes permuted by ``perm`` and
+    padded, in the runtime's default (row-major) layout; ``shape`` is the
+    table's own. ``table[rows]`` gathers the rows from ``data`` and gives
+    them back as the table's rows: the padding sliced off, the axes put
+    back. The super-tick reads every static table of rank 2 or more by
+    rows of woken agents, and only so.
+    """
+
+    def __init__(self, data, perm, shape):
+        self.data, self.perm, self.shape = data, tuple(perm), tuple(shape)
+
+    @property
+    def dtype(self):
+        return self.data.dtype
+
+    @property
+    def ndim(self) -> int:
+        return len(self.shape)
+
+    def __getitem__(self, rows):
+        kept = tuple(slice(0, self.shape[d]) for d in self.perm[1:])
+        picked = self.data[rows][(slice(None),) + kept]
+        return jnp.transpose(picked, np.argsort(self.perm))
+
+    def tree_flatten(self):
+        return (self.data,), (self.perm, self.shape)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], *aux)
 
 
 class SimState(NamedTuple):
@@ -602,11 +670,12 @@ class AsyncEngine:
                 dp_limit=getattr(update, "planned_Ti", None),
             )
         )
-        scan_options = _scan_compiler_options(self.n, self.p, self.dtype)
+        self._scan_options = _scan_compiler_options(self.n, self.p, self.dtype)
         self._chunk = jax.jit(
-            self._chunk_impl, static_argnums=2, compiler_options=scan_options
+            self._chunk_impl, static_argnums=2, compiler_options=self._scan_options
         )
         self._forced = jax.jit(self._slot_forced)
+        self._relaid = (0, 0)  # static tables re-stored as _RowTable, their bytes
 
         # Dynamic topology: the graph becomes mutable state. The live CSR
         # and its slot-form TopologyState stay host-side; the super-tick
@@ -637,7 +706,7 @@ class AsyncEngine:
             self.topo = TopologyState.from_csr(csr, capacity=_slot_capacity(csr))
             self._dyn = self._dyn_tiles()
             self._chunk_dyn = jax.jit(
-                self._chunk_dyn_impl, static_argnums=2, compiler_options=scan_options
+                self._chunk_dyn_impl, static_argnums=2, compiler_options=self._scan_options
             )
             self._forced_dyn = jax.jit(self._slot_dyn_forced)
             self._static = None
@@ -645,37 +714,38 @@ class AsyncEngine:
             self._csr = None
             self.topo = None
             self._dyn = None
-            self._static = self._static_tables()
+            self._static, self._relaid = self._place_static(self._static_tables())
 
     def _clock_tables(self) -> dict:
-        """The (n,) wake, churn and straggler probabilities (f32 device
+        """The (n,) wake, churn and straggler probabilities (f32 host
         arrays; churn/straggler entries only where the scenario has them)."""
-        f32 = jnp.float32
-        tables = {"wake_probs": jnp.asarray(self.wake_probs, f32)}
+        f32 = np.float32
+        tables = {"wake_probs": np.asarray(self.wake_probs, f32)}
         if self._leave is not None:
-            tables["leave"] = jnp.asarray(self._leave, f32)
-            tables["rejoin"] = jnp.asarray(self._rejoin, f32)
+            tables["leave"] = np.asarray(self._leave, f32)
+            tables["rejoin"] = np.asarray(self._rejoin, f32)
         if self._drop is not None:
-            tables["drop"] = jnp.asarray(self._drop, f32)
+            tables["drop"] = np.asarray(self._drop, f32)
         return tables
 
     def _static_tables(self) -> dict:
         """Every O(n) table the static-topology super-tick reads, as one
-        pytree of device arrays passed to the jitted programs as an
-        argument. Closed over, each numpy table would be baked into the
-        program as a literal: at a million agents that is gigabytes of
-        constants to compile, where an argument costs nothing."""
+        pytree of host arrays; :meth:`_place_static` puts it on the device,
+        and the jitted programs take it as an argument. Closed over, each
+        numpy table would be baked into the program as a literal: at a
+        million agents that is gigabytes of constants to compile, where an
+        argument costs nothing."""
         tables = self._clock_tables()
-        tables["deg"] = jnp.asarray(self._deg_counts)
+        tables["deg"] = self._deg_counts
         if self._delays is not None:
-            tables["idx"] = jnp.asarray(self._idx)
-            tables["w"] = jnp.asarray(self._w, self.dtype)
-            tables["delays"] = jnp.asarray(self._delays)
+            tables["idx"] = np.asarray(self._idx)
+            tables["w"] = np.asarray(self._w, self.dtype)
+            tables["delays"] = np.asarray(self._delays)
         elif self.fused:
-            tables["idx"] = jnp.asarray(self._fidx)
-            tables["w"] = jnp.asarray(self._fw, jnp.float32)
+            tables["idx"] = np.asarray(self._fidx)
+            tables["w"] = np.asarray(self._fw, np.float32)
         else:
-            tables["mix"] = self.update.mix.tables(self.dtype)
+            tables["mix"] = self.update.mix.tables(self.dtype, xp=np)
         consts_fn = getattr(self.update, "agent_constants", None)
         consts = None if consts_fn is None else consts_fn()
         if consts is not None:
@@ -684,11 +754,77 @@ class AsyncEngine:
             def const_table(a):
                 a = np.asarray(a)
                 if np.issubdtype(a.dtype, np.floating):
-                    a = a.astype(self.dtype)
-                return jnp.asarray(a)
+                    a = a.astype(self.dtype, copy=False)
+                return a
 
             tables["consts"] = jax.tree.map(const_table, consts)
         return tables
+
+    def static_formats(self, state, static) -> dict:
+        """The format the scan chunk asks for each ``static`` table.
+
+        The chunk of ``steps_per_chunk`` slots is compiled with every
+        table's layout left to the compiler (``Layout.AUTO``) and the
+        state's at the default, on the device the arguments name (arrays
+        or ``jax.ShapeDtypeStruct``s, so a described chip will do; the
+        default device where they name none); the compiled program's
+        input formats are the answer. A TPU's runtime
+        may lay a table out with the agent axis in the lanes, where the
+        scan reads it by rows of woken agents: the chunk asks for it
+        agent-major, and a table kept in the runtime default would be
+        relaid out at the entry of every call.
+        """
+
+        def formats(layout):
+            return lambda x: Format(layout, x.sharding)
+
+        chunk = jax.jit(
+            self._chunk_impl,
+            static_argnums=2,
+            in_shardings=(
+                jax.tree.map(formats(None), state),
+                jax.tree.map(formats(Layout.AUTO), static),
+            ),
+            compiler_options=self._scan_options,
+        )
+        compiled = chunk.lower(state, static, self.steps_per_chunk).compile()
+        return compiled.input_formats[0][1]
+
+    def _place_static(self, tables: dict) -> tuple[dict, tuple[int, int]]:
+        """Put the host ``tables`` on the device, each stored so that the
+        runtime's default layout for it is the one the scan chunk asks for
+        (:meth:`static_formats`), once, so that no call of a program
+        relays it out. Returns the device tables and the number of them
+        re-stored, with their bytes.
+
+        A table whose wanted layout is not its own is re-stored as a
+        :class:`_RowTable`, permuted and padded on the host, and kept
+        only if the runtime lays that out row-major. No array is placed
+        in a non-default layout: an executable that JAX reads back from
+        its persistent compilation cache loses the non-default layouts of
+        its entry, and refuses such an array or misreads its bytes.
+        """
+        with span("repro.engine.place_tables"):
+
+            def spec(x):
+                return jax.ShapeDtypeStruct(x.shape, jax.dtypes.canonicalize_dtype(x.dtype))
+
+            theta = jax.ShapeDtypeStruct((self.n, self.p), self.dtype)
+            state = jax.eval_shape(self.init_state, theta)
+            wanted = jax.tree.leaves(self.static_formats(state, jax.tree.map(spec, tables)))
+            leaves, tree = jax.tree.flatten(tables)
+            relaid = nbytes = 0
+            for i, fmt in enumerate(wanted):
+                x, stored = leaves[i], None
+                packing = _row_packing(x.shape, fmt.layout)
+                if packing is not None:
+                    data = jnp.asarray(_pack_rows(x, *packing))
+                    if data.format.layout.major_to_minor == tuple(range(x.ndim)):
+                        stored = _RowTable(data, packing[0], x.shape)
+                        relaid += 1
+                        nbytes += data.on_device_size_in_bytes()
+                leaves[i] = jnp.asarray(x) if stored is None else stored
+            return jax.tree.unflatten(tree, leaves), (relaid, nbytes)
 
     # -- state ------------------------------------------------------------
     def init_state(self, Theta0, seed: int | None = None) -> SimState:
@@ -763,7 +899,9 @@ class AsyncEngine:
         safe = jnp.minimum(woken, n - 1)
         consts = static.get("consts")
         with jax.named_scope("obs.row_gather"):
-            consts_rows = None if consts is None else jax.tree.map(lambda t: t[safe], consts)
+            consts_rows = None if consts is None else jax.tree.map(
+                lambda t: t[safe], consts, is_leaf=lambda t: isinstance(t, _RowTable)
+            )
         if self.fused and self._delays is None:
             with jax.named_scope("obs.fused_row_update"):
                 # One Pallas launch: gather + mix + Eq. 4/6 + drop-mode scatter.
@@ -861,7 +999,7 @@ class AsyncEngine:
         consts = dict(self._consts_base)
         consts["deg"] = jnp.asarray(w.sum(axis=1))
         tiles = {
-            **self._clock_tables(),
+            **jax.tree.map(jnp.asarray, self._clock_tables()),
             "idx": jnp.asarray(t.nbr),
             "w": jnp.asarray(w, self.dtype),
             "counts": jnp.asarray(np.asarray(t.valid).sum(axis=1), jnp.float32),
@@ -1085,6 +1223,8 @@ class AsyncEngine:
             "batch_size": int(self.batch_size),
             "fused": bool(self.fused),
             "dtype": str(jnp.dtype(self.dtype).name),
+            "static_tables_relaid": self._relaid[0],
+            "static_relaid_bytes": self._relaid[1],
         }
 
     # -- drivers -----------------------------------------------------------

@@ -269,6 +269,24 @@ def _named(spans, name):
     return [s for s in spans if s[0] == name]
 
 
+@pytest.mark.parametrize("dynamic", [False, True], ids=["static", "dynamic"])
+def test_place_tables_span_at_set_up(tmp_path, dynamic):
+    """A static-topology engine places its tables in one
+    ``repro.engine.place_tables`` span at set-up, and its gauges read no
+    table placed off the default on the CPU; the dynamic-topology engine
+    rebuilds its tiles on every swap and places nothing."""
+    from repro.sim import GraphUpdate
+
+    obj = _quad_problem(n=32, seed=3)
+    kw = dict(graph_update=GraphUpdate(every=4)) if dynamic else {}
+    eng, spans = _host_spans(
+        tmp_path, lambda: AsyncEngine(CDUpdate(obj), slot_wakes=8.0, seed=0, **kw)
+    )
+    assert len(_named(spans, "repro.engine.place_tables")) == (0 if dynamic else 1)
+    meta = eng.report_meta()
+    assert (meta["static_tables_relaid"], meta["static_relaid_bytes"]) == (0, 0)
+
+
 @pytest.mark.parametrize("sharded", [False, True], ids=["async", "sharded_s1"])
 def test_run_spans_nest_in_repro_run(tmp_path, sharded):
     """One ``run(..., snapshot_every=, serve=)`` call: one

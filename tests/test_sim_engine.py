@@ -1,6 +1,7 @@
 """Batched async engine (repro.sim): cross-validation against the
 sequential simulators, DP budget-stop parity, and scenario invariants."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -442,3 +443,85 @@ def test_scan_compiler_options_off_tpu():
     from repro.sim.engine import _scan_compiler_options
 
     assert _scan_compiler_options(10**7, 20, jnp.float32) is None
+
+
+# ---------------------------------------------------------------------------
+# Static tables placed in the scan chunk's layouts
+# ---------------------------------------------------------------------------
+
+
+def _tiled_agent_major(engine, state, static):
+    """Formats such as a TPU compiler asks for: every table of rank 2 or
+    more agent-major with its other axes reversed, in (8, 128) tiles. On
+    the CPU, whose compiler asks for the default layouts, they drive the
+    packing path."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    cpu = SingleDeviceSharding(jax.devices()[0])
+
+    def fmt(x):
+        if x.ndim < 2:
+            return Format(Layout((0,)), cpu)
+        order = (0,) + tuple(range(x.ndim - 1, 0, -1))
+        return Format(Layout(order, tiling=((8, 128),)), cpu)
+
+    return jax.tree.map(fmt, static)
+
+
+@pytest.mark.parametrize("formats", ["compiler", "tiled_agent_major"])
+def test_placed_static_tables_run_bit_identical(monkeypatch, formats):
+    """An engine whose tables were stored for the formats asked for runs
+    ``step``, ``advance(16)``, ``advance(5)``, ``step`` to the same bits as
+    one whose tables went through plain ``jnp.asarray``; every stored
+    table reads back by rows equal to the host arrays, and the gauges
+    count the tables stored off the default: none where the compiler
+    asks for the default layouts (the CPU), all five of rank 2 or more,
+    permuted and padded to the tile, where it asks for tiled agent-major
+    layouts."""
+    from repro.sim.engine import _RowTable
+
+    obj = _quad_problem(n=24, seed=1, mix_mode="sparse")
+    kw = dict(slot_wakes=6.0, seed=5)
+    plain = AsyncEngine(CDUpdate(obj), **kw)
+    plain._static = jax.tree.map(jnp.asarray, plain._static_tables())
+    if formats == "tiled_agent_major":
+        monkeypatch.setattr(AsyncEngine, "static_formats", _tiled_agent_major)
+    eng = AsyncEngine(CDUpdate(obj), **kw)
+
+    def is_table(t):
+        return isinstance(t, _RowTable)
+
+    packed = [t for t in jax.tree.leaves(eng._static, is_leaf=is_table) if is_table(t)]
+    meta = eng.report_meta()
+    if formats == "compiler":
+        assert not packed
+        assert (meta["static_tables_relaid"], meta["static_relaid_bytes"]) == (0, 0)
+    else:
+        # X, y, mask and the neighbour indices and weights
+        assert len(packed) == meta["static_tables_relaid"] == 5
+        assert meta["static_relaid_bytes"] == sum(t.data.nbytes for t in packed)
+        X = eng._static["consts"]["X"]
+        assert X.perm == (0, 2, 1) and X.data.shape == (obj.n, 8, 128)
+
+    rows = np.arange(obj.n)
+    jax.tree.map(
+        lambda t, ref: np.testing.assert_array_equal(t[rows], ref),
+        eng._static, plain._static, is_leaf=is_table,
+    )
+    X = eng._static["consts"]["X"][rows]
+    np.testing.assert_array_equal(X, np.asarray(obj.data.X, np.float32))
+
+    rng = np.random.default_rng(2)
+    masks = rng.random((2, obj.n)) < 0.3
+    theta0 = rng.normal(size=(obj.n, obj.p))
+
+    def drive(e):
+        s = e.step(e.init_state(theta0), masks[0])
+        s = e.advance(s, 16)
+        s = e.advance(s, 5)
+        return e.step(s, masks[1])
+
+    out, ref = drive(eng), drive(plain)
+    assert int(out.applied) > 0
+    jax.tree.map(np.testing.assert_array_equal, out, ref)

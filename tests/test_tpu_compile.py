@@ -147,3 +147,73 @@ def test_vmem_guard_keeps_scan_state_out_of_vmem(one_chip):
         loop = next(line for line in text.splitlines() if " while(" in line)
         pinned[guarded] = re.findall(r"f32\[200000,20\]\{[^}]*S\(1\)\}", loop)
     assert pinned[False] and not pinned[True]
+
+
+@pytest.fixture(scope="module")
+def widths_engine():
+    """An engine at the MovieLens cell's widths (m = 589 padded points of
+    p = 20 features an agent, a hub of degree 300 so K = 300) with
+    n = 2,048 agents, built on the CPU in the chip path's 32-bit mode."""
+    import numpy as np
+
+    from repro.core import AgentData, make_objective
+    from repro.core.graph import csr_from_coo
+    from repro.sim import AsyncEngine, CDUpdate
+
+    n, m, p, hub = 2048, 589, 20, 300
+    rng = np.random.default_rng(0)
+    rows = np.concatenate([np.arange(n - 1), np.zeros(hub, np.int64)])
+    cols = np.concatenate([np.arange(1, n), np.arange(1, hub + 1)])
+    graph = csr_from_coo(n, rows, cols, np.ones(len(rows)), symmetrize=True)
+    data = AgentData(
+        X=rng.normal(size=(n, m, p)).astype(np.float32),
+        y=rng.normal(size=(n, m)).astype(np.float32),
+        mask=np.ones((n, m), np.float32),
+    )
+    obj = make_objective(graph, data, "quadratic", mu=0.04, clip=10.0, mix_mode="sparse")
+    with jax.enable_x64(False):
+        return AsyncEngine(CDUpdate(obj), slot_wakes=0.01 * n, seed=0)
+
+
+@pytest.mark.parametrize("tables", ["default_layout", "engine_formats"])
+def test_supertick_reads_static_tables_without_relayout(one_chip, widths_engine, tables):
+    """With its tables in the v5e's default layouts, the compiled scan
+    chunk copies a static table at its entry (the padded X, relaid
+    agent-major); with them stored as the engine stores them for the
+    formats ``AsyncEngine.static_formats`` asks for, no program does: the
+    chunk at its own length and at another, nor the forced slot."""
+    import re
+
+    from repro.sim.engine import _row_packing, _RowTable
+
+    eng = widths_engine
+    copy_of_static = re.compile(r"\bcopy\(%?static")
+
+    def spec(x, shape=None):
+        return jax.ShapeDtypeStruct(shape or x.shape, x.dtype, sharding=one_chip)
+
+    with jax.enable_x64(False):
+        theta = jax.ShapeDtypeStruct((eng.n, eng.p), eng.dtype)
+        state = jax.tree.map(spec, jax.eval_shape(eng.init_state, theta))
+        static = jax.tree.map(spec, eng._static)
+        if tables == "default_layout":
+            text = eng._chunk.lower(state, static, eng.steps_per_chunk).compile().as_text()
+            assert copy_of_static.search(text)
+            return
+
+        def stored(x, want):
+            packing = _row_packing(x.shape, want.layout)
+            if packing is None:
+                return x
+            return _RowTable(spec(x, packing[1]), packing[0], x.shape)
+
+        static = jax.tree.map(stored, static, eng.static_formats(state, static))
+        assert static["consts"]["X"].perm == (0, 2, 1)  # agent-major, points minor
+        mask = jax.ShapeDtypeStruct((eng.n,), jnp.bool_, sharding=one_chip)
+        texts = [
+            eng._chunk.lower(state, static, steps).compile().as_text()
+            for steps in (eng.steps_per_chunk, 5)
+        ]
+        texts.append(eng._forced.lower(state, static, mask).compile().as_text())
+    for text in texts:
+        assert not copy_of_static.search(text)
