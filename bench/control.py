@@ -9,7 +9,12 @@ slots (as many as a run of the cell trains), and the compared numbers of
   the precision below the configuration's (f32 at full precision -> bf16
   x3 products), its served scores likewise;
 * ``half_batch`` -- the reference applying only the first half of each
-  slot's woken rows.
+  slot's woken rows (of each shard's, under the sharded engine).
+
+Under the sharded engine the replays take the placement the engine's own
+cut (``repro.sim.partition.partition_graph``, called with the
+configuration's options as the engine calls it) makes of the deployment,
+so no engine is built; the replays run on the chips the cell asks for.
 
 The sampled requests are those a run of the seed draws, each served at
 the last version. A state left unchanged reads ``train_gap`` = 1 by
@@ -32,7 +37,7 @@ for _p in (ROOT / "src", ROOT):
         sys.path.insert(0, str(_p))
 
 
-def readings(cell, seed: int, slots: int, seconds: float = 20.0) -> dict:
+def readings(cell, seed: int, slots: int, seconds: float = 20.0, devices=None) -> dict:
     import numpy as np
 
     from bench import deploy, harness, reference, traffic
@@ -45,6 +50,8 @@ def readings(cell, seed: int, slots: int, seconds: float = 20.0) -> dict:
     every = harness.publication_period(mix)
     slots = slots // every * every
     seed31 = deploy.engine_seed(seed)
+    placement = engine_placement(cfg, dep, cell.chips)
+    placed = dict(placement=placement, shards=cell.chips, devices=devices)
 
     requests = []
     sched = traffic.schedule(mix, dep.counts, dep.test_count, seed, seconds)
@@ -58,13 +65,13 @@ def readings(cell, seed: int, slots: int, seconds: float = 20.0) -> dict:
 
     t1 = time.perf_counter()
     ref, touched, rows_ref = reference.replay(
-        dep, cfg, theta0, seed31, prob, slots, every, users, "highest"
+        dep, cfg, theta0, seed31, prob, slots, every, users, "highest", **placed
     )
     out = {"seed": seed, "slots": slots, "reference_s": time.perf_counter() - t1}
 
     def compare(name, precision, half):
         theta, _, rows = reference.replay(
-            dep, cfg, theta0, seed31, prob, slots, every, users, precision, half
+            dep, cfg, theta0, seed31, prob, slots, every, users, precision, half, **placed
         )
         score_precision = "exact" if precision == "highest" else precision
         pairs = []
@@ -80,6 +87,24 @@ def readings(cell, seed: int, slots: int, seconds: float = 20.0) -> dict:
     return out
 
 
+def engine_placement(cfg: dict, dep, chips: int):
+    """The (S, R) placement the sharded engine makes of ``dep`` on
+    ``chips`` shards, or None where the configuration names the
+    single-device engine."""
+    import numpy as np
+
+    from bench import harness
+
+    eng = harness.check_engine(cfg, chips)
+    if eng["kind"] != "sharded":
+        return None
+    from repro.sim.partition import partition_graph
+
+    part = partition_graph(dep.graph(), chips, mode=eng.get("partition_mode", "degree"),
+                           relabel=eng.get("relabel"))
+    return np.array(part.owned)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -93,12 +118,13 @@ def main(argv=None) -> int:
     cell = spec.resolve(spec.load(ROOT), args.workload, ROOT)
     enable_compile_cache()
     try:
-        chips(cell.chips)
+        devices = chips(cell.chips)
     except NoChip as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
     for seed in args.seeds:
-        print(json.dumps(readings(cell, seed, args.slots), default=float), flush=True)
+        print(json.dumps(readings(cell, seed, args.slots, devices=devices), default=float),
+              flush=True)
     return 0
 
 

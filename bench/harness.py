@@ -3,7 +3,9 @@
 ``run()`` takes the cell as :func:`bench.spec.resolve` returns it and the
 devices to use; the entry point (``bench/run.py``) looks for the chip
 first. The window drives the system's own entries: the engine from
-``repro.sim.make_engine`` through ``engine.run(None, slots, state=...)``,
+``repro.sim.make_engine`` through ``engine.run(None, slots, state=...)``
+(the engine the configuration's ``engine`` block names, see
+:func:`check_engine`),
 and, where the mix serves, ``ServeHandle.for_engine`` with
 ``run(..., snapshot_every=, serve=handle)`` and requests sent by an
 open-loop client through ``ServeHandle.predict``.
@@ -11,7 +13,8 @@ open-loop client through ``ServeHandle.predict``.
 The check (``correct``) replays every slot the run trained (set-up's
 calls and the window's) with the plain reference of
 :mod:`bench.reference`, once the window has closed and the program's
-state is freed, and compares:
+state is freed, and compares (under the sharded engine, with the
+placement of agents on shards read from the engine before it is freed):
 
 * ``wake_set_diff`` -- agents whose models the program changed against
   those the reference updated: exact, limit 0;
@@ -117,13 +120,114 @@ class Client:
                 self.kept[i] = (np.asarray(res.values), int(res.version))
 
 
+ENGINE_KEYS = ("kind", "partition_mode", "relabel", "exchange")
+EXCHANGE_KEYS = ("method", "dtype", "error_feedback")
+
+
+def check_engine(cfg: dict, chips: int) -> dict:
+    """The configuration's ``engine`` block (absent: ``{"kind": "async"}``),
+    refused where the benchmark cannot build it or the reference cannot
+    replay it:
+
+    * ``"async"``: the single-device engine, on one chip;
+    * ``"sharded"``: ``ShardedAsyncEngine`` with one shard per chip of the
+      cell, on two or more chips; ``partition_mode`` ``"degree"`` or
+      ``"contiguous"``; ``relabel`` null or ``"rcm"`` (``"sfc"`` needs
+      coordinates the deployment lacks); ``exchange`` with ``method``
+      ``"all_gather"``, ``"p2p"`` or ``"auto"`` and ``dtype`` ``"f32"``
+      only: a bf16 or int8 halo reads quantised neighbour rows, a
+      different result, which the reference does not replay.
+    """
+    eng = dict(cfg.get("engine") or {"kind": "async"})
+    unknown = sorted(set(eng) - set(ENGINE_KEYS))
+    if unknown:
+        raise ValueError(f"engine: unknown key(s) {unknown}; known {ENGINE_KEYS}")
+    kind = eng.get("kind")
+    if kind == "async":
+        if len(eng) > 1:
+            raise ValueError(f"engine: {sorted(set(eng) - {'kind'})} apply to kind 'sharded' only")
+        if chips != 1:
+            raise ValueError(f"engine 'async' runs on one device; the cell asks for {chips} chips")
+        return eng
+    if kind != "sharded":
+        raise ValueError(f"engine.kind={kind!r}: known 'async', 'sharded'")
+    if chips < 2:
+        raise ValueError("engine 'sharded' takes one shard per chip; the cell asks for one chip")
+    if eng.get("partition_mode", "degree") not in ("degree", "contiguous"):
+        raise ValueError(
+            f"engine.partition_mode={eng['partition_mode']!r}: known 'degree', 'contiguous'"
+        )
+    if eng.get("relabel") not in (None, "rcm"):
+        raise ValueError(
+            f"engine.relabel={eng['relabel']!r}: known null, 'rcm' "
+            "(the deployment has no coordinates)"
+        )
+    ex = dict(eng.get("exchange") or {})
+    unknown = sorted(set(ex) - set(EXCHANGE_KEYS))
+    if unknown:
+        raise ValueError(f"engine.exchange: unknown key(s) {unknown}; known {EXCHANGE_KEYS}")
+    if ex.get("method", "auto") not in ("all_gather", "p2p", "auto"):
+        raise ValueError(
+            f"engine.exchange.method={ex['method']!r}: known 'all_gather', 'p2p', 'auto'"
+        )
+    if ex.get("dtype", "f32") != "f32":
+        raise ValueError(
+            f"engine.exchange.dtype={ex['dtype']!r}: only 'f32'; a quantised halo is a "
+            "different result, which the reference does not replay"
+        )
+    if ex.get("error_feedback", False):
+        raise ValueError("engine.exchange.error_feedback: the f32 halo has no error to feed back")
+    return eng
+
+
+def engine_config(cfg: dict, mix: dict, n: int, seed: int, devices):
+    """``(EngineConfig, shards)`` of the engine the configuration names:
+    ``shards`` None for the single-device engine, else one per device."""
+    from repro.sim import EngineConfig, ExchangeSpec
+
+    eng = check_engine(cfg, len(devices))
+    base = dict(
+        slot_wakes=traffic_mod.slot_wakes(mix, n),
+        rates=float(mix["clock_rate"]),
+        seed=deploy.engine_seed(seed),
+        devices=list(devices),
+    )
+    if eng["kind"] == "async":
+        return EngineConfig(**base), None
+    placement = dict(
+        partition_mode=eng.get("partition_mode", "degree"),
+        relabel=eng.get("relabel"),
+        exchange=ExchangeSpec(**eng.get("exchange", {})),
+    )
+    return EngineConfig(**base, **placement), len(devices)
+
+
+def placement_of(engine):
+    """``(placement, facts)`` of a sharded engine, read while it lives:
+    the (S, R) agent ids at each shard's rows (n at padding), which the
+    reference replays the per-shard clocks on, and the facts the readers
+    see. ``(None, None)`` for the single-device engine."""
+    part = getattr(engine, "part", None)
+    if part is None:
+        return None, None
+    method = engine.exchange_method
+    facts = {
+        "shards": int(engine.num_shards),
+        "exchange_method": method,
+        "rows_per_shard": int(part.rows_per_shard),
+        "halo_fraction": float(part.halo_fraction()),
+        "exchange_rows_per_slot": int(part.exchange_rows(method)),
+    }
+    return np.array(part.owned), facts
+
+
 def build(cfg: dict, mix: dict, dep, seed: int, devices, stage=lambda name: None):
     """The system under test: objective, engine and (where the mix serves)
     the serving handle, made through the program's own entries.
     ``stage(name)`` is called as each part is done."""
     from repro.core import AgentData, make_objective
     from repro.serve import ServeHandle
-    from repro.sim import CDUpdate, EngineConfig, make_engine
+    from repro.sim import CDUpdate, make_engine
 
     graph, X = dep.graph(), dep.X()
     stage("graph and padded X on the host")
@@ -137,13 +241,8 @@ def build(cfg: dict, mix: dict, dep, seed: int, devices, stage=lambda name: None
     )
     del X
     stage("objective")
-    ecfg = EngineConfig(
-        slot_wakes=traffic_mod.slot_wakes(mix, dep.n),
-        rates=float(mix["clock_rate"]),
-        seed=deploy.engine_seed(seed),
-        devices=list(devices),
-    )
-    engine = make_engine(CDUpdate(obj), ecfg)
+    ecfg, shards = engine_config(cfg, mix, dep.n, seed, devices)
+    engine = make_engine(CDUpdate(obj), ecfg, shards=shards)
     handle = ServeHandle.for_engine(engine) if mix.get("requests") else None
     return obj, engine, handle
 
@@ -168,6 +267,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, t_start: float,
     ``keep_trace``: where a traced run also writes its compact events."""
     cfg, mix = cell.cfg, cell.traffic
     traffic_mod.check(mix)
+    check_engine(cfg, len(devices))
     kind = devices[0].device_kind
 
     # -- set-up: deployment, system, warm-up of every shape the run uses
@@ -233,6 +333,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, t_start: float,
     # Slots the run asked for (set-up's call and the window's), not the
     # program's own count.
     theta_prog, trained = np.array(res.Theta), (1 + calls) * int(mix["slots_per_call"])
+    placement, placed = placement_of(engine)
     served = []
     if client is not None:
         for i, (values, version) in sorted(client.kept.items()):
@@ -243,7 +344,8 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, t_start: float,
     gc.collect()
 
     t_check = time.perf_counter()
-    checks = check(cfg, mix, dep, seed, theta0, theta_prog, trained, served)
+    checks = check(cfg, mix, dep, seed, theta0, theta_prog, trained, served,
+                   placement=placement, devices=devices)
     check_s = time.perf_counter() - t_check
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     if client is not None and client.failed:
@@ -258,6 +360,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, t_start: float,
         "compiles": window_compiles["compiles"],
         "setup_compiles": setup_compiles["compiles"],
         "check_s": check_s,
+        "placement": placed,
     }
     out = {
         "correct": bool(correct),
@@ -275,6 +378,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, t_start: float,
     }
     ctx = {
         "device_kind": kind,
+        "p": dep.p,
         "window": window,
         "work": work,
         "client": None
@@ -283,6 +387,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices, t_start: float,
         "serve_counters": None if counters0 is None else (counters0, counters1),
         "trace": tracer.reduced,
         "traced": tracer.window,
+        "placement": placed,
     }
     if client is not None:
         for q in LATENCY_PERCENTILES:
@@ -333,16 +438,20 @@ def sample_users(mix: dict, users) -> np.ndarray:
 
 
 def check(cfg, mix, dep, seed, theta0, theta_prog, trained, served,
-          precision: str = "highest", half: bool = False) -> dict:
+          precision: str = "highest", half: bool = False, placement=None,
+          devices=None) -> dict:
     """Replay the ``trained`` slots with the reference and compare (see
     the module doc). ``served``: (user, features, scores, version) of the
-    sampled requests. ``precision``/``half`` only for the control's
+    sampled requests; ``placement``: the sharded engine's, one shard on
+    each of the cell's ``devices``, which the replay then runs on; None
+    for the single engine. ``precision``/``half`` only for the control's
     readings."""
     prob = reference.wake_probability(traffic_mod.slot_wakes(mix, dep.n), dep.n)
     users = sample_users(mix, [u for u, *_ in served])
     theta_ref, touched, rows_at = reference.replay(
         dep, cfg, theta0, deploy.engine_seed(seed), prob, trained, publication_period(mix),
-        users, precision, half,
+        users, precision, half, placement=placement,
+        shards=None if devices is None else len(devices), devices=devices,
     )
     pos = {int(u): i for i, u in enumerate(users)}
     pairs = [(X, values, rows_at.get(version), pos[u]) for u, X, values, version in served]

@@ -5,14 +5,25 @@ the yardstick. A device the table lacks is an error, never a default.
 
 Source: Google Cloud documentation, "TPU v5e": per chip 197 TFLOP/s in
 bf16, 393 TOP/s in int8, 16 GB of HBM at 819 GB/s, and 1,600 Gbit/s of
-chip-to-chip interconnect.
+chip-to-chip interconnect (ICI).
+
+The interconnect figure is one number per chip: the note gives neither a
+count of links nor a split by link or by direction. The table takes it as
+it stands, 1,600 Gbit/s = 200e9 B/s, for the chip's links all together.
+A share of it is therefore a share of everything the chip can send over
+the interconnect, whichever of its links a collective uses.
 """
 
 from __future__ import annotations
 
 PEAKS = {
     # jax.devices()[0].device_kind of a TPU v5e chip.
-    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9},
+    "TPU v5 lite": {
+        "flops": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "ici_bytes_per_s": 200e9,
+    },
 }
 
 

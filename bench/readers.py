@@ -3,10 +3,14 @@
 Each reader takes the run's context: ``trace`` (the reduction of
 :mod:`bench.trace_reduce`), ``traced`` (applied updates and slots inside
 the traced window), ``work`` (the mean work of one applied update,
-:mod:`bench.workcount`), ``device_kind``, ``window`` (host counts of the
-whole window), ``client`` (per-request host times) and
-``serve_counters`` (the serving tier's counters at the window's ends).
-A reader that finds nothing to read returns None.
+:mod:`bench.workcount`), ``p`` (the model width), ``device_kind``,
+``window`` (host counts of the whole window), ``client`` (per-request
+host times), ``serve_counters`` (the serving tier's counters at the
+window's ends) and ``placement`` (None for the single-device engine; for
+the sharded engine ``shards``, ``exchange_method``, ``rows_per_shard``,
+``halo_fraction`` and ``exchange_rows_per_slot``, from which
+:func:`bench.workcount.halo_bytes_per_slot` counts the halo's bytes). A
+reader that finds nothing to read returns None.
 """
 
 from __future__ import annotations

@@ -49,6 +49,14 @@ def per_update(m: np.ndarray, deg: np.ndarray, p: int) -> dict:
     }
 
 
+def halo_bytes_per_slot(placement: dict, p: int) -> int:
+    """Bytes the sharded engine's halo exchange ships in one slot, summed
+    over shards: ``exchange_rows_per_slot`` rows of p f32 each, from the
+    placement facts the harness reads off the engine. Padding rows count,
+    because the static shapes ship them."""
+    return int(placement["exchange_rows_per_slot"]) * int(p) * F32
+
+
 def rate_weighted_mean(work: dict, rates: np.ndarray) -> dict:
     """Expected work of one applied update: agents wake in proportion to
     their clock rates, so each agent's work is weighted by its rate."""
