@@ -10,9 +10,10 @@ Every algorithm in ``repro.core`` reduces its graph traffic to two shapes:
 :func:`mix_op` builds a :class:`MixOp` for either graph representation.
 Below :func:`repro.core.graph.sparse_crossover` agents the operator
 materializes the (n, n) matrix and uses the MXU matmul fast path; at or
-above it the operator stays O(nnz): padded-neighbour gathers for ``row``
-and a ``segment_sum`` for ``all``. On a TPU backend, ``all`` routes
-through the ``graph_mix``/``sparse_mix`` Pallas kernels for f32 at
+above it the operator stays O(nnz): padded-neighbour gathers for ``row``,
+a ``segment_sum`` for ``all``, and a gather of just the rows' CSR edges
+for ``gather_rows`` (the engines' woken rows). On a TPU backend, ``all``
+routes through the ``graph_mix``/``sparse_mix`` Pallas kernels for f32 at
 on-chip agent counts (and through plain jnp otherwise — on this CPU
 container the kernels would run interpreted, so they are test/TPU-only).
 Pass ``mode="dense"``/``"sparse"`` to pin a representation explicitly
@@ -22,6 +23,7 @@ Pass ``mode="dense"``/``"sparse"`` to pin a representation explicitly
 from __future__ import annotations
 
 import dataclasses
+import math
 import warnings
 
 import jax
@@ -44,6 +46,13 @@ _KERNEL_MAX_N = 4096
 # f32 products at full f32 precision: a TPU's default would round the
 # operands of these contractions to bf16.
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+# The CSR row gather's edge block is a whole number of these: one (8, 128)
+# tile of its int32 edge tables.
+_EDGE_TILE = 1024
+# Standard deviations of a batch's edge count above its mean that the edge
+# block holds, so that one trip of the block is the rule.
+_EDGE_SIGMAS = 4.0
 
 
 def kernel_max_n() -> int:
@@ -74,15 +83,19 @@ class MixOp:
     rows: np.ndarray | None = None  # (nnz,) COO rows, sorted — sparse only
     cols: np.ndarray | None = None  # (nnz,)
     vals: np.ndarray | None = None  # (nnz,)
+    indptr: np.ndarray | None = None  # (n + 1,) CSR row starts — sparse only
 
     def _kernel_auto(self, Theta) -> bool:
+        return self._kernel_serves(Theta.dtype)
+
+    def _kernel_serves(self, dtype) -> bool:
         # Engage the Pallas kernels only where they are the right tool:
         # compiled TPU lowering, f32 (the kernels accumulate/return f32 —
         # silently downcasting the x64 theory paths is not acceptable),
         # and an on-chip agent count whose Theta slab fits VMEM.
         return (
             jax.default_backend() == "tpu"
-            and Theta.dtype == jnp.float32
+            and jnp.dtype(dtype) == jnp.float32
             and self.n <= kernel_max_n()
         )
 
@@ -118,41 +131,69 @@ class MixOp:
         w_i = jnp.asarray(self.w, Theta.dtype)[i]  # (K,)
         return jnp.sum(w_i[:, None] * Theta[cols_i], axis=0)
 
-    def tables(self, dtype, xp=jnp) -> dict:
+    def gathers_csr(self, dtype) -> bool:
+        """Whether :meth:`gather_rows` on auto sums CSR edges for models of
+        ``dtype`` (:func:`csr_gather_rows`): a sparse graph that the
+        ``sparse_rows_mix`` kernel does not serve."""
+        return self.kind == "sparse" and not self._kernel_serves(dtype)
+
+    def tables(self, dtype, xp=jnp, use_kernel: bool | None = None) -> dict:
         """The operator's arrays as device arrays (weights in ``dtype``), for
         :meth:`gather_rows` callers that pass them through jit; host arrays
-        with ``xp=np``."""
+        with ``xp=np``. A sparse graph gives its CSR arrays (``indptr``,
+        ``cols``, ``vals``), or, where the ``sparse_rows_mix`` kernel serves
+        ``dtype`` (``use_kernel``; None asks the auto gate), the padded
+        (n, K) neighbour tiles that kernel reads."""
         if self.kind == "dense":
             return {"W": xp.asarray(self.W, dtype)}
-        return {"idx": xp.asarray(self.idx), "w": xp.asarray(self.w, dtype)}
+        if use_kernel is None:
+            use_kernel = not self.gathers_csr(dtype)
+        if use_kernel:
+            return {"idx": xp.asarray(self.idx), "w": xp.asarray(self.w, dtype)}
+        return {
+            "indptr": xp.asarray(self.indptr, np.int32),
+            "cols": xp.asarray(self.cols, np.int32),
+            "vals": xp.asarray(self.vals, dtype),
+        }
 
-    def gather_rows(self, Theta, idx, use_kernel: bool | None = None, tables=None):
-        """Batched neighbour sums for a row subset: (B,) indices -> (B, p).
+    def gather_rows(
+        self, Theta, idx, use_kernel: bool | None = None, tables=None, edge_block: int | None = None
+    ):
+        """Batched neighbour sums for a row subset: (B,) indices ->
+        ``(out, edges, trips)``, ``out`` of shape (B, p).
 
         The super-tick path of ``repro.sim``: gather only the woken agents'
         neighbourhoods instead of computing all n sums. Indices may be
-        traced and may contain the out-of-range padding sentinel n (jit
-        gathers clamp it to row n-1; callers mask those entries out when
-        scattering). Sparse graphs route through the ``sparse_mix`` Pallas
-        machinery on TPU under the same gate as :meth:`all`. ``tables``:
-        this operator's :meth:`tables`, passed in by a jitted caller (None
-        reads the operator's own arrays).
+        traced and may contain the out-of-range padding sentinel n, whose
+        sum is 0 on the CSR route and row n-1's elsewhere (callers mask
+        those entries out when scattering). Sparse graphs route through
+        the ``sparse_mix`` Pallas machinery on TPU under the same gate as
+        :meth:`all`, and through :func:`csr_gather_rows` otherwise, which
+        takes ``edge_block`` edges a trip (:func:`mix_edge_block`).
+        ``tables``: this operator's :meth:`tables`, passed in by a jitted
+        caller (None reads the operator's own arrays); their kind decides
+        the route. ``edges`` and ``trips`` are the CSR route's real edges
+        summed and blocks run, as int32 scalars (zeros on the other routes).
         """
-        if use_kernel is None:
-            use_kernel = self._kernel_auto(Theta)
         if tables is None:
-            tables = self.tables(Theta.dtype)
+            if use_kernel is None:
+                use_kernel = self._kernel_auto(Theta)
+            tables = self.tables(Theta.dtype, use_kernel=use_kernel)
+        zero = jnp.zeros((), jnp.int32)
         # Rows first, then the cast (they commute): a caller's table may be
         # any array-like that gathers rows (the engine's packed tables).
         if self.kind == "dense":
-            return jnp.matmul(tables["W"][idx].astype(Theta.dtype), Theta, precision=_HIGHEST)
-        cols = tables["idx"][idx]  # (B, K)
-        w = tables["w"][idx].astype(Theta.dtype)  # (B, K)
-        if use_kernel:
+            out = jnp.matmul(tables["W"][idx].astype(Theta.dtype), Theta, precision=_HIGHEST)
+            return out, zero, zero
+        if "idx" in tables:
             from repro.kernels import ops
 
-            return ops.sparse_rows_mix(cols, w.astype(jnp.float32), Theta)
-        return jnp.einsum("bk,bkp->bp", w, Theta[cols], precision=_HIGHEST)
+            cols = tables["idx"][idx]  # (B, K)
+            w = tables["w"][idx]  # (B, K)
+            return ops.sparse_rows_mix(cols, w.astype(jnp.float32), Theta), zero, zero
+        if edge_block is None:
+            raise ValueError("the CSR route of gather_rows needs an edge_block (mix_edge_block)")
+        return csr_gather_rows(tables, Theta, idx, edge_block)
 
     def edge_tables(self) -> dict:
         """The graph's weights for :meth:`pairwise_smoothness`, as device
@@ -583,6 +624,76 @@ def sharded_mix_op(
     )
 
 
+def mix_edge_block(deg, probs, rows: int) -> int:
+    """Edge slots E of one trip of :func:`csr_gather_rows`, for batches of
+    at most ``rows`` agents, each woken with its probability in ``probs``,
+    over a graph whose agents have the degrees ``deg``.
+
+    A batch's edge count is a sum of independent Bernoulli-weighted
+    degrees (the probabilities scaled down so that at most ``rows`` wake
+    on average); E is its mean plus ``_EDGE_SIGMAS`` standard deviations,
+    rounded up to a whole ``_EDGE_TILE``, so that one trip is the rule,
+    and never more than ``rows`` times the largest degree, which no batch
+    passes: a near-regular graph gets the padded block's B·K.
+    """
+    deg = np.asarray(deg, np.float64)
+    p = np.asarray(probs, np.float64)
+    if p.sum() > rows:
+        p = p * (rows / p.sum())
+    mean = float(np.sum(p * deg))
+    sd = float(np.sqrt(np.sum(p * (1.0 - p) * deg**2)))
+    block = -(-math.ceil(mean + _EDGE_SIGMAS * sd) // _EDGE_TILE) * _EDGE_TILE
+    return int(max(1, min(block, rows * int(deg.max(initial=0)))))
+
+
+def csr_gather_rows(tables: dict, Theta, idx, block: int):
+    """Neighbour sums of the rows ``idx`` from a graph's CSR ``tables``
+    (:meth:`MixOp.tables`): ``(out, edges, trips)``.
+
+    Sums the (B,) rows' real edges and no padding: it lays the rows'
+    edges end to end (each row's in its CSR order), gathers them a block
+    of ``block`` edge slots at a time with their weights and neighbour
+    rows of ``Theta``, and reduces each block into the B rows, in as many
+    trips of a ``while_loop`` as the rows' edges fill, so that no edge is
+    ever dropped. Rows at the sentinel n (or past it) have no edges and
+    sum to 0. ``edges`` is the number of edges summed, ``trips`` the
+    blocks run.
+    """
+    indptr = tables["indptr"]
+    n = indptr.shape[0] - 1
+    B = idx.shape[0]
+    safe = jnp.minimum(idx, n - 1)
+    first = indptr[safe]
+    deg = jnp.where(idx < n, indptr[safe + 1] - first, 0)
+    ends = jnp.cumsum(deg)  # one past each row's last edge slot
+    edges = ends[-1]
+    base = first - (ends - deg)  # edge slot e of row b is CSR entry base[b] + e
+    step = jnp.diff(base, prepend=0)
+    trips = (edges + block - 1) // block
+
+    def trip(t, acc):
+        e0 = t * block
+        e = e0 + jnp.arange(block, dtype=ends.dtype)
+        live = e < edges
+        # A slot belongs to the last row that begins at or before it. Each
+        # row marks its first slot in the block (rows begun earlier mark
+        # slot 0, rows begun past it none); running sums over the slots of
+        # the marks and of the rows' steps in ``base`` give each slot its
+        # row and its CSR entry, exactly and with no gather per slot.
+        at_first = jnp.clip(ends - deg - e0, 0, block)
+        marks = jnp.zeros((block,), ends.dtype)
+        row = marks.at[at_first].add(1, mode="drop").cumsum() - 1
+        at = marks.at[at_first].add(step, mode="drop").cumsum() + e
+        row = jnp.where(live, row, B)  # B past the last edge
+        at = jnp.where(live, at, 0)
+        w = jnp.where(live, tables["vals"][at], 0).astype(Theta.dtype)
+        contrib = w[:, None] * Theta[tables["cols"][at]]
+        return acc + jax.ops.segment_sum(contrib, row, num_segments=B, indices_are_sorted=True)
+
+    out = jax.lax.fori_loop(0, trips, trip, jnp.zeros((B, Theta.shape[1]), Theta.dtype))
+    return out, edges.astype(jnp.int32), trips.astype(jnp.int32)
+
+
 def mix_op(graph, mode: str = "auto") -> MixOp:
     """Build the neighbour-sum operator for a dense or CSR graph.
 
@@ -606,4 +717,5 @@ def mix_op(graph, mode: str = "auto") -> MixOp:
         rows=csr.row_ids(),
         cols=csr.indices,
         vals=csr.data,
+        indptr=csr.indptr,
     )

@@ -30,6 +30,10 @@ engine context supports them):
 * ``wakes``: ``wakes_realized`` (wake mask sum before straggler/capacity
   losses), ``wakes_thinned`` (straggler drops), ``wakes_capacity_dropped``
   (static-batch overflow), ``wakes_applied`` (rows actually scattered);
+  and, where the single-device engine's neighbour sums run over CSR
+  edges, ``mix_edges`` (the woken rows' real edges summed) and
+  ``mix_trips`` (edge blocks run; ``mix_trips`` over the slots run says
+  how often a slot's edges needed a second block);
 * ``churn``: cumulative ``churn_departures`` / ``churn_rejoins``
   (active-flag transitions of the churn Markov chain);
 * ``privacy``: ``dp_updates_applied`` (cumulative private updates) and
@@ -57,6 +61,9 @@ import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
+
+# ``mix_edges`` is kept below this on the device, its multiples in a carry.
+_MIX_EDGES_WRAP = 1 << 30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,7 +146,8 @@ class MetricsAccumulator:
     update budget ``planned_Ti``), ``exchange_offsets`` (None = no
     halo exchange; an int = the point-to-point plan's offset count,
     0 for the all_gather wire), ``quantized`` (the halo wire is lossy
-    and reports error stats). Groups whose context is absent contribute
+    and reports error stats), ``mix_counts`` (the neighbour sums report
+    their edges and trips). Groups whose context is absent contribute
     no leaves, whatever the spec says — the pytree structure is fixed
     at engine build and stable across the scan.
     """
@@ -154,6 +162,7 @@ class MetricsAccumulator:
         dp_limit: int | None = None,
         exchange_offsets: int | None = None,
         quantized: bool = False,
+        mix_counts: bool = False,
     ):
         self.spec = spec
         self.rows = int(rows)
@@ -162,6 +171,7 @@ class MetricsAccumulator:
         self.dp_limit = dp_limit if spec.privacy else None
         self.exchange_offsets = exchange_offsets if spec.exchange else None
         self.quantized = bool(quantized) and spec.quantization
+        self.mix_counts = bool(mix_counts) and spec.wakes
 
     # -- pytree ------------------------------------------------------------
     def init(self) -> dict:
@@ -175,6 +185,13 @@ class MetricsAccumulator:
             m["wakes_applied"] = jnp.zeros((), i32)
             if self.straggler:
                 m["wakes_thinned"] = jnp.zeros((), i32)
+        if self.mix_counts:
+            # Edges pass int32 in minutes of a large swarm: the count is
+            # kept as _MIX_EDGES_WRAP-sized carries plus a remainder,
+            # joined on the host by :meth:`snapshot`.
+            m["mix_edges"] = jnp.zeros((), i32)
+            m["mix_edges_carry"] = jnp.zeros((), i32)
+            m["mix_trips"] = jnp.zeros((), i32)
         if self.churn:
             m["churn_departures"] = jnp.zeros((), i32)
             m["churn_rejoins"] = jnp.zeros((), i32)
@@ -227,6 +244,7 @@ class MetricsAccumulator:
         dp_counts=None,
         exchange=None,
         quant_stats=None,
+        mix=None,
     ) -> dict:
         """Advance the metrics pytree by one slot (runs inside the trace).
 
@@ -235,7 +253,8 @@ class MetricsAccumulator:
         sentinel ``rows``, ``applied`` their applied mask,
         ``capacity_dropped`` the static-batch overflow count,
         ``exchange`` this shard's slice of :meth:`ExchangeVolume.tiles`,
-        ``quant_stats`` the halo wire's error stats dict. All inputs are
+        ``quant_stats`` the halo wire's error stats dict, ``mix`` the
+        neighbour sums' ``(edges, trips)`` int32 scalars. All inputs are
         values the slot already computed — the accumulator draws no
         randomness and never touches Theta.
         """
@@ -250,6 +269,11 @@ class MetricsAccumulator:
             if self.straggler:
                 thinned = (wake_pre & ~wake).sum().astype(jnp.int32)
                 m["wakes_thinned"] = m["wakes_thinned"] + thinned
+        if self.mix_counts and mix is not None:
+            edges = m["mix_edges"] + mix[0]
+            m["mix_edges_carry"] = m["mix_edges_carry"] + edges // _MIX_EDGES_WRAP
+            m["mix_edges"] = edges % _MIX_EDGES_WRAP
+            m["mix_trips"] = m["mix_trips"] + mix[1]
         if self.churn and active_prev is not None:
             departed = (active_prev & ~active_new).sum().astype(jnp.int32)
             rejoined = ((~active_prev) & active_new).sum().astype(jnp.int32)
@@ -296,9 +320,18 @@ class MetricsAccumulator:
         Sharded callers pass the stacked (S, ...) pytree; per-shard
         leaves keep their leading shard axis so the report layer can
         show per-shard burn-down as well as totals. The internal
-        ``last_wake`` marker is dropped — it is state, not a counter.
+        ``last_wake`` marker is dropped — it is state, not a counter —
+        and ``mix_edges`` is joined with its carries into one int64.
         """
-        return {k: np.asarray(v) for k, v in m.items() if k != "last_wake"}
+        out = {
+            k: np.asarray(v)
+            for k, v in m.items()
+            if k not in ("last_wake", "mix_edges_carry")
+        }
+        if "mix_edges" in out:
+            carry = np.asarray(m["mix_edges_carry"], np.int64)
+            out["mix_edges"] = carry * _MIX_EDGES_WRAP + out["mix_edges"].astype(np.int64)
+        return out
 
 
 # Host-side dynamic-topology counters. Unlike the in-jit groups above,

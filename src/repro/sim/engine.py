@@ -7,8 +7,8 @@ i.i.d. clocks via binomial thinning (:mod:`repro.sim.clocks`): each
 **super-tick** wakes a random *subset* of agents (per-agent rates
 supported), computes their Eq. 4 / Eq. 6 / Eq. 16 updates from a
 bounded-staleness snapshot through the woken-rows gather/mix/scatter
-path (``MixOp.gather_rows``, backed by the ``sparse_mix`` Pallas
-machinery on TPU), and scatter-applies them — collapsing the scan length from O(T) to
+path (``MixOp.gather_rows``: the woken rows' real CSR edges, or the
+``sparse_mix`` Pallas machinery on TPU at on-chip n), and scatter-applies them — collapsing the scan length from O(T) to
 O(T / slot_wakes) compiled steps while keeping the same fixed points
 (cross-validated against the sequential paths in ``test_sim_engine.py``,
 in the style of the spmd/CD cross-checks).
@@ -55,7 +55,7 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.graph import TopologyState, as_csr, csr_from_coo, neighbor_counts
-from repro.core.mixing import kernel_max_n, sharded_mix_op
+from repro.core.mixing import kernel_max_n, mix_edge_block, sharded_mix_op
 from repro.core.model_propagation import propagation_rows_from
 from repro.core.spmd_compat import shard_map
 from repro.obs.metrics import ExchangeVolume, MetricsAccumulator, topology_log_init
@@ -658,6 +658,16 @@ class AsyncEngine:
         else:
             self._fidx = self._fw = None
 
+        # The static super-tick's neighbour sums run over the woken rows'
+        # CSR edges wherever the update's MixOp gathers them so, in blocks
+        # sized from the degrees, the wake probabilities and B.
+        mix = update.mix
+        self.mix_edge_block = (
+            mix_edge_block(np.diff(mix.indptr), self.wake_probs, self.batch_size)
+            if not (self.dynamic or self.fused or delay) and mix.gathers_csr(self.dtype)
+            else None
+        )
+
         self.metrics_spec = cfg.metrics_spec()
         self._macc = (
             None
@@ -668,6 +678,7 @@ class AsyncEngine:
                 churn=self._leave is not None,
                 straggler=self._drop is not None,
                 dp_limit=getattr(update, "planned_Ti", None),
+                mix_counts=self.mix_edge_block is not None,
             )
         )
         self._scan_options = _scan_compiler_options(self.n, self.p, self.dtype)
@@ -898,6 +909,7 @@ class AsyncEngine:
         Theta = state.Theta
         safe = jnp.minimum(woken, n - 1)
         consts = static.get("consts")
+        mix_counts = None
         with jax.named_scope("obs.row_gather"):
             consts_rows = None if consts is None else jax.tree.map(
                 lambda t: t[safe], consts, is_leaf=lambda t: isinstance(t, _RowTable)
@@ -925,7 +937,9 @@ class AsyncEngine:
                     neigh = jnp.einsum("bk,bkp->bp", w, vals, precision=_HIGHEST)
                 else:
                     hist = state.hist
-                    neigh = self.update.mix.gather_rows(Theta, woken, tables=static["mix"])
+                    neigh, *mix_counts = self.update.mix.gather_rows(
+                        Theta, woken, tables=static["mix"], edge_block=self.mix_edge_block
+                    )
 
             with jax.named_scope("obs.row_update"):
                 if consts_rows is None:
@@ -958,6 +972,7 @@ class AsyncEngine:
                     active_prev=active_prev,
                     active_new=active,
                     dp_counts=ustate if self._macc.dp_limit is not None else None,
+                    mix=mix_counts,
                 )
             return SimState(
                 Theta=Theta,
@@ -1225,6 +1240,7 @@ class AsyncEngine:
             "dtype": str(jnp.dtype(self.dtype).name),
             "static_tables_relaid": self._relaid[0],
             "static_relaid_bytes": self._relaid[1],
+            "mix_edge_block": self.mix_edge_block,
         }
 
     # -- drivers -----------------------------------------------------------

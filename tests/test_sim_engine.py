@@ -9,6 +9,8 @@ import pytest
 from repro.core import (
     AgentData,
     DPConfig,
+    as_csr,
+    csr_from_coo,
     knn_graph,
     make_objective,
     ring_graph,
@@ -469,23 +471,30 @@ def _tiled_agent_major(engine, state, static):
     return jax.tree.map(fmt, static)
 
 
-@pytest.mark.parametrize("formats", ["compiler", "tiled_agent_major"])
+@pytest.mark.parametrize(
+    "formats", ["compiler", "tiled_agent_major", "tiled_agent_major_delay"]
+)
 def test_placed_static_tables_run_bit_identical(monkeypatch, formats):
     """An engine whose tables were stored for the formats asked for runs
     ``step``, ``advance(16)``, ``advance(5)``, ``step`` to the same bits as
     one whose tables went through plain ``jnp.asarray``; every stored
     table reads back by rows equal to the host arrays, and the gauges
     count the tables stored off the default: none where the compiler
-    asks for the default layouts (the CPU), all five of rank 2 or more,
+    asks for the default layouts (the CPU), all of rank 2 or more,
     permuted and padded to the tile, where it asks for tiled agent-major
-    layouts."""
+    layouts: X, y and mask (the CSR neighbour tables have one axis), and
+    under a delay scenario also its (n, K) int32 neighbour ids, weights
+    and delays."""
     from repro.sim.engine import _RowTable
 
     obj = _quad_problem(n=24, seed=1, mix_mode="sparse")
     kw = dict(slot_wakes=6.0, seed=5)
+    delay = formats.endswith("_delay")
+    if delay:
+        kw["scenario"] = Scenario(delay=DelayConfig(max_delay=2, edge_delays=1))
     plain = AsyncEngine(CDUpdate(obj), **kw)
     plain._static = jax.tree.map(jnp.asarray, plain._static_tables())
-    if formats == "tiled_agent_major":
+    if formats.startswith("tiled_agent_major"):
         monkeypatch.setattr(AsyncEngine, "static_formats", _tiled_agent_major)
     eng = AsyncEngine(CDUpdate(obj), **kw)
 
@@ -498,15 +507,17 @@ def test_placed_static_tables_run_bit_identical(monkeypatch, formats):
         assert not packed
         assert (meta["static_tables_relaid"], meta["static_relaid_bytes"]) == (0, 0)
     else:
-        # X, y, mask and the neighbour indices and weights
-        assert len(packed) == meta["static_tables_relaid"] == 5
+        assert len(packed) == meta["static_tables_relaid"] == (6 if delay else 3)
+        if delay:
+            idx = eng._static["idx"]
+            assert is_table(idx) and idx.data.dtype == np.int32
         assert meta["static_relaid_bytes"] == sum(t.data.nbytes for t in packed)
         X = eng._static["consts"]["X"]
         assert X.perm == (0, 2, 1) and X.data.shape == (obj.n, 8, 128)
 
     rows = np.arange(obj.n)
     jax.tree.map(
-        lambda t, ref: np.testing.assert_array_equal(t[rows], ref),
+        lambda t, ref: np.testing.assert_array_equal(t[rows] if is_table(t) else t, ref),
         eng._static, plain._static, is_leaf=is_table,
     )
     X = eng._static["consts"]["X"][rows]
@@ -525,3 +536,100 @@ def test_placed_static_tables_run_bit_identical(monkeypatch, formats):
     out, ref = drive(eng), drive(plain)
     assert int(out.applied) > 0
     jax.tree.map(np.testing.assert_array_equal, out, ref)
+
+
+# ---------------------------------------------------------------------------
+# The woken rows' CSR neighbour sums on a hub graph
+# ---------------------------------------------------------------------------
+
+
+def _hub_problem(n=300, hubs=6, hub_degree=200, seed=21):
+    """A quadratic problem over a 4-NN graph joined to ``hubs`` stars of
+    ``hub_degree`` spokes: a few rows far wider than the rest, so the
+    padded (B, K) block would be mostly padding."""
+    rng = np.random.default_rng(seed)
+    knn = as_csr(knn_graph(rng.normal(size=(n, 8)), k=4))
+    spokes = [rng.choice(np.arange(hubs, n), hub_degree, replace=False) for _ in range(hubs)]
+    rows = np.concatenate([knn.row_ids(), np.repeat(np.arange(hubs), hub_degree)])
+    cols = np.concatenate([knn.indices, *spokes])
+    graph = csr_from_coo(n, rows, cols, np.ones(len(rows)), symmetrize=True)
+    return _quad_problem(n, graph=graph, mix_mode="sparse", seed=seed)
+
+
+def _np_eq4(obj, Theta, woken):
+    """The woken agents' Eq. 4 step from the snapshot ``Theta``, in numpy
+    (quadratic loss, no clip): each row's neighbour sum over its CSR edges."""
+    csr = as_csr(obj.graph)
+    X, y, mask = (np.asarray(a, np.float64) for a in (obj.data.X, obj.data.y, obj.data.mask))
+    d, c, alpha, lam = obj.degrees, obj.confidences, obj.alphas(), obj.lambdas
+    Theta = np.asarray(Theta, np.float64)
+    out = Theta.copy()
+    for i in woken:
+        lo, hi = csr.indptr[i], csr.indptr[i + 1]
+        neigh = csr.data[lo:hi] @ Theta[csr.indices[lo:hi]] / d[i]
+        resid = X[i] @ Theta[i] - y[i]
+        grad = (2.0 * resid * mask[i]) @ X[i] / max(mask[i].sum(), 1.0) + 2.0 * lam[i] * Theta[i]
+        out[i] = (1.0 - alpha[i]) * Theta[i] + alpha[i] * (neigh - obj.mu * c[i] * grad)
+    return out
+
+
+def _woken(eng, state, mask=None):
+    """The rows the engine's next slot updates: a forced ``mask``'s first B
+    agents, or the sampled slot's, drawn by the engine's own rule."""
+    if mask is None:
+        k_wake = jax.random.split(state.key, 6)[3]
+        mask = np.asarray(jax.random.uniform(k_wake, (eng.n,)) < eng._static["wake_probs"])
+    return np.nonzero(mask)[0][: eng.batch_size]
+
+
+def _hub_masks(n, rng):
+    """Forced wake sets: every hub with 20 others (more edges than one
+    block holds), then a plain draw of 20."""
+    hubs = np.zeros(n, bool)
+    hubs[:6] = True
+    hubs[rng.choice(np.arange(6, n), 20, replace=False)] = True
+    plain = np.zeros(n, bool)
+    plain[rng.choice(n, 20, replace=False)] = True
+    return [hubs, plain]
+
+
+def test_hub_graph_slots_match_numpy_eq4():
+    """On a hub graph, forced and sampled slots of the engine give the
+    numpy Eq. 4 step of the rows they wake, to 1e-6."""
+    obj = _hub_problem()
+    eng = AsyncEngine(CDUpdate(obj), slot_wakes=10.0, seed=3)
+    assert eng.mix_edge_block is not None
+    rng = np.random.default_rng(4)
+    state = eng.init_state(rng.normal(size=(obj.n, obj.p)))
+    for mask in _hub_masks(obj.n, rng) + [None] * 3:
+        want = _np_eq4(obj, state.Theta, _woken(eng, state, mask))
+        state = eng.advance(state, 1) if mask is None else eng.step(state, mask)
+        np.testing.assert_allclose(np.asarray(state.Theta), want, rtol=1e-6, atol=1e-6)
+
+
+def test_hub_graph_mix_counters_and_bit_exact_theta():
+    """With metrics on, ``mix_edges`` is the woken valid rows' degrees
+    summed and ``mix_trips`` the sum over slots of ceil(edges / E), with E
+    the engine's ``mix_edge_block``; Theta is bit-exact with metrics off."""
+    obj = _hub_problem()
+    kw = dict(slot_wakes=10.0, seed=3)
+    eng_off = AsyncEngine(CDUpdate(obj), **kw)
+    eng_on = AsyncEngine(CDUpdate(obj), metrics=True, **kw)
+    E = eng_on.report_meta()["mix_edge_block"]
+    assert E == eng_on.mix_edge_block == eng_off.mix_edge_block
+    deg = np.diff(as_csr(obj.graph).indptr)
+    rng = np.random.default_rng(5)
+    theta0 = rng.normal(size=(obj.n, obj.p))
+    s_off, s_on = eng_off.init_state(theta0), eng_on.init_state(theta0)
+    per_slot = []
+    for mask in _hub_masks(obj.n, rng) + [None] * 4:
+        per_slot.append(int(deg[_woken(eng_on, s_on, mask)].sum()))
+        if mask is None:
+            s_off, s_on = eng_off.advance(s_off, 1), eng_on.advance(s_on, 1)
+        else:
+            s_off, s_on = eng_off.step(s_off, mask), eng_on.step(s_on, mask)
+    np.testing.assert_array_equal(np.asarray(s_off.Theta), np.asarray(s_on.Theta))
+    counters, _ = eng_on.metrics_snapshot(s_on)
+    assert int(counters["mix_edges"]) == sum(per_slot)
+    trips = [-(-e // E) for e in per_slot]
+    assert int(counters["mix_trips"]) == sum(trips) and max(trips) >= 2

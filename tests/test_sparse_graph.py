@@ -23,6 +23,7 @@ from repro.core import (
     synchronous_round,
 )
 from repro.core.graph import dense_weights
+from repro.core.mixing import mix_edge_block
 from repro.data.synthetic import linear_classification_problem
 
 
@@ -190,22 +191,77 @@ def test_mix_operator_parity():
     )
 
 
-def test_mix_gather_rows_batched_parity():
-    """gather_rows (the repro.sim woken-rows path) == stacked row() calls,
-    on both backends, including the interpreted kernel route."""
+def _hub_knn_graph(n, hubs, hub_degree, seed):
+    """A star of ``hub_degree`` spokes at each of the first ``hubs`` agents,
+    joined to a 6-NN cosine graph: a few rows far wider than the rest."""
+    rng = np.random.default_rng(seed)
+    knn = as_csr(knn_cosine_graph(rng.normal(size=(n, 8)), k=6))
+    spokes = [rng.choice(np.arange(hubs, n), hub_degree, replace=False) for _ in range(hubs)]
+    rows = np.concatenate([knn.row_ids(), np.repeat(np.arange(hubs), hub_degree)])
+    cols = np.concatenate([knn.indices, *spokes])
+    return csr_from_coo(n, rows, cols, np.ones(len(rows)), symmetrize=True)
+
+
+def _uniform_block(op, B):
+    """The CSR gather's edge block for B rows drawn uniformly."""
+    return mix_edge_block(np.diff(op.indptr), np.full(op.n, B / op.n), B)
+
+
+@pytest.mark.parametrize(
+    "case", ["dense", "sparse", "kernel", "hub", "sentinel", "two_trips"]
+)
+def test_mix_gather_rows_batched_parity(case):
+    """gather_rows (the repro.sim woken-rows path) == stacked row() calls:
+    on both backends, the interpreted kernel route against the plain one,
+    and the CSR edge gather on a hub graph (where B·K is far more than the
+    rows' real edges), with the padding sentinel n among the rows (whose
+    sums are 0), and with more edges than one block holds (two trips or
+    more of its loop)."""
     rng = np.random.default_rng(8)
-    g = knn_cosine_graph(rng.normal(size=(40, 8)), k=6)
-    Theta = jnp.asarray(rng.normal(size=(40, 9)), jnp.float32)
-    rows = jnp.asarray([0, 3, 17, 39, 5])
-    for mode in ("dense", "sparse"):
-        op = mix_op(g, mode=mode)
-        got = np.asarray(op.gather_rows(Theta, rows))
+    if case in ("dense", "sparse", "kernel"):
+        g = knn_cosine_graph(rng.normal(size=(40, 8)), k=6)
+        Theta = jnp.asarray(rng.normal(size=(40, 9)), jnp.float32)
+        rows = jnp.asarray([0, 3, 17, 39, 5])
+        if case == "kernel":
+            sparse = mix_op(g, mode="sparse")
+            block = _uniform_block(sparse, len(rows))
+            kern = np.asarray(sparse.gather_rows(Theta, rows, use_kernel=True)[0])
+            plain = np.asarray(sparse.gather_rows(Theta, rows, use_kernel=False, edge_block=block)[0])
+            np.testing.assert_allclose(kern, plain, rtol=1e-5, atol=1e-5)
+            return
+        op = mix_op(g, mode=case)
+        block = _uniform_block(op, len(rows)) if case == "sparse" else None
+        got = np.asarray(op.gather_rows(Theta, rows, edge_block=block)[0])
         want = np.stack([np.asarray(op.row(Theta, int(i))) for i in np.asarray(rows)])
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
-    sparse = mix_op(g, mode="sparse")
-    kern = np.asarray(sparse.gather_rows(Theta, rows, use_kernel=True))
-    plain = np.asarray(sparse.gather_rows(Theta, rows, use_kernel=False))
-    np.testing.assert_allclose(kern, plain, rtol=1e-5, atol=1e-5)
+        return
+
+    n = 400
+    g = _hub_knn_graph(n, hubs=3, hub_degree=250, seed=8)
+    op = mix_op(g, mode="sparse")
+    deg = np.diff(g.indptr)
+    Theta = jnp.asarray(rng.normal(size=(n, 9)), jnp.float32)
+    rows = np.array([0, 3, 17, 399, 5, 250, 1])
+    if case == "hub":
+        rows = np.concatenate([[0], rng.choice(np.arange(3, n), 19, replace=False)])
+    elif case == "sentinel":
+        rows = np.array([0, n, 17, n, 399, 5, n])
+    block = 128 if case == "two_trips" else _uniform_block(op, len(rows))
+    got, edges, trips = op.gather_rows(Theta, jnp.asarray(rows), edge_block=block)
+    real = rows[rows < n]
+    assert int(edges) == int(deg[real].sum())
+    K = int(deg.max())
+    if case == "hub":
+        assert len(rows) * K > 10 * int(edges)  # the padded block's slots
+    if case == "two_trips":
+        assert int(trips) == -(-int(edges) // block) >= 2
+    else:
+        assert int(trips) == 1
+    zero = np.zeros(Theta.shape[1], np.float32)
+    want = np.stack(
+        [np.asarray(op.row(Theta, int(i))) if i < n else zero for i in rows]
+    )
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
 
 
 def test_mix_all_kernel_path_parity():

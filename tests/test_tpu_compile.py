@@ -217,3 +217,34 @@ def test_supertick_reads_static_tables_without_relayout(one_chip, widths_engine,
         texts.append(eng._forced.lower(state, static, mask).compile().as_text())
     for text in texts:
         assert not copy_of_static.search(text)
+
+
+def test_supertick_mix_makes_no_padded_neighbour_block(one_chip, widths_engine):
+    """The compiled static chunk sums the woken rows' real CSR edges: no
+    HLO instruction holds the B·K·p elements of the padded (B, K, p)
+    neighbour block (K = 300, the hub's degree), while the edge block's
+    (E, p) rows are there."""
+    import math
+    import re
+
+    import numpy as np
+
+    eng = widths_engine
+    K = int(np.diff(eng.update.mix.indptr).max())
+    assert K == 300
+    shape = re.compile(r"\b(?:pred|[suf]\d+|bf16)\[([\d,]*)\]")
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    with jax.enable_x64(False):
+        theta = jax.ShapeDtypeStruct((eng.n, eng.p), eng.dtype)
+        state = jax.tree.map(spec, jax.eval_shape(eng.init_state, theta))
+        static = jax.tree.map(spec, eng._static)
+        text = eng._chunk.lower(state, static, eng.steps_per_chunk).compile().as_text()
+    sizes = {
+        math.prod(int(d) for d in dims.split(",")) if dims else 1
+        for dims in shape.findall(text)
+    }
+    assert eng.mix_edge_block * eng.p in sizes
+    assert eng.batch_size * K * eng.p not in sizes
