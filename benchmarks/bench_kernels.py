@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops, ref
+from repro.kernels import ref
 
 
 def _time(fn, *args, iters=5):
@@ -46,31 +46,6 @@ def run(verbose=True):
     x = jnp.asarray(rng.normal(size=(G, Q, Pd)), jnp.float32)
     us_ref = _time(jax.jit(ref.ssm_chunk_ref), C, B, cum, dt, x)
     rows.append(("ssm_chunk_ref_8x128", us_ref, "oracle jnp"))
-
-    # Fused woken-row super-tick: B=256 woken rows, K=16 neighbours, m=4
-    # data points, p=128 features over a 4096-row slab — the engine hot
-    # path (gather + mix + Eq. 4 + scatter in one launch).
-    Bf, K, m, p, nt = 256, 16, 4, 128, 4096
-    frows = jnp.asarray(rng.choice(nt, size=Bf, replace=False).astype(np.int32))
-    fidx = jnp.asarray(rng.integers(0, nt, size=(Bf, K)).astype(np.int32))
-    fw = jnp.asarray(rng.random((Bf, K)), jnp.float32)
-    coef = jnp.asarray(
-        np.stack([np.full(Bf, 0.5), np.full(Bf, float(K)),
-                  np.full(Bf, 0.1), np.full(Bf, 0.2)], 1), jnp.float32)
-    fX = jnp.asarray(rng.normal(size=(Bf, m, p)), jnp.float32)
-    fy = jnp.asarray(rng.normal(size=(Bf, m)), jnp.float32)
-    fmask = jnp.ones((Bf, m), jnp.float32)
-    fnoise = jnp.zeros((Bf, p), jnp.float32)
-    ftheta = jnp.asarray(rng.normal(size=(nt, p)), jnp.float32)
-    us_ref = _time(
-        jax.jit(lambda *a: ref.fused_row_update_ref(*a, limit=nt)),
-        frows, fidx, fw, coef, fX, fy, fmask, fnoise, ftheta)
-    rows.append(("fused_row_update_ref_256x128", us_ref, "oracle jnp"))
-    us_k = _time(
-        lambda *a: ops.fused_row_update(*a, limit=nt),
-        frows, fidx, fw, coef, fX, fy, fmask, fnoise, ftheta)
-    rows.append(("fused_row_update_256x128", us_k,
-                 "pallas (interpret-mode on CPU; TPU path is the engine hot loop)"))
 
     if verbose:
         for name, us, note in rows:

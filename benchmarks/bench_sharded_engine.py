@@ -85,7 +85,6 @@ def run(
     partition_mode: str = "degree",
     relabel: str | None = "rcm",
     exchange: str = "auto",
-    fused="auto",
     metrics: bool = False,
     roofline: bool | None = None,
     verbose: bool = True,
@@ -93,10 +92,7 @@ def run(
     """Time the sharded engine at scale and report the comm sweep rows.
 
     ``exchange`` takes an :class:`repro.core.mixing.ExchangeSpec` or a
-    spec string (``"auto"``, ``"p2p:bf16"``, ``"p2p:int8:ef"`` ...);
-    ``fused`` is the EngineConfig knob (``"auto"`` engages the fused
-    super-tick kernel on TPU only — forcing ``True`` on a CPU host runs
-    the kernel in interpret mode, which is not a perf configuration).
+    spec string (``"auto"``, ``"p2p:bf16"``, ``"p2p:int8:ef"`` ...).
     ``roofline`` places the super-tick against the chip's peaks
     (:mod:`repro.roofline.peaks`); None does so on a TPU only, since the
     CPU has no peaks to place it against.
@@ -141,7 +137,6 @@ def run(
         slot_wakes=slot_wakes,
         scenario=scenario,
         seed=seed,
-        fused=fused,
         metrics=metrics,
     )
     part_s = time.time() - t0
@@ -189,7 +184,7 @@ def run(
         ("sharded_super_tick", steady_s * 1e6 / slots,
          f"n={n} S={shards} B={engine.batch_size} churn={int(churn)} "
          f"exchange={engine.exchange_method}:{wire.dtype}"
-         f"{':ef' if wire.error_feedback else ''} fused={int(engine.fused)} "
+         f"{':ef' if wire.error_feedback else ''} "
          f"xbytes={xbytes} us/slot"),
         ("sharded_equiv_ticks_per_s", ticks_per_s,
          f"{applied} wakes applied, {int(np.asarray(state.dropped).sum())} dropped, "
@@ -210,8 +205,7 @@ def run(
                 )
     if roofline:
         # Place the compiled super-tick on the bandwidth roofline (the
-        # program advance() just ran, fused kernel and compressed halos
-        # included) and report the measured-vs-bound gap.
+        # program advance() just ran, compressed halos included) and report the measured-vs-bound gap.
         from repro.roofline import supertick_report
 
         rows += supertick_report(
@@ -241,7 +235,6 @@ def main(argv=None):
                     help="ExchangeSpec string: method[:dtype[:ef]] with method "
                          "auto|all_gather|p2p and dtype f32|bf16|int8 "
                          "(e.g. p2p:bf16, p2p:int8:ef)")
-    ap.add_argument("--fused", default="auto", choices=["auto", "on", "off"])
     ap.add_argument("--metrics", action="store_true",
                     help="run with in-jit telemetry on and report its totals")
     ap.add_argument("--no-roofline", action="store_true")
@@ -258,7 +251,6 @@ def main(argv=None):
         partition_mode=args.mode,
         relabel=None if args.relabel == "none" else args.relabel,
         exchange=args.exchange,
-        fused={"auto": "auto", "on": True, "off": False}[args.fused],
         metrics=args.metrics,
         roofline=False if args.no_roofline else None,
     )

@@ -112,7 +112,7 @@ def _bench_kernels(full, rows, record):
 
     t0 = time.time()
     ks = bench_kernels.run()
-    # Per-kernel rows (fused_row_update etc.) join the summary alongside
+    # Per-kernel rows (dp_clip_noise_ref etc.) join the summary alongside
     # the aggregate, so kernel-level perf has its own trajectory.
     rows.extend(ks)
     record("kernels", t0, f"{len(ks)} kernels timed")

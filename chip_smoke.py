@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Smoke run of the engine's main path on a TPU, through the user entry points.
 
-    python3 chip_smoke.py              # one chip: the three phases below
+    python3 chip_smoke.py              # one chip: the two phases below
     python3 chip_smoke.py --chips 4    # four chips: the sharded engine only
 
 One chip, one process, in order:
@@ -15,9 +15,6 @@ One chip, one process, in order:
    the served version.
 2. ``engine_dpcd`` — a few slots of the same deployment under ``DPCDUpdate``
    with a per-agent budget. Fails if any agent spends more than its budget.
-3. ``fused_kernel`` — at n = 4096 ``fused="auto"`` must engage the fused
-   Pallas kernel, compiled (the program holds a ``tpu_custom_call``), and
-   agree with the unfused engine under forced wakes to 1e-6.
 
 With ``--chips 4``: ``sharded_engine`` (S = 4 with RCM relabel and the p2p
 halo exchange at four times the one-chip n, every shard's state and tiles
@@ -29,10 +26,10 @@ under forced wakes, n = 262,144, to 1e-5).
 
 Each phase prints one ``phase {...}`` line of smoke figures (compile and
 steady seconds, applied wakes, objective before and after, DP spend,
-served-row mismatches, ``peak_bytes_in_use``, fused kernel engaged); none
-is a benchmark metric. Every compiled super-tick is checked for f64. The
-last line is ``{"ok": true, "device": {...}}``, printed only when every
-phase passed; with no TPU the script exits 1 before any phase.
+served-row mismatches, ``peak_bytes_in_use``); none is a benchmark
+metric. Every compiled super-tick is checked for f64. The last line is
+``{"ok": true, "device": {...}}``, printed only when every phase passed;
+with no TPU the script exits 1 before any phase.
 """
 
 from __future__ import annotations
@@ -182,7 +179,6 @@ def run_engine_phase(name, engine, obj, n, seed, objective_of, theta_of, devices
         "objective_after": q1,
         "served_row_mismatches": mism,
         "predict_requests": reqs,
-        "fused": bool(engine.fused),
         "peak_bytes_in_use": peak_bytes(devices),
         **(extra or {}),
     }
@@ -251,48 +247,6 @@ def phase_engine_dpcd(obj, seed: int, devices) -> None:
         peak_bytes_in_use=peak_bytes(devices),
     )
     check(float(eps.max()) <= cfg.eps_bar * (1 + 1e-9), "an agent exceeded its DP budget")
-
-
-def phase_fused_kernel(seed: int, devices) -> None:
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.core.mixing import kernel_max_n
-    from repro.sim import CDUpdate, make_engine
-
-    n = kernel_max_n()
-    obj = make_deployment(n, seed)
-    cfg = churn_config(64.0, seed).replace(batch_size=512)
-    fused = make_engine(CDUpdate(obj), cfg)
-    plain = make_engine(CDUpdate(obj), cfg.replace(fused=False))
-    check(fused.fused, f"fused='auto' did not engage the fused kernel at n={n}")
-    rng = np.random.default_rng(seed + 2)
-    masks = [rng.random(n) < 0.05 for _ in range(8)]
-    theta0 = np.zeros((n, P), np.float32)
-    sf, su = fused.init_state(theta0), plain.init_state(theta0)
-    with compile_seconds() as comp:
-        for mask in masks:
-            sf, su = fused.step(sf, mask), plain.step(su, mask)
-    err = float(np.abs(np.asarray(sf.Theta) - np.asarray(su.Theta)).max())
-    forced = fused._forced.lower(sf, fused._static, jnp.asarray(masks[0])).compile().as_text()
-    chunk = fused._chunk.lower(sf, fused._static, 16).compile().as_text()
-    kernel_ran = "tpu_custom_call" in forced and "tpu_custom_call" in chunk
-    assert_no_f64(chunk, "fused super-tick")
-    sampled = fused.run(None, 32, state=sf)
-    emit(
-        "fused_kernel",
-        n=n,
-        compile_s=round(comp["s"], 3),
-        compile_cache_hits=comp["cache_hits"],
-        forced_slots=len(masks),
-        max_abs_diff_vs_unfused=err,
-        fused_kernel_compiled=kernel_ran,
-        sampled_applied_wakes=sampled.wakes_applied,
-        peak_bytes_in_use=peak_bytes(devices),
-    )
-    check(kernel_ran, "the fused kernel is not a tpu_custom_call in the compiled program")
-    check(err <= 1e-6, f"fused vs unfused max |diff| {err} > 1e-6")
-    check(bool(np.isfinite(sampled.Theta).all()), "non-finite Theta from the fused run")
 
 
 def phase_sharded_parity(seed: int, devices) -> None:
@@ -431,9 +385,6 @@ def main(argv=None) -> int:
     if args.chips == 1:
         obj = phase_engine_cd(args.seed, devices)
         phase_engine_dpcd(obj, args.seed, devices)
-        del obj
-        gc.collect()
-        phase_fused_kernel(args.seed, devices)
     else:
         # The sharded engine first, so each device's peak bytes are its own
         # share's; the parity phase then puts the whole S = 1 graph on one.

@@ -1,9 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
 ``interpret=None`` compiles the kernels for the TPU when JAX's backend is
-a TPU and interprets them anywhere else (the CPU test runs). The engine
-and mixing gates engage the kernels only on a TPU, so interpretation
-never stands in for the device on a program path.
+a TPU and interprets them anywhere else (the CPU test runs). The mixing
+gate engages the kernels only on a TPU, so interpretation never stands
+in for the device on a program path.
 """
 
 from __future__ import annotations
@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import dp_clip_noise as _dpk
-from repro.kernels import fused_row_update as _frk
 from repro.kernels import graph_mix as _gmk
 from repro.kernels import sparse_mix as _smk
 from repro.kernels import ssm_scan as _ssk
@@ -22,27 +21,6 @@ from repro.kernels import ssm_scan as _ssk
 
 def _default_interpret():
     return jax.default_backend() != "tpu"
-
-
-# Scoped VMEM that Mosaic lets one kernel use on a TPU v5e by default.
-VMEM_LIMIT_BYTES = 16 * 2**20
-
-
-def _round_up(x: int, mult: int) -> int:
-    return -(-int(x) // mult) * mult
-
-
-def fused_row_update_fits(nt: int, p: int, m: int, block_b: int = 8) -> bool:
-    """Whether the fused kernel's blocks fit a v5e kernel's scoped VMEM.
-
-    Counts every block double-buffered, in f32 at its lane- and
-    sublane-padded size: the (nt, p) slab in and out, and each grid
-    step's (block_b, ...) tiles of data, weights (K < nt) and coefficients.
-    """
-    pp, m8 = _round_up(p, 128), _round_up(m, 8)
-    slab = _round_up(nt, 8) * pp
-    tiles = block_b * (m8 * pp + 2 * _round_up(m, 128) + _round_up(nt, 128) + 128 + pp)
-    return 4 * 2 * (2 * slab + tiles) <= VMEM_LIMIT_BYTES
 
 
 def _pad_to(x, mult, axis):
@@ -103,53 +81,6 @@ def sparse_mix(idx, w, theta, block_a=8, block_p=256, interpret=None):
     with jax.named_scope("obs.sparse_mix"):
         t = _pad_to(theta, bp, 1)
         out = _smk.sparse_mix(idx, w, t, block_a=block_a, block_p=bp, interpret=interpret)
-        return out[:, :p]
-
-
-@functools.partial(jax.jit, static_argnames=("limit", "clip", "block_b", "interpret"))
-def fused_row_update(
-    rows, idx, w, coef, X, y, mask, noise, theta, limit, clip=None, block_b=8, interpret=None
-):
-    """Fused woken-row super-tick: gather + mix + Eq. 4 + scatter in one launch.
-
-    rows (B,) slab rows (sentinel >= limit skipped); idx/w (B, K)
-    row-gathered neighbour tables over the slab; coef (B, 4) per-row
-    [alpha, deg, mu*conf, 2*lam]; X (B, m, p), y/mask (B, m); noise
-    (B, p); theta (nt, p). Returns the (nt, p) f32 updated slab.
-    Quadratic loss only — see ``repro.kernels.fused_row_update``.
-    """
-    interpret = _default_interpret() if interpret is None else interpret
-    nt, p = theta.shape
-    B = rows.shape[0]
-    bb = min(8 if block_b is None else block_b, max(8, B))
-    f32 = jnp.float32
-    with jax.named_scope("obs.fused_row_update"):
-        # Pad the feature dim to one lane-aligned tile (the in-kernel gradient
-        # needs whole rows, so p is never split) and the row batch to a tile
-        # multiple with sentinel rows (computed, never scattered).
-        theta_p = _pad_to(theta.astype(f32), 128, 1)
-        Xp = _pad_to(_pad_to(X.astype(f32), 128, 2), 8, 1)
-        yp = _pad_to(y.astype(f32), 8, 1)
-        mp = _pad_to(mask.astype(f32), 8, 1)
-        rows_p = _pad_to(rows.astype(jnp.int32), bb, 0)
-        pad_b = rows_p.shape[0] - B
-        if pad_b:
-            rows_p = rows_p.at[B:].set(jnp.int32(limit))
-        idx_p = _pad_to(idx.astype(jnp.int32), bb, 0)
-        w_p = _pad_to(w.astype(f32), bb, 0)
-        coef_p = _pad_to(_pad_to(coef.astype(f32), 128, 1), bb, 0)
-        # Padded coef rows carry deg=0; set deg=1 so the sentinel rows' dead
-        # arithmetic stays finite (0/0 NaNs would trip debug-nan runs).
-        if pad_b:
-            coef_p = coef_p.at[B:, 1].set(1.0)
-        Xp = _pad_to(Xp, bb, 0)
-        yp = _pad_to(yp, bb, 0)
-        mp = _pad_to(mp, bb, 0)
-        noise_p = _pad_to(_pad_to(noise.astype(f32), 128, 1), bb, 0)
-        out = _frk.fused_row_update(
-            rows_p, idx_p, w_p, coef_p, Xp, yp, mp, noise_p, theta_p,
-            limit=limit, clip=clip, block_b=bb, interpret=interpret,
-        )
         return out[:, :p]
 
 

@@ -72,35 +72,3 @@ def ssm_chunk_ref(C, B, cum, dt, x):
     w_end = jnp.exp(jnp.clip(cum[:, -1:] - cum, -60.0, 0.0)) * dt  # (G,Q)
     s_loc = jnp.einsum("gq,gqp,gqn->gpn", w_end, x, B)
     return y, s_loc
-
-
-def fused_row_update_ref(rows, idx, w, coef, X, y, mask, noise, theta, limit, clip=None):
-    """Fused woken-row super-tick: gather + mix + Eq. 4 + drop-mode scatter.
-
-    rows: (B,) slab rows (entries >= limit are sentinels, never written);
-    idx/w: (B, K) row-gathered padded neighbour tables over the slab;
-    coef: (B, 4+) per-row [alpha, deg, mu*conf, 2*lam]; X: (B, m, p),
-    y/mask: (B, m) padded data rows; noise: (B, p); theta: (nt, p).
-    Returns the (nt, p) f32 updated slab — same contract as
-    ``fused_row_update`` (quadratic loss, optional per-point L1 clip).
-    """
-    t32 = theta.astype(jnp.float32)
-    nt = t32.shape[0]
-    safe = jnp.minimum(rows, nt - 1)
-    tr = t32[safe]  # (B, p)
-    neigh = jnp.einsum("bk,bkp->bp", w.astype(jnp.float32), t32[idx])
-    X32 = X.astype(jnp.float32)
-    resid = 2.0 * (jnp.einsum("bmp,bp->bm", X32, tr) - y.astype(jnp.float32))
-    if clip is not None:
-        norms = jnp.abs(resid) * jnp.sum(jnp.abs(X32), axis=-1)
-        resid = resid * jnp.minimum(1.0, clip / jnp.maximum(norms, 1e-12))
-    m32 = mask.astype(jnp.float32)
-    m_hat = jnp.maximum(jnp.sum(m32, axis=-1), 1.0)
-    g_sum = jnp.einsum("bm,bmp->bp", resid * m32, X32)
-    c32 = coef.astype(jnp.float32)
-    alpha, deg, cmu, lam2 = c32[:, 0:1], c32[:, 1:2], c32[:, 2:3], c32[:, 3:4]
-    grads = g_sum / m_hat[:, None] + lam2 * tr + noise.astype(jnp.float32)
-    new = (1.0 - alpha) * tr + alpha * (neigh / deg - cmu * grads)
-    keep = rows < limit
-    tgt = jnp.where(keep, rows, nt)
-    return t32.at[tgt].set(jnp.where(keep[:, None], new, 0.0), mode="drop")

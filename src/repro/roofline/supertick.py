@@ -2,7 +2,7 @@
 
 Lowers an already-built :class:`repro.sim.AsyncEngine` /
 :class:`repro.sim.ShardedAsyncEngine` chunk — the exact jitted program
-``advance`` runs, fused kernel and compressed halo exchange included —
+``advance`` runs, compressed halo exchange included —
 and pushes the compiled HLO through :func:`repro.roofline.analyze_compiled`
 to place the super-tick against the three-term bandwidth roofline:
 

@@ -39,11 +39,6 @@ class EngineConfig:
     * ``scenario``: churn / delay / straggler bundle (None = none);
     * ``seed`` / ``dtype`` / ``steps_per_chunk``: PRNG seed, model dtype,
       super-ticks per jitted scan chunk;
-    * ``fused``: woken-row hot-path selection — ``"auto"`` engages the
-      fused Pallas kernel on TPU for f32 quadratic-loss updates at
-      on-chip slab sizes (``REPRO_KERNEL_MAX_N``), ``True`` forces it
-      (interpreted off-TPU; tests), ``False`` keeps the unfused
-      gather/mix/update/scatter ops;
     * ``metrics``: in-jit telemetry — a
       :class:`repro.obs.MetricsSpec` selecting counter groups, ``True``
       for the default spec, ``None``/``False`` (default) for no
@@ -84,7 +79,6 @@ class EngineConfig:
     seed: int = 0
     dtype: Any = jnp.float32
     steps_per_chunk: int = 16
-    fused: Any = "auto"  # False | True | "auto"
     metrics: Any = None  # MetricsSpec | True | False | None
     partition_mode: str = "degree"
     relabel: Any = None
@@ -94,10 +88,6 @@ class EngineConfig:
     devices: Any = None
     graph_update: Any = None  # GraphUpdate | None (None = static topology)
     drift_threshold: float = 0.25
-
-    def __post_init__(self):
-        if self.fused not in (False, True, "auto"):
-            raise ValueError(f"fused must be False, True, or 'auto', got {self.fused!r}")
 
     def exchange_spec(self) -> ExchangeSpec:
         """The coerced exchange spec (warns on deprecated bare strings)."""

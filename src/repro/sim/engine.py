@@ -55,7 +55,7 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.graph import TopologyState, as_csr, csr_from_coo, neighbor_counts
-from repro.core.mixing import kernel_max_n, mix_edge_block, sharded_mix_op
+from repro.core.mixing import mix_edge_block, sharded_mix_op
 from repro.core.model_propagation import propagation_rows_from
 from repro.core.spmd_compat import shard_map
 from repro.obs.metrics import ExchangeVolume, MetricsAccumulator, topology_log_init
@@ -97,37 +97,6 @@ def _scan_compiler_options(rows: int, p: int, dtype) -> dict | None:
     options, vmem_bytes = guard
     slab_bytes = rows * (-(-p // 8) * 8) * jnp.dtype(dtype).itemsize
     return options if 2 * slab_bytes >= vmem_bytes else None
-
-
-def _resolve_fused(update, fused, slab_rows: int, dtype, has_delay: bool) -> bool:
-    """Resolve the tri-state ``fused`` knob against what the kernel serves.
-
-    ``"auto"`` engages only where the Pallas kernel is the right tool
-    (same gate family as :meth:`repro.core.mixing.MixOp._kernel_auto`):
-    compiled TPU lowering, f32 models, an update that implements the
-    fused row math (quadratic loss), no per-edge delays, and a slab
-    within ``REPRO_KERNEL_MAX_N`` whose blocks (slab width p, m data
-    points per agent) fit the kernel's VMEM. ``True`` forces the kernel
-    (interpreted off-TPU — tests and parity checks); ``False`` keeps the
-    unfused ops.
-    """
-    supported = bool(getattr(update, "fused_supported", False)) and not has_delay
-    if fused == "auto":
-        from repro.kernels.ops import fused_row_update_fits
-
-        return (
-            supported
-            and jax.default_backend() == "tpu"
-            and jnp.dtype(dtype) == jnp.dtype(jnp.float32)
-            and slab_rows <= kernel_max_n()
-            and fused_row_update_fits(slab_rows, update.p, update.obj.data.X.shape[1])
-        )
-    if fused:
-        if not supported:
-            reason = "a delay scenario" if has_delay else type(update).__name__
-            raise ValueError(f"fused=True but the fused path does not serve {reason}")
-        return True
-    return False
 
 
 def _row_packing(shape, wanted):
@@ -583,11 +552,8 @@ class AsyncEngine:
     Configured by :class:`repro.sim.EngineConfig` (``config=...``); the
     historical keyword arguments (``slot_wakes``, ``rates``,
     ``batch_size``, ``scenario``, ``seed``, ``dtype``,
-    ``steps_per_chunk``, ``fused``) still work as overrides merged into
-    the config — see the ``EngineConfig`` docstring for what each knob
-    means. With ``fused`` on (``"auto"`` engages it on TPU for f32
-    quadratic-loss updates at on-chip n), the woken-row hot path runs as
-    one ``fused_row_update`` Pallas launch instead of four XLA ops.
+    ``steps_per_chunk``) still work as overrides merged into the config
+    — see the ``EngineConfig`` docstring for what each knob means.
     """
 
     def __init__(self, update: LocalUpdate, *, config: EngineConfig | None = None, **kw):
@@ -616,11 +582,6 @@ class AsyncEngine:
                 "dynamic topology and per-edge delays do not compose yet: the "
                 "snapshot-ring delay tiles are baked per graph"
             )
-        if self.dynamic and cfg.fused is True:
-            raise ValueError(
-                "fused=True is static-topology only (the Pallas slab bakes the "
-                "neighbour tables); leave fused='auto' for dynamic runs"
-            )
 
         self._deg_counts = np.asarray(neighbor_counts(update.graph), dtype=np.float32)
         churn = self.scenario.churn
@@ -644,27 +605,13 @@ class AsyncEngine:
         else:
             self._idx = self._w = self._delays = None
 
-        fused_knob = False if self.dynamic else cfg.fused
-        self.fused = _resolve_fused(update, fused_knob, self.n, self.dtype, delay is not None)
-        if self.fused:
-            # The fused kernel consumes padded (n, K) neighbour tables
-            # whatever the MixOp backend (same tile build as the delay
-            # path above — dense graphs go through the CSR form).
-            mix = update.mix
-            if getattr(mix, "kind", None) == "sparse":
-                self._fidx, self._fw = np.asarray(mix.idx), np.asarray(mix.w)
-            else:
-                self._fidx, self._fw = as_csr(update.graph).padded_neighbors()
-        else:
-            self._fidx = self._fw = None
-
         # The static super-tick's neighbour sums run over the woken rows'
         # CSR edges wherever the update's MixOp gathers them so, in blocks
         # sized from the degrees, the wake probabilities and B.
         mix = update.mix
         self.mix_edge_block = (
             mix_edge_block(np.diff(mix.indptr), self.wake_probs, self.batch_size)
-            if not (self.dynamic or self.fused or delay) and mix.gathers_csr(self.dtype)
+            if not (self.dynamic or delay) and mix.gathers_csr(self.dtype)
             else None
         )
 
@@ -752,9 +699,6 @@ class AsyncEngine:
             tables["idx"] = np.asarray(self._idx)
             tables["w"] = np.asarray(self._w, self.dtype)
             tables["delays"] = np.asarray(self._delays)
-        elif self.fused:
-            tables["idx"] = np.asarray(self._fidx)
-            tables["w"] = np.asarray(self._fw, np.float32)
         else:
             tables["mix"] = self.update.mix.tables(self.dtype, xp=np)
         consts_fn = getattr(self.update, "agent_constants", None)
@@ -914,47 +858,35 @@ class AsyncEngine:
             consts_rows = None if consts is None else jax.tree.map(
                 lambda t: t[safe], consts, is_leaf=lambda t: isinstance(t, _RowTable)
             )
-        if self.fused and self._delays is None:
-            with jax.named_scope("obs.fused_row_update"):
-                # One Pallas launch: gather + mix + Eq. 4/6 + drop-mode scatter.
-                hist = state.hist
+        with jax.named_scope("obs.gather_mix"):
+            if self._delays is not None:
+                hist = state.hist.at[state.ptr % self.depth].set(Theta)
                 cols = static["idx"][safe]  # (B, K)
-                ww = static["w"][safe]  # (B, K)
-                new_slab, applied, ustate = self.update.apply_fused(
-                    Theta, woken, valid, k_upd, state.ustate, cols, ww,
+                w = static["w"][safe]  # (B, K)
+                dly = static["delays"][safe]  # (B, K)
+                slots = jnp.mod(state.ptr - dly, self.depth)
+                vals = hist[slots, cols]  # (B, K, p)
+                neigh = jnp.einsum("bk,bkp->bp", w, vals, precision=_HIGHEST)
+            else:
+                hist = state.hist
+                neigh, *mix_counts = self.update.mix.gather_rows(
+                    Theta, woken, tables=static["mix"], edge_block=self.mix_edge_block
+                )
+
+        with jax.named_scope("obs.row_update"):
+            if consts_rows is None:
+                new_rows, applied, ustate = self.update.apply(
+                    Theta, woken, valid, neigh, k_upd, state.ustate
+                )
+            else:
+                new_rows, applied, ustate = self.update.apply_rows(
+                    Theta[safe], woken, valid, neigh, k_upd, state.ustate,
                     srows=woken, ssize=n, consts=consts_rows,
                 )
-                Theta = new_slab.astype(Theta.dtype)
-        else:
-            with jax.named_scope("obs.gather_mix"):
-                if self._delays is not None:
-                    hist = state.hist.at[state.ptr % self.depth].set(Theta)
-                    cols = static["idx"][safe]  # (B, K)
-                    w = static["w"][safe]  # (B, K)
-                    dly = static["delays"][safe]  # (B, K)
-                    slots = jnp.mod(state.ptr - dly, self.depth)
-                    vals = hist[slots, cols]  # (B, K, p)
-                    neigh = jnp.einsum("bk,bkp->bp", w, vals, precision=_HIGHEST)
-                else:
-                    hist = state.hist
-                    neigh, *mix_counts = self.update.mix.gather_rows(
-                        Theta, woken, tables=static["mix"], edge_block=self.mix_edge_block
-                    )
 
-            with jax.named_scope("obs.row_update"):
-                if consts_rows is None:
-                    new_rows, applied, ustate = self.update.apply(
-                        Theta, woken, valid, neigh, k_upd, state.ustate
-                    )
-                else:
-                    new_rows, applied, ustate = self.update.apply_rows(
-                        Theta[safe], woken, valid, neigh, k_upd, state.ustate,
-                        srows=woken, ssize=n, consts=consts_rows,
-                    )
-
-            with jax.named_scope("obs.scatter"):
-                tgt = jnp.where(applied, woken, n)
-                Theta = Theta.at[tgt].set(new_rows.astype(Theta.dtype), mode="drop")
+        with jax.named_scope("obs.scatter"):
+            tgt = jnp.where(applied, woken, n)
+            Theta = Theta.at[tgt].set(new_rows.astype(Theta.dtype), mode="drop")
 
         with jax.named_scope("obs.finalize"):
             deg = static["deg"][safe]
@@ -1033,8 +965,8 @@ class AsyncEngine:
         return tiles
 
     def _slot_dyn(self, state: SimState, tiles: dict, wake_mask) -> SimState:
-        """One super-tick against the live-topology tiles (no fused or
-        delay variants: both bake per-graph structure into the program)."""
+        """One super-tick against the live-topology tiles (no delay
+        variant: its tiles bake per-graph structure into the program)."""
         n, B = self.n, self.batch_size
         with jax.named_scope("obs.wake_sample"):
             key, k_leave, k_rejoin, k_wake, k_strag, k_upd = jax.random.split(
@@ -1236,7 +1168,6 @@ class AsyncEngine:
             "p": self.p,
             "slot_wakes": float(self.config.slot_wakes),
             "batch_size": int(self.batch_size),
-            "fused": bool(self.fused),
             "dtype": str(jnp.dtype(self.dtype).name),
             "static_tables_relaid": self._relaid[0],
             "static_relaid_bytes": self._relaid[1],
@@ -1399,9 +1330,7 @@ class ShardedAsyncEngine:
     slot's payload instead of biasing the fixed point — the accumulator
     rides in ``ShardedSimState.ef``). Configuration arrives as a shared
     :class:`repro.sim.EngineConfig` (``config=...``), with the old
-    keyword arguments still accepted as overrides; ``fused`` collapses
-    the woken-row path into the ``fused_row_update`` Pallas kernel over
-    the halo-extended slab.
+    keyword arguments still accepted as overrides.
 
     Per-agent data and theory constants are **shard-resident**: the
     engine tiles ``update.agent_constants()`` (datasets X/y/mask,
@@ -1456,11 +1385,6 @@ class ShardedAsyncEngine:
             )
         self.dynamic = cfg.graph_update is not None or self.scenario.arrival is not None
         self.topology_log = topology_log_init()
-        if self.dynamic and cfg.fused is True:
-            raise ValueError(
-                "fused=True is static-topology only (the Pallas slab bakes the "
-                "neighbour tables); leave fused='auto' for dynamic runs"
-            )
         self._pending: set[int] = set()
         csr = as_csr(update.graph)
         if self.dynamic:
@@ -1551,12 +1475,6 @@ class ShardedAsyncEngine:
             )
         self._rebuild_static()
 
-        # The sharded slab is the halo-extended block (R + Hmax rows) —
-        # that is what the fused kernel keeps VMEM-resident per shard.
-        fused_knob = False if self.dynamic else cfg.fused
-        self.fused = _resolve_fused(
-            update, fused_knob, R + self.smix.halo_width, self.dtype, False
-        )
         self._use_ef = self.smix.error_feedback
 
         self._chunk = jax.jit(
@@ -1827,30 +1745,18 @@ class ShardedAsyncEngine:
                 if static.consts is None
                 else jax.tree.map(lambda t: t[0][safe], static.consts)
             )
-        if self.fused:
-            with jax.named_scope("obs.fused_row_update"):
-                # One Pallas launch over the halo-extended slab: gather + mix
-                # + Eq. 4/6 + scatter; owned rows [:R] come back updated.
-                cols = static.idx[0][safe]  # (B, K) extended-local indices
-                ww = jnp.asarray(static.w[0], jnp.float32)[safe]  # (B, K)
-                new_ext, applied, ustate = self.update.apply_fused(
-                    Theta_ext, grows, valid, k_upd, ustate, cols, ww,
-                    srows=woken, ssize=R, consts=consts_rows,
-                )
-                Theta = new_ext[:R].astype(Theta.dtype)
-        else:
-            with jax.named_scope("obs.gather_mix"):
-                neigh = self.smix.gather_rows(
-                    Theta_ext, static.idx[0], static.w[0], woken
-                )
-            with jax.named_scope("obs.row_update"):
-                new_rows, applied, ustate = self.update.apply_rows(
-                    Theta[safe], grows, valid, neigh, k_upd, ustate,
-                    srows=woken, ssize=R, consts=consts_rows,
-                )
-            with jax.named_scope("obs.scatter"):
-                tgt = jnp.where(applied, woken, R)
-                Theta = Theta.at[tgt].set(new_rows.astype(Theta.dtype), mode="drop")
+        with jax.named_scope("obs.gather_mix"):
+            neigh = self.smix.gather_rows(
+                Theta_ext, static.idx[0], static.w[0], woken
+            )
+        with jax.named_scope("obs.row_update"):
+            new_rows, applied, ustate = self.update.apply_rows(
+                Theta[safe], grows, valid, neigh, k_upd, ustate,
+                srows=woken, ssize=R, consts=consts_rows,
+            )
+        with jax.named_scope("obs.scatter"):
+            tgt = jnp.where(applied, woken, R)
+            Theta = Theta.at[tgt].set(new_rows.astype(Theta.dtype), mode="drop")
 
         with jax.named_scope("obs.finalize"):
             messages = state.messages[0] + jnp.sum(
@@ -2107,7 +2013,6 @@ class ShardedAsyncEngine:
             "num_shards": int(self.num_shards),
             "slot_wakes": float(self.config.slot_wakes),
             "batch_size": int(self.batch_size),
-            "fused": bool(self.fused),
             "dtype": str(jnp.dtype(self.dtype).name),
             "exchange_method": self.exchange_method,
             "exchange_dtype": self.smix.dtype,

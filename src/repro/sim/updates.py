@@ -56,43 +56,6 @@ from repro.core.model_propagation import (
 from repro.core.objective import Objective
 
 
-def _eq4_fused_slab(obj, Theta_slab, krows, cols, w, consts, noise, limit, interpret):
-    """Run the fused Pallas kernel for one Eq. 4/6 woken batch.
-
-    ``consts`` is the row-gathered :func:`eq4_agent_constants` slice
-    (each leaf (B, ...)); the per-row coefficient pack mirrors the
-    unfused ``eq4_theta_rows_from`` term grouping exactly —
-    ``[alpha, deg, mu * conf, 2 * lam]`` — so the two paths differ only
-    in f32 reduction order (recorded in docs/DEVIATIONS.md).
-    """
-    from repro.kernels import ops
-
-    f32 = jnp.float32
-    coef = jnp.stack(
-        [
-            jnp.asarray(consts["alpha"], f32),
-            jnp.asarray(consts["deg"], f32),
-            jnp.asarray(obj.mu, f32) * jnp.asarray(consts["conf"], f32),
-            2.0 * jnp.asarray(consts["lam"], f32),
-        ],
-        axis=1,
-    )
-    return ops.fused_row_update(
-        krows,
-        cols,
-        w,
-        coef,
-        jnp.asarray(consts["X"], f32),
-        jnp.asarray(consts["y"], f32),
-        jnp.asarray(consts["mask"], f32),
-        noise,
-        Theta_slab,
-        limit=limit,
-        clip=None if obj.clip is None else float(obj.clip),
-        interpret=interpret,
-    )
-
-
 @runtime_checkable
 class LocalUpdate(Protocol):
     """What the engine needs from an update rule.
@@ -208,53 +171,6 @@ class CDUpdate:
             new_rows = eq4_theta_rows_from(self.obj, theta_rows, neigh, consts)
         return new_rows, valid, state
 
-    @property
-    def fused_supported(self) -> bool:
-        """The fused kernel implements the quadratic point grad only."""
-        return self.obj.loss.name == "quadratic"
-
-    def apply_fused(
-        self,
-        Theta_slab,
-        rows,
-        valid,
-        key,
-        state,
-        cols,
-        w,
-        srows=None,
-        ssize=None,
-        consts=None,
-        interpret=None,
-    ):
-        """Fused-kernel Eq. 4 step over a theta slab (single launch).
-
-        ``Theta_slab``: the (nt, p) slab the kernel gathers from and
-        scatters into (single-device: the full Theta; sharded: the
-        halo-extended block). ``rows``: (B,) *global* agent ids (sentinel
-        n) used to gather constants on the replicated path; ``cols``/
-        ``w``: (B, K) row-gathered neighbour tables addressing the slab;
-        ``srows``/``ssize``: local scatter rows and their sentinel
-        (default ``rows``/``n``); ``consts``: shard-resident constant
-        slice as in :meth:`apply_rows`. Returns the updated slab (f32),
-        the applied mask, and the state.
-        """
-        if not self.fused_supported:
-            raise NotImplementedError(
-                f"fused path supports the quadratic loss only, got {self.obj.loss.name!r}"
-            )
-        if srows is None:
-            srows, ssize = rows, self.n
-        if consts is None:
-            safe = jnp.minimum(rows, self.n - 1)
-            consts = jax.tree.map(lambda a: jnp.asarray(a)[safe], eq4_agent_constants(self.obj))
-        krows = jnp.where(valid, srows, ssize)
-        noise = jnp.zeros((srows.shape[0], Theta_slab.shape[1]), jnp.float32)
-        new_slab = _eq4_fused_slab(
-            self.obj, Theta_slab, krows, cols, w, consts, noise, ssize, interpret
-        )
-        return new_slab, valid, state
-
     def objective(self, Theta) -> float:
         """Q(Theta) of Eq. 2 (used by ``record_every``)."""
         return float(self.obj.value(Theta))
@@ -350,57 +266,6 @@ class DPCDUpdate:
             new_rows = eq4_theta_rows_from(self.obj, theta_rows, neigh, consts, grad_noise=noise)
         state = state.at[jnp.where(applied, srows, ssize)].add(1, mode="drop")
         return new_rows, applied, state
-
-    @property
-    def fused_supported(self) -> bool:
-        """The fused kernel implements the quadratic point grad only."""
-        return self.obj.loss.name == "quadratic"
-
-    def apply_fused(
-        self,
-        Theta_slab,
-        rows,
-        valid,
-        key,
-        state,
-        cols,
-        w,
-        srows=None,
-        ssize=None,
-        consts=None,
-        interpret=None,
-    ):
-        """Fused-kernel Eq. 6 step: the budget-stopping/noise logic of
-        :meth:`apply_rows` with the row math in one kernel launch —
-        budget-exhausted agents become kernel sentinels, so their stale
-        slab row survives exactly like the unfused drop-mode scatter."""
-        if not self.fused_supported:
-            raise NotImplementedError(
-                f"fused path supports the quadratic loss only, got {self.obj.loss.name!r}"
-            )
-        n = self.n
-        if srows is None:
-            srows, ssize = rows, n
-        counts = state[jnp.minimum(srows, ssize - 1)]
-        applied = valid & (counts < self.planned_Ti)
-        f32 = jnp.float32
-        if self.cfg.mechanism == "gaussian":
-            draws = jax.random.normal(key, (srows.shape[0], Theta_slab.shape[1]), f32)
-        else:
-            draws = jax.random.laplace(key, (srows.shape[0], Theta_slab.shape[1]), f32)
-        if consts is None:
-            safe = jnp.minimum(rows, n - 1)
-            consts = jax.tree.map(
-                lambda a: jnp.asarray(a)[safe],
-                {**eq4_agent_constants(self.obj), "scales": self.scales},
-            )
-        noise = draws * jnp.asarray(consts["scales"], f32)[:, None]
-        krows = jnp.where(applied, srows, ssize)
-        new_slab = _eq4_fused_slab(
-            self.obj, Theta_slab, krows, cols, w, consts, noise, ssize, interpret
-        )
-        state = state.at[jnp.where(applied, srows, ssize)].add(1, mode="drop")
-        return new_slab, applied, state
 
     def eps_spent(self, state) -> np.ndarray:
         """(n,) composed per-agent spend for the applied-update counts."""

@@ -392,9 +392,6 @@ def test_sharded_set_topology_policy_counters():
 def test_dynamic_mode_rejects_unsupported_configs():
     obj = _quad_problem(16, seed=3)
     gu = GraphUpdate(every=4)
-    with pytest.raises(ValueError, match="fused"):
-        AsyncEngine(CDUpdate(obj),
-                    config=EngineConfig(graph_update=gu, fused=True))
     with pytest.raises(NotImplementedError, match="delay"):
         AsyncEngine(
             CDUpdate(obj),

@@ -1,12 +1,11 @@
-"""Fused super-tick kernel + compressed halo exchange + config API tests.
+"""Compressed halo exchange + config API tests.
 
-In-process: single-device fused-vs-unfused forced-wake parity (dense and
-sparse mix backends, CD and DP updates), the ExchangeSpec deprecation
-shim, and the EngineConfig/make_engine factory. Subprocess (8 forced
-host devices): the fused parity matrix across S=4 x {all_gather, p2p} x
-{f32, bf16} wires, and the compressed fixed-point acceptance — bf16
-halos with error feedback land within 1e-4 of the exact optimum while
-plain bf16 halos do not.
+In-process: the ExchangeSpec deprecation shim and the
+EngineConfig/make_engine factory. Subprocess (8 forced host devices):
+the sharded exchange matrix across S=4 x {all_gather, p2p} x {f32, bf16}
+wires against the single-device engine, and the compressed fixed-point
+acceptance — bf16 halos with error feedback land within 1e-4 of the
+exact optimum while plain bf16 halos do not.
 """
 
 import os
@@ -15,32 +14,28 @@ import sys
 import textwrap
 import warnings
 
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import AgentData, DPConfig, knn_graph, make_objective
+from repro.core import AgentData, knn_graph, make_objective
 from repro.core.mixing import ExchangeSpec
 from repro.sim import (
     AsyncEngine,
     CDUpdate,
-    DPCDUpdate,
     EngineConfig,
     ShardedAsyncEngine,
     make_engine,
 )
 
-FUSED_TOL = 1e-6  # recorded deviation: f32 reduction-order, see DEVIATIONS.md
 
-
-def _quad_problem(n, p=4, m=3, seed=0, mix_mode="sparse", clip=None):
+def _quad_problem(n, p=4, m=3, seed=0):
     rng = np.random.default_rng(seed)
     graph = knn_graph(rng.normal(size=(n, 8)), k=8)
     targets = rng.normal(size=(n, p)) / np.sqrt(p)
     X = rng.normal(size=(n, m, p)) / np.sqrt(p)
     y = np.einsum("nmp,np->nm", X, targets)
     data = AgentData(X=X, y=y, mask=np.ones((n, m)))
-    return make_objective(graph, data, "quadratic", mu=0.5, mix_mode=mix_mode, clip=clip)
+    return make_objective(graph, data, "quadratic", mu=0.5, mix_mode="sparse")
 
 
 def _forced_run(engine, n, masks):
@@ -48,71 +43,6 @@ def _forced_run(engine, n, masks):
     for mask in masks:
         state = engine.step(state, mask)
     return state
-
-
-# ---------------------------------------------------------------------------
-# single-device fused parity
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("mix_mode", ["sparse", "dense"])
-def test_fused_forced_wakes_match_unfused_single_device(mix_mode):
-    obj = _quad_problem(48, mix_mode=mix_mode, seed=1)
-    n = obj.n
-    rng = np.random.default_rng(5)
-    masks = [rng.random(n) < 0.25 for _ in range(8)]
-    s0 = _forced_run(AsyncEngine(CDUpdate(obj), slot_wakes=8.0, fused=False), n, masks)
-    s1 = _forced_run(AsyncEngine(CDUpdate(obj), slot_wakes=8.0, fused=True), n, masks)
-    np.testing.assert_allclose(
-        np.asarray(s1.Theta), np.asarray(s0.Theta), rtol=0, atol=FUSED_TOL
-    )
-    assert int(s1.applied) == int(s0.applied)
-
-
-def test_fused_dp_parity_including_budget_stop():
-    """DP-CD fused path: same noise draws, same budget accounting — agents
-    freeze after planned_Ti wakes on both paths."""
-    obj = _quad_problem(24, seed=2, clip=1.0)
-    n = obj.n
-    upd = lambda: DPCDUpdate.plan(obj, DPConfig(eps_bar=0.8), planned_Ti=3)
-    masks = [np.ones(n, bool)] * 5  # 5 all-wake slots > planned_Ti=3
-    s0 = _forced_run(AsyncEngine(upd(), slot_wakes=float(n), fused=False), n, masks)
-    s1 = _forced_run(AsyncEngine(upd(), slot_wakes=float(n), fused=True), n, masks)
-    np.testing.assert_allclose(
-        np.asarray(s1.Theta), np.asarray(s0.Theta), rtol=0, atol=FUSED_TOL
-    )
-    assert np.array_equal(np.asarray(s1.ustate), np.asarray(s0.ustate))
-    assert np.array_equal(np.asarray(s1.ustate), np.full(n, 3))
-
-
-def test_fused_sharded_single_shard_matches_single_device():
-    obj = _quad_problem(32, seed=3)
-    n = obj.n
-    rng = np.random.default_rng(9)
-    masks = [rng.random(n) < 0.3 for _ in range(6)]
-    s0 = _forced_run(AsyncEngine(CDUpdate(obj), slot_wakes=8.0, fused=False), n, masks)
-    eng = ShardedAsyncEngine(CDUpdate(obj), num_shards=1, slot_wakes=8.0, fused=True)
-    sS = _forced_run(eng, n, masks)
-    np.testing.assert_allclose(
-        eng.global_theta(sS), np.asarray(s0.Theta), rtol=0, atol=FUSED_TOL
-    )
-
-
-def test_fused_true_raises_for_unsupported_update():
-    """fused=True is a hard request: non-quadratic losses have no fused
-    kernel and must fail loudly, not silently fall back."""
-    obj = _quad_problem(16, seed=0)
-    rng = np.random.default_rng(0)
-    y = np.sign(rng.normal(size=(16, 3)))
-    logistic = make_objective(
-        obj.graph, AgentData(X=np.asarray(obj.data.X), y=y, mask=np.ones((16, 3))),
-        "logistic", mu=0.5,
-    )
-    with pytest.raises(ValueError, match="fused"):
-        AsyncEngine(CDUpdate(logistic), fused=True)
-    # "auto" silently resolves off instead.
-    eng = AsyncEngine(CDUpdate(logistic), fused="auto")
-    assert eng.fused is False
 
 
 # ---------------------------------------------------------------------------
@@ -173,10 +103,9 @@ def test_engine_config_and_kwargs_build_identical_engines():
     n = obj.n
     rng = np.random.default_rng(3)
     masks = [rng.random(n) < 0.3 for _ in range(4)]
-    cfg = EngineConfig(slot_wakes=8.0, seed=1, fused=False)
+    cfg = EngineConfig(slot_wakes=8.0, seed=1)
     a = _forced_run(AsyncEngine(CDUpdate(obj), config=cfg), n, masks)
-    b = _forced_run(AsyncEngine(CDUpdate(obj), slot_wakes=8.0, seed=1, fused=False),
-                    n, masks)
+    b = _forced_run(AsyncEngine(CDUpdate(obj), slot_wakes=8.0, seed=1), n, masks)
     assert np.array_equal(np.asarray(a.Theta), np.asarray(b.Theta))
     # kwargs override config fields
     eng = AsyncEngine(CDUpdate(obj), config=cfg, slot_wakes=4.0)
@@ -197,15 +126,19 @@ def test_engine_config_rejects_unknown_options():
     obj = _quad_problem(16, seed=9)
     with pytest.raises(TypeError, match="slot_wake"):
         AsyncEngine(CDUpdate(obj), slot_wake=8.0)  # typo'd kwarg
-    with pytest.raises(ValueError, match="fused"):
-        EngineConfig(fused="yes")
+    # The row path has one form: there is no fused variant to select.
+    gone = {"fused": "auto"}
+    with pytest.raises(TypeError, match="fused"):
+        EngineConfig(**gone)
+    with pytest.raises(TypeError, match="fused"):
+        AsyncEngine(CDUpdate(obj), **gone)
 
 
 # ---------------------------------------------------------------------------
 # multi-device subprocess matrices
 # ---------------------------------------------------------------------------
 
-FUSED_MATRIX_SCRIPT = textwrap.dedent(
+EXCHANGE_MATRIX_SCRIPT = textwrap.dedent(
     """
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -228,40 +161,33 @@ FUSED_MATRIX_SCRIPT = textwrap.dedent(
     wrng = np.random.default_rng(7)
     masks = [wrng.random(n) < 0.15 for _ in range(4)]
 
-    ref_eng = AsyncEngine(upd, slot_wakes=8.0, fused=False)
+    ref_eng = AsyncEngine(upd, slot_wakes=8.0)
     rs = ref_eng.init_state(np.zeros((n, p)))
     for msk in masks:
         rs = ref_eng.step(rs, msk)
     R = np.asarray(rs.Theta)
 
-    # Parity matrix: fused x {all_gather, p2p} x {f32, bf16 (+EF)} at S=4.
-    # f32 wires must match the single-device engine to fused-kernel
-    # tolerance; compressed wires must match the *unfused* engine with the
-    # identical wire bit-for-bit (the quantizer runs outside the kernel).
+    # Exchange matrix: {all_gather, p2p} x {f32, bf16 (+EF)} at S=4. f32
+    # wires must match the single-device engine; compressed wires must
+    # differ from it, by no more than the wire precision allows.
     for spec in (ExchangeSpec(method="all_gather"),
                  ExchangeSpec(method="p2p"),
                  ExchangeSpec(method="all_gather", dtype="bf16"),
                  ExchangeSpec(method="p2p", dtype="bf16"),
                  ExchangeSpec(method="p2p", dtype="bf16", error_feedback=True)):
-        outs = {}
-        for fused in (False, True):
-            eng = ShardedAsyncEngine(upd, num_shards=4, relabel="rcm",
-                                     exchange=spec, slot_wakes=8.0, fused=fused)
-            st = eng.init_state(np.zeros((n, p)))
-            for msk in masks:
-                st = eng.step(st, msk)
-            outs[fused] = eng.global_theta(st)
-        fu_err = np.abs(outs[True] - outs[False]).max()
-        assert fu_err < 1e-6, (spec, fu_err)
+        eng = ShardedAsyncEngine(upd, num_shards=4, relabel="rcm",
+                                 exchange=spec, slot_wakes=8.0)
+        st = eng.init_state(np.zeros((n, p)))
+        for msk in masks:
+            st = eng.step(st, msk)
+        err = np.abs(eng.global_theta(st) - R).max()
         if spec.dtype == "f32":
-            ref_err = np.abs(outs[True] - R).max()
-            assert ref_err < 1e-6, (spec, ref_err)
+            assert err < 1e-6, (spec, err)
         else:
-            wire_err = np.abs(outs[False] - R).max()
-            assert 0 < wire_err < 5e-2, (spec, wire_err)
+            assert 0 < err < 5e-2, (spec, err)
         print(f"{spec.method}:{spec.dtype}:ef={int(spec.error_feedback)} "
-              f"fused_vs_unfused={fu_err:.2e}")
-    print("FUSED_MATRIX_OK")
+              f"vs_single_device={err:.2e}")
+    print("EXCHANGE_MATRIX_OK")
     """
 )
 
@@ -319,10 +245,10 @@ def _run_multidev(script):
     )
 
 
-def test_fused_parity_matrix_multidevice():
-    res = _run_multidev(FUSED_MATRIX_SCRIPT)
+def test_sharded_exchange_matrix_multidevice():
+    res = _run_multidev(EXCHANGE_MATRIX_SCRIPT)
     assert res.returncode == 0, res.stderr[-3000:]
-    assert "FUSED_MATRIX_OK" in res.stdout
+    assert "EXCHANGE_MATRIX_OK" in res.stdout
 
 
 @pytest.mark.slow

@@ -236,81 +236,3 @@ def test_ssm_chunk_consistency_with_model_reference():
     assert not bool(jnp.any(jnp.isnan(out)))
 
 
-# ---------------------------------------------------------------------------
-# fused_row_update
-# ---------------------------------------------------------------------------
-
-
-def _fused_instance(B, K, m, p, nt, rng, sentinels=0):
-    """Random fused-update operands; the last `sentinels` rows are >= limit."""
-    rows = rng.choice(nt, size=B, replace=False).astype(np.int32)
-    limit = nt
-    if sentinels:
-        limit = nt - 1
-        rows[-sentinels:] = nt - 1  # == limit after the cap below
-        rows = np.minimum(rows, nt - 1)
-    idx = rng.integers(0, nt, size=(B, K)).astype(np.int32)
-    w = rng.random((B, K)).astype(np.float32)
-    coef = np.stack(
-        [
-            rng.uniform(0.2, 0.9, B),       # alpha
-            rng.uniform(1.0, K, B),         # degree
-            rng.uniform(0.05, 0.5, B),      # mu * confidence
-            rng.uniform(0.0, 0.3, B),       # 2 * lambda
-        ],
-        axis=1,
-    ).astype(np.float32)
-    X = rng.normal(size=(B, m, p)).astype(np.float32)
-    y = rng.normal(size=(B, m)).astype(np.float32)
-    mask = (rng.random((B, m)) < 0.8).astype(np.float32)
-    noise = rng.normal(size=(B, p)).astype(np.float32) * 0.01
-    theta = rng.normal(size=(nt, p)).astype(np.float32)
-    args = tuple(jnp.asarray(a) for a in (rows, idx, w, coef, X, y, mask, noise, theta))
-    return args, limit
-
-
-@pytest.mark.parametrize("B,K,m,p,nt", [(8, 4, 3, 8, 64), (17, 7, 5, 100, 128),
-                                        (1, 3, 2, 128, 32), (40, 10, 4, 200, 256)])
-@pytest.mark.parametrize("clip", [None, 0.7])
-def test_fused_row_update_matches_ref(B, K, m, p, nt, clip):
-    rng = np.random.default_rng(B + p)
-    args, limit = _fused_instance(B, K, m, p, nt, rng)
-    got = ops.fused_row_update(*args, limit=limit, clip=clip, interpret=True)
-    want = ref.fused_row_update_ref(*args, limit=limit, clip=clip)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=4e-6, atol=4e-6)
-    # Untouched slab rows pass through bit-identically (drop-mode scatter).
-    rows = np.asarray(args[0])
-    untouched = np.setdiff1d(np.arange(nt), rows[rows < limit])
-    theta = np.asarray(args[8])
-    assert np.array_equal(np.asarray(got)[untouched], theta[untouched])
-
-
-def test_fused_row_update_sentinel_rows_never_write():
-    """Rows >= limit (padding / budget-stopped agents) leave the slab alone."""
-    rng = np.random.default_rng(0)
-    args, limit = _fused_instance(12, 5, 3, 16, 64, rng, sentinels=4)
-    got = ops.fused_row_update(*args, limit=limit, interpret=True)
-    want = ref.fused_row_update_ref(*args, limit=limit)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=4e-6, atol=4e-6)
-    theta = np.asarray(args[8])
-    assert np.array_equal(np.asarray(got)[limit:], theta[limit:])
-
-
-@pytest.mark.parametrize("block_b", [1, 4, 16])
-def test_fused_row_update_block_shape_invariance(block_b):
-    rng = np.random.default_rng(7)
-    args, limit = _fused_instance(24, 6, 4, 32, 128, rng)
-    got = ops.fused_row_update(*args, limit=limit, block_b=block_b, interpret=True)
-    want = ops.fused_row_update(*args, limit=limit, block_b=8, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=4e-6, atol=4e-6)
-
-
-def test_fused_row_update_pads_ragged_shapes():
-    """p not a multiple of 128, m not a multiple of 8, B not a multiple of
-    block_b: the wrapper pads, the valid region still matches the oracle."""
-    rng = np.random.default_rng(3)
-    args, limit = _fused_instance(11, 4, 3, 37, 50, rng)
-    got = ops.fused_row_update(*args, limit=limit, interpret=True)
-    want = ref.fused_row_update_ref(*args, limit=limit)
-    assert got.shape == (50, 37)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=4e-6, atol=4e-6)

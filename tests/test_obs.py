@@ -384,11 +384,11 @@ def test_serving_spans_in_order_and_counters(tmp_path, call):
     assert 0.0 < c1["serve_publish_sync_s_total"] <= c1["serve_publish_s_total"]
 
 
-@pytest.mark.parametrize("variant", ["unfused", "fused", "sharded", "dynamic"])
+@pytest.mark.parametrize("variant", ["static", "delay", "sharded", "dynamic"])
 def test_supertick_hlo_carries_row_gather_scope(variant):
     """The woken rows' constant gather is its own ``obs.row_gather``
     scope in the compiled super-tick, on every slot path."""
-    from repro.sim import GraphUpdate
+    from repro.sim import DelayConfig, GraphUpdate
 
     obj = _quad_problem(n=32, seed=14)
     kw = dict(slot_wakes=8.0, seed=0)
@@ -399,13 +399,14 @@ def test_supertick_hlo_carries_row_gather_scope(variant):
         eng = AsyncEngine(CDUpdate(obj), graph_update=GraphUpdate(every=4), **kw)
         chunk, tables = eng._chunk_dyn, eng._dyn
     else:
-        eng = AsyncEngine(CDUpdate(obj), fused=variant == "fused", **kw)
+        if variant == "delay":
+            kw["scenario"] = Scenario(delay=DelayConfig(max_delay=2))
+        eng = AsyncEngine(CDUpdate(obj), **kw)
         chunk, tables = eng._chunk, eng._static
-    assert eng.fused == (variant == "fused")
     state = eng.init_state(np.zeros((obj.n, obj.p)))
     text = chunk.lower(state, tables, 2).compile().as_text()
     assert "obs.row_gather" in text
-    assert "obs.row_update" in text or "obs.fused_row_update" in text
+    assert "obs.row_update" in text
 
 
 def test_spans_keep_nothing_without_a_profiler():

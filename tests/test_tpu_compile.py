@@ -1,12 +1,12 @@
-"""Ahead-of-time TPU v5e compiles of the Pallas kernels on the engine path.
+"""Ahead-of-time TPU v5e compiles of the Pallas kernels and the super-tick.
 
 Interpret mode runs a kernel's program on the CPU but never asks Mosaic,
 the TPU kernel compiler, whether it accepts it: tiling refusals and
 VMEM/SMEM overflows only show up in a real compile. These tests compile
 each kernel for one chip of a described (not attached) v5e topology at the
-edges of the shapes the auto gates admit (``kernel_max_n()`` agents, the
-largest slab width and data depth ``fused_row_update_fits`` accepts), and
-check that the compiled program holds the kernel as a ``tpu_custom_call``.
+edges of the shapes the auto gates admit (``kernel_max_n()`` agents), and
+check that the compiled program holds the kernel as a ``tpu_custom_call``;
+the engine's scan chunk is compiled the same way, and checked for f64.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
@@ -53,47 +53,68 @@ def _compile(fn, one_chip, *shapes):
 F32, I32 = jnp.float32, jnp.int32
 
 
-def _largest(fits, lo, hi):
-    """Largest x in [lo, hi] with fits(x) (fits is monotone decreasing)."""
-    assert fits(lo)
-    while lo < hi:
-        mid = (lo + hi + 1) // 2
-        lo, hi = (mid, hi) if fits(mid) else (lo, mid - 1)
-    return lo
+def _engine(n, p, m, B, K):
+    """An engine of ``n`` agents with ``m`` data points of ``p`` features
+    each, woken ``B`` at a time, over a chain with a hub of degree ``K``
+    (so ``K`` is the largest degree), built in the chip path's 32-bit mode."""
+    import numpy as np
 
+    from repro.core import AgentData, make_objective
+    from repro.core.graph import csr_from_coo
+    from repro.sim import AsyncEngine, CDUpdate
 
-def _fused_shapes(nt, p, m, B, K):
-    return [
-        ((B,), I32), ((B, K), I32), ((B, K), F32), ((B, 4), F32),
-        ((B, m, p), F32), ((B, m), F32), ((B, m), F32), ((B, p), F32), ((nt, p), F32),
-    ]
-
-
-def _fused(nt):
-    return lambda *a: ops.fused_row_update(*a, limit=nt, interpret=False)
+    rows = np.concatenate([np.arange(n - 1), np.zeros(K - 1, np.int64)])
+    cols = np.concatenate([np.arange(1, n), np.arange(2, K + 1)])
+    graph = csr_from_coo(n, rows, cols, np.ones(len(rows)), symmetrize=True)
+    data = AgentData(
+        X=np.ones((n, m, p), np.float32),
+        y=np.ones((n, m), np.float32),
+        mask=np.ones((n, m), np.float32),
+    )
+    obj = make_objective(graph, data, "quadratic", mu=0.04, clip=10.0, mix_mode="sparse")
+    with jax.enable_x64(False):
+        return AsyncEngine(CDUpdate(obj), slot_wakes=0.5 * B, batch_size=B, seed=0)
 
 
 @pytest.mark.parametrize(
     "case",
     [
-        "engine_cell",  # n = kernel_max_n(), p = 20, m = 16, the smoke's shapes
-        "widest_slab",  # the largest p the VMEM gate admits at that n
-        "deepest_data",  # the largest m it admits at p = 20
+        "engine_cell",  # n = kernel_max_n(), p = 20, m = 16
+        "widest_slab",  # p = 128: one full lane tile of features
+        "deepest_data",  # m = 968 points an agent at p = 20
         "every_row_woken",  # B = n, K past one lane tile
     ],
 )
-def test_fused_row_update_compiles_at_gate_edges(one_chip, case):
-    nt = kernel_max_n()
+def test_supertick_compiles_without_f64(one_chip, case):
+    """The scan chunk (gather, mix, Eq. 4, scatter) compiles for the chip
+    at ``kernel_max_n()`` agents with a wide slab, deep data, or every row
+    woken, in 32 bits throughout."""
+    import re
+
+    import numpy as np
+
+    n = kernel_max_n()
     p, m, B, K = 20, 16, 112, 40
     if case == "widest_slab":
-        p = _largest(lambda q: ops.fused_row_update_fits(nt, q, m), 1, 4096)
-        assert not ops.fused_row_update_fits(nt, p + 1, m)
+        p = 128
     elif case == "deepest_data":
-        m = _largest(lambda q: ops.fused_row_update_fits(nt, p, q), 1, 1 << 14)
+        m = 968
     elif case == "every_row_woken":
-        B, K = nt, 200
-    assert ops.fused_row_update_fits(nt, p, m)
-    _compile(_fused(nt), one_chip, *_fused_shapes(nt, p, m, B, K))
+        B, K = n, 200
+    eng = _engine(n, p, m, B, K)
+    assert int(np.diff(eng.update.mix.indptr).max()) == K
+    assert eng.batch_size == B
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    with jax.enable_x64(False):
+        theta = jax.ShapeDtypeStruct((n, p), eng.dtype)
+        state = jax.tree.map(spec, jax.eval_shape(eng.init_state, theta))
+        static = jax.tree.map(spec, eng._static)
+        text = eng._chunk.lower(state, static, eng.steps_per_chunk).compile().as_text()
+    assert "obs.row_update" in text
+    assert re.search(r"\bf64\[", text) is None
 
 
 @pytest.mark.parametrize("p", [20, 1000])
